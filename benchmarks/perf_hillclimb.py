@@ -16,6 +16,7 @@ three-term delta.  Run:
 
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU analysis tool: never claims a chip
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 import argparse

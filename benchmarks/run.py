@@ -1,6 +1,6 @@
 """Benchmark harness: one module per paper table/figure + framework benches.
 
-    PYTHONPATH=src python -m benchmarks.run [--quick] [--skip-dryrun]
+    PYTHONPATH=src python -m benchmarks.run [--quick]
 
 Paper experiments (ratios/trends are the reproduction target — DESIGN.md §8):
   fig7   block-size sweep          fig8   collaborator scaling
@@ -14,15 +14,15 @@ Paper experiments (ratios/trends are the reproduction target — DESIGN.md §8):
   fig14  partition-tolerant writes: quorum availability, heal-time convergence
 Framework:
   ckpt_stall  LW+MEU vs workspace checkpointing
-  dryrun      one representative cell (full table: results/dryrun_all.json)
+
+The multi-pod dry-run is a CPU analysis tool with 512 forced host devices; it
+runs in a process of its own (``python -m repro.launch.dryrun``), never as a
+child of this one, which has already initialised JAX.
 """
 
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
 import sys
 import time
 
@@ -41,25 +41,11 @@ from benchmarks import (
     fig14_quorum,
     tab2_query,
 )
-from benchmarks.common import RESULTS_DIR
-
-
-def _dryrun_sample() -> int:
-    """Compile a representative train cell with 512 host devices."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
-    cmd = [
-        sys.executable, "-m", "repro.launch.dryrun",
-        "--arch", "gemma2-2b", "--shape", "train_4k",
-        "--out", os.path.join(RESULTS_DIR, "dryrun_sample.json"),
-    ]
-    return subprocess.call(cmd, env=env)
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true", help="reduced sweep sizes")
-    ap.add_argument("--skip-dryrun", action="store_true")
     args = ap.parse_args(argv)
 
     benches = [
@@ -89,10 +75,6 @@ def main(argv=None) -> int:
 
             traceback.print_exc()
             print(f"BENCH FAIL {name}: {exc}")
-    if not args.skip_dryrun:
-        print("\n=== dryrun sample (full sweep: results/dryrun_all.json) ===")
-        if _dryrun_sample() != 0:
-            failures += 1
     print(f"\nbenchmarks done in {time.time()-t0:.0f}s, failures={failures}")
     return 1 if failures else 0
 

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 __all__ = ["pipelined_forward"]
 
 
@@ -86,7 +84,7 @@ def pipelined_forward(
         )
         return outs.reshape(-1, *x.shape[1:])
 
-    return shard_map(
+    return jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),

@@ -44,6 +44,4 @@ def vary(tree: Any) -> Any:
     axes = current_manual_axes()
     if not axes:
         return tree
-    from repro.compat import pcast_varying
-
-    return jax.tree.map(lambda x: pcast_varying(x, axes), tree)
+    return jax.tree.map(lambda x: jax.lax.pcast(x, axes, to="varying"), tree)

@@ -9,7 +9,8 @@ because the *framework's* model substrate needs them on TPU (DESIGN.md §6):
 
 Each kernel has a pure-jnp oracle in :mod:`.ref` and a jit'd dispatch wrapper
 in :mod:`.ops`; tests sweep shapes/dtypes and assert_allclose kernel-vs-ref
-in interpret mode (CPU container).
+in interpret mode on the CPU, and ``tests/test_tpu_compile.py`` compiles each
+kernel at its real widths for a described TPU v5e.
 """
 
 from .ops import attention, mamba_scan, wkv6

@@ -135,7 +135,7 @@ def flash_attention_pallas(
     block_kv: int = 128,
     q_offset: int = 0,
     scale: Optional[float] = None,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Pallas fused attention.  Shapes as in :func:`repro.kernels.ref.attention_ref`."""
     B, S, H, hd = q.shape

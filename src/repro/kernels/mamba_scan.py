@@ -3,9 +3,9 @@
 TPU adaptation (DESIGN.md §6): the CUDA kernel assigns one thread per channel
 and scans time sequentially in registers.  On TPU the equivalent is a grid
 over ``(batch, d_inner blocks, time chunks)`` with the per-channel state
-h ∈ ℝ^{bd×ds} held in VMEM scratch; inside a chunk, a ``fori_loop`` advances
-time with fully-vectorized [bd, ds] elementwise updates on the VPU while the
-chunk's inputs sit in VMEM.  The diagonal-A structure of Mamba-1 makes the
+h ∈ ℝ^{ds×bd} held in VMEM scratch (channels on the 128-wide lane axis);
+inside a chunk, a ``fori_loop`` advances time with fully-vectorized [ds, bd]
+elementwise updates on the VPU while the chunk's inputs sit in VMEM.  The diagonal-A structure of Mamba-1 makes the
 update elementwise (no MXU work is lost by not using it — there is no matmul
 in the recurrence), and ``y_t = C_t · h_t`` is a ds-reduction fused into the
 same loop.
@@ -16,8 +16,8 @@ the [bd]- and [ds]-shaped chunk inputs rather than materialized at
 is exactly what makes the attention-free archs memory-bound rather than
 HBM-traffic-pathological on long contexts.
 
-VMEM per step: chunk·(2·bd + 2·ds) input floats + bd·ds state + chunk·bd out
-(chunk=128, bd=256, ds=16 → ~0.4 MB).
+VMEM per step: chunk·(2·bd + 2·ds) input floats + bd·ds state + 3·chunk·bd
+f32 row copies and out (chunk=128, bd=256, ds=16 → ~0.6 MB).
 """
 
 from __future__ import annotations
@@ -34,13 +34,16 @@ __all__ = ["mamba_scan_pallas"]
 
 
 def _scan_kernel(
-    u_ref,   # [1, cs, bd]
-    d_ref,   # [1, cs, bd]   delta (softplus'd)
-    A_ref,   # [bd, ds]
-    b_ref,   # [1, cs, ds]
-    c_ref,   # [1, cs, ds]
-    y_ref,   # [1, cs, bd]
-    h_ref,   # VMEM [bd, ds] running state
+    u_ref,    # [1, cs, bd]
+    d_ref,    # [1, cs, bd]   delta (softplus'd)
+    A_ref,    # [ds, bd]      A transposed: channels on lanes
+    b_ref,    # [1, ds, cs]   B transposed: time on lanes
+    c_ref,    # [1, ds, cs]   C transposed
+    y_ref,    # [1, cs, bd]
+    h_ref,    # VMEM [ds, bd] running state
+    dt_s,     # VMEM [cs, bd] f32 delta rows
+    dtu_s,    # VMEM [cs, bd] f32 delta·u rows
+    y_s,      # VMEM [cs, bd] f32 output rows
     *,
     cs: int,
 ):
@@ -50,26 +53,27 @@ def _scan_kernel(
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    u = u_ref[0].astype(jnp.float32)     # [cs, bd]
-    dt = d_ref[0].astype(jnp.float32)    # [cs, bd]
-    A = A_ref[...].astype(jnp.float32)   # [bd, ds]
-    Bm = b_ref[0].astype(jnp.float32)    # [cs, ds]
-    Cm = c_ref[0].astype(jnp.float32)    # [cs, ds]
+    dt = d_ref[0].astype(jnp.float32)              # [cs, bd]
+    dt_s[...] = dt
+    dtu_s[...] = dt * u_ref[0].astype(jnp.float32)
+    A = A_ref[...].astype(jnp.float32)             # [ds, bd]
+    Bt = b_ref[0].astype(jnp.float32)              # [ds, cs]
+    Ct = c_ref[0].astype(jnp.float32)              # [ds, cs]
+    lane = jax.lax.broadcasted_iota(jnp.int32, Bt.shape, 1)
 
-    def step(t, carry):
-        h, ys = carry
-        decay = jnp.exp(dt[t][:, None] * A)                  # [bd, ds]
-        drive = (dt[t] * u[t])[:, None] * Bm[t][None, :]     # [bd, ds]
-        h = decay * h + drive
-        y = jnp.sum(h * Cm[t][None, :], axis=-1)             # [bd]
-        ys = jax.lax.dynamic_update_index_in_dim(ys, y, t, axis=0)
-        return h, ys
+    def step(t, h):
+        # row t of the f32 copies is read from the refs (Mosaic lowers no
+        # dynamic value slicing); column t of B/C by a one-hot lane reduce
+        dt_t = dt_s[pl.ds(t, 1), :]                                   # [1, bd]
+        dtu_t = dtu_s[pl.ds(t, 1), :]                                 # [1, bd]
+        b_t = jnp.sum(jnp.where(lane == t, Bt, 0.0), axis=1, keepdims=True)  # [ds, 1]
+        c_t = jnp.sum(jnp.where(lane == t, Ct, 0.0), axis=1, keepdims=True)  # [ds, 1]
+        h = jnp.exp(dt_t * A) * h + dtu_t * b_t                       # [ds, bd]
+        y_s[pl.ds(t, 1), :] = jnp.sum(h * c_t, axis=0, keepdims=True)
+        return h
 
-    h0 = h_ref[...]
-    ys0 = jnp.zeros((cs, u.shape[1]), jnp.float32)
-    h_fin, ys = jax.lax.fori_loop(0, cs, step, (h0, ys0))
-    h_ref[...] = h_fin
-    y_ref[0] = ys.astype(y_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, cs, step, h_ref[...])
+    y_ref[0] = y_s[...].astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_d", "interpret"))
@@ -82,7 +86,7 @@ def mamba_scan_pallas(
     *,
     chunk: int = 128,
     block_d: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Pallas selective scan; matches :func:`repro.kernels.ref.mamba_scan_ref`
     (zero initial state).  Returns y [B, S, di]."""
@@ -95,20 +99,27 @@ def mamba_scan_pallas(
     nd = di // bd
 
     kernel = functools.partial(_scan_kernel, cs=cs)
+    row_block = pl.BlockSpec((1, cs, bd), lambda b, idd, ic: (b, ic, idd))
+    col_block = pl.BlockSpec((1, ds, cs), lambda b, idd, ic: (b, 0, ic))
 
     y = pl.pallas_call(
         kernel,
         grid=(B, nd, nc),
         in_specs=[
-            pl.BlockSpec((1, cs, bd), lambda b, idd, ic: (b, ic, idd)),
-            pl.BlockSpec((1, cs, bd), lambda b, idd, ic: (b, ic, idd)),
-            pl.BlockSpec((bd, ds), lambda b, idd, ic: (idd, 0)),
-            pl.BlockSpec((1, cs, ds), lambda b, idd, ic: (b, ic, 0)),
-            pl.BlockSpec((1, cs, ds), lambda b, idd, ic: (b, ic, 0)),
+            row_block,
+            row_block,
+            pl.BlockSpec((ds, bd), lambda b, idd, ic: (0, idd)),
+            col_block,
+            col_block,
         ],
-        out_specs=pl.BlockSpec((1, cs, bd), lambda b, idd, ic: (b, ic, idd)),
+        out_specs=row_block,
         out_shape=jax.ShapeDtypeStruct((B, S, di), u.dtype),
-        scratch_shapes=[pltpu.VMEM((bd, ds), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((ds, bd), jnp.float32),
+            pltpu.VMEM((cs, bd), jnp.float32),
+            pltpu.VMEM((cs, bd), jnp.float32),
+            pltpu.VMEM((cs, bd), jnp.float32),
+        ],
         interpret=interpret,
-    )(u, delta, A, Bmat, Cmat)
+    )(u, delta, A.T, jnp.swapaxes(Bmat, 1, 2), jnp.swapaxes(Cmat, 1, 2))
     return y

@@ -2,8 +2,8 @@
 
 Models call these wrappers; ``use_pallas`` (from the ModelConfig) selects the
 TPU kernels, otherwise the chunked pure-jnp twins in :mod:`repro.models` run
-(CPU dry-runs, oracles).  On this CPU container Pallas executes in interpret
-mode; on a real TPU ``interpret=False`` compiles to Mosaic.
+(CPU dry-runs, oracles).  The kernels compile to Mosaic for the TPU; only
+the CPU kernel tests run them in interpret mode, by passing ``interpret=True``.
 """
 
 from __future__ import annotations
@@ -15,10 +15,7 @@ from .flash_attention import flash_attention_pallas
 from .mamba_scan import mamba_scan_pallas
 from .rwkv6_scan import wkv6_pallas
 
-__all__ = ["attention", "wkv6", "mamba_scan", "INTERPRET"]
-
-#: Flip to False on a real TPU deployment.
-INTERPRET = True
+__all__ = ["attention", "wkv6", "mamba_scan"]
 
 
 def attention(
@@ -30,7 +27,6 @@ def attention(
         return flash_attention_pallas(
             q, k, v, causal=causal, window=window, logit_softcap=logit_softcap,
             block_q=chunk_q, block_kv=chunk_kv, q_offset=q_offset,
-            interpret=INTERPRET,
         )
     from repro.models.attention import flash_attention
 
@@ -43,7 +39,7 @@ def attention(
 def wkv6(r, k, v, w, u, *, chunk=128, s0=None, use_pallas=False):
     """RWKV-6 recurrence.  Pallas path requires zero initial state."""
     if use_pallas and s0 is None:
-        out = wkv6_pallas(r, k, v, w, u, chunk=chunk, interpret=INTERPRET)
+        out = wkv6_pallas(r, k, v, w, u, chunk=chunk)
         return out, None
     from repro.models.rwkv6 import wkv_chunked
 
@@ -53,7 +49,7 @@ def wkv6(r, k, v, w, u, *, chunk=128, s0=None, use_pallas=False):
 def mamba_scan(u, delta, A, Bmat, Cmat, *, chunk=128, h0=None, use_pallas=False):
     """Selective scan.  Pallas path requires zero initial state."""
     if use_pallas and h0 is None:
-        y = mamba_scan_pallas(u, delta, A, Bmat, Cmat, chunk=chunk, interpret=INTERPRET)
+        y = mamba_scan_pallas(u, delta, A, Bmat, Cmat, chunk=chunk)
         return y, None
     from repro.models.mamba import ssm_chunked_scan
 

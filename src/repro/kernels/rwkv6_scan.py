@@ -38,12 +38,12 @@ __all__ = ["wkv6_pallas"]
 
 
 def _wkv_kernel(
-    r_ref,   # [1, cs, 1, C]
-    k_ref,   # [1, cs, 1, C]
-    v_ref,   # [1, cs, 1, C]
-    lw_ref,  # [1, cs, 1, C]  log-decay (≤ 0)
-    u_ref,   # [1, C]         bonus for this head
-    o_ref,   # [1, cs, 1, C]
+    r_ref,   # [1, 1, cs, C]
+    k_ref,   # [1, 1, cs, C]
+    v_ref,   # [1, 1, cs, C]
+    lw_ref,  # [1, 1, cs, C]  log-decay (≤ 0)
+    u_ref,   # [1, 1, C]      bonus for this head
+    o_ref,   # [1, 1, cs, C]
     s_ref,   # VMEM [C, C] running state
     *,
     cs: int,
@@ -55,13 +55,20 @@ def _wkv_kernel(
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    r = r_ref[0, :, 0, :].astype(jnp.float32)    # [cs, C]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    lw = lw_ref[0, :, 0, :].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)             # [C]
+    r = r_ref[0, 0].astype(jnp.float32)          # [cs, C]
+    k = k_ref[0, 0].astype(jnp.float32)
+    v = v_ref[0, 0].astype(jnp.float32)
+    lw = lw_ref[0, 0].astype(jnp.float32)
+    u = u_ref[0].astype(jnp.float32)             # [1, C]
 
-    L = jnp.cumsum(lw, axis=0)                   # inclusive  L_t   [cs, C]
+    # inclusive prefix sum L_t over the chunk as a lower-triangular matmul
+    # (Mosaic has no cumsum); HIGHEST keeps the f32 log-decays exact enough
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 0)
+    u_idx = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 1)
+    L = jax.lax.dot_general(
+        (u_idx <= t_idx).astype(jnp.float32), lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )                                            # [cs, C]
     Lx = L - lw                                  # exclusive  L_{t-1}
 
     # inter-chunk contribution through the carried state (MXU matmul)
@@ -70,26 +77,27 @@ def _wkv_kernel(
         r_dec, s_ref[...], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                            # [cs, C]
 
-    # intra-chunk pairwise scores (strictly lower-triangular in t, u)
-    rel = Lx[:, None, :] - L[None, :, :]         # [t, u, C]
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 0)
-    u_idx = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 1)
-    tri = (u_idx < t_idx)[..., None]             # u < t
-    rel = jnp.where(tri, rel, -jnp.inf)
-    att = jnp.einsum("ti,tui,ui->tu", r, jnp.exp(rel), k)     # [cs, cs]
+    # intra-chunk pairwise scores (strictly lower-triangular in t, u),
+    # accumulated one channel at a time so every operand stays 2-D
+    tri = u_idx < t_idx                          # u < t
+    LT, kT = L.T, k.T                            # [C, cs]
+    att = jnp.zeros((cs, cs), jnp.float32)
+    for i in range(C):
+        rel = jnp.where(tri, Lx[:, i : i + 1] - LT[i : i + 1, :], -jnp.inf)
+        att = att + (r[:, i : i + 1] * jnp.exp(rel)) * kT[i : i + 1, :]
     out = inter + jax.lax.dot_general(
         att, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    diag = jnp.sum(r * u[None, :] * k, axis=-1, keepdims=True)  # [cs, 1]
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)           # [cs, 1]
     out = out + diag * v
 
     # state update: S ← exp(L_T) ∘ S + Σ_u exp(L_T − L_u) k_u ⊗ v_u
-    decay_all = jnp.exp(L[-1][None, :] - L)      # [cs, C] (≤ 1)
-    s_new = jnp.exp(L[-1])[:, None] * s_ref[...] + jax.lax.dot_general(
+    decay_all = jnp.exp(L[cs - 1 :, :] - L)      # [cs, C] (≤ 1)
+    s_new = jnp.exp(LT[:, cs - 1 :]) * s_ref[...] + jax.lax.dot_general(
         decay_all * k, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     s_ref[...] = s_new
-    o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+    o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -101,7 +109,7 @@ def wkv6_pallas(
     u: jax.Array,  # [H, C]
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Pallas WKV; matches :func:`repro.kernels.ref.wkv6_ref` (zero initial state)."""
     B, S, H, C = r.shape
@@ -112,19 +120,23 @@ def wkv6_pallas(
     logw = jnp.log(jnp.maximum(w.astype(jnp.float32), 1e-38))
     kernel = functools.partial(_wkv_kernel, cs=cs, C=C)
 
+    # head-major layout: each block's trailing (cs, C) dims are tile-aligned
+    rt, kt, vt, lwt = (jnp.swapaxes(x, 1, 2) for x in (r, k, v, logw))  # [B, H, S, C]
+    seq_block = pl.BlockSpec((1, 1, cs, C), lambda b, h, ic: (b, h, ic, 0))
+
     out = pl.pallas_call(
         kernel,
         grid=(B, H, nc),
         in_specs=[
-            pl.BlockSpec((1, cs, 1, C), lambda b, h, ic: (b, ic, h, 0)),
-            pl.BlockSpec((1, cs, 1, C), lambda b, h, ic: (b, ic, h, 0)),
-            pl.BlockSpec((1, cs, 1, C), lambda b, h, ic: (b, ic, h, 0)),
-            pl.BlockSpec((1, cs, 1, C), lambda b, h, ic: (b, ic, h, 0)),
-            pl.BlockSpec((1, C), lambda b, h, ic: (h, 0)),
+            seq_block,
+            seq_block,
+            seq_block,
+            seq_block,
+            pl.BlockSpec((1, 1, C), lambda b, h, ic: (h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, cs, 1, C), lambda b, h, ic: (b, ic, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, C), r.dtype),
+        out_specs=seq_block,
+        out_shape=jax.ShapeDtypeStruct((B, H, S, C), r.dtype),
         scratch_shapes=[pltpu.VMEM((C, C), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, logw, u)
-    return out
+    )(rt, kt, vt, lwt, u[:, None, :])
+    return jnp.swapaxes(out, 1, 2)  # [B, S, H, C]

@@ -1,11 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
-The two lines above MUST run before any other import — jax locks the device
-count at first init, and the production meshes need 512 placeholder devices
-(single-pod 16×16 = 256 used as a sub-mesh, multi-pod 2×16×16 = 512).
+A CPU analysis tool: the environment lines below MUST run before jax is
+imported — jax locks the platform and device count at first init, and the
+production meshes need 512 placeholder host devices (single-pod 16×16 = 256
+used as a sub-mesh, multi-pod 2×16×16 = 512).  It never claims a chip.
 
 Per cell this script:
   1. builds the model + abstract state (ShapeDtypeStructs, no allocation),
@@ -21,6 +19,13 @@ Usage:
     python -m repro.launch.dryrun --all --multi-pod --out results/dryrun.json
 """
 
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
+).strip()
+
 import argparse
 import json
 import sys
@@ -31,7 +36,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import cost_analysis_dict, set_mesh
 from repro.configs import ARCHS, SHAPES, applicable_shapes, get_config
 from repro.distributed.sharding import (
     batch_shardings,
@@ -128,7 +132,7 @@ def run_cell(
     model = Model(cfg)
     t0 = time.time()
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = AdamW(AdamWConfig())
             n_pods = mesh.shape.get("pod", 0) if cross_pod != "auto" else 0
@@ -181,7 +185,7 @@ def run_cell(
         t_compile = time.time() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
 
     n_params = _count_params_abstract(model)

@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_variant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.serve import ServeConfig, ServeEngine
@@ -35,6 +36,7 @@ def main(argv=None) -> int:
     ap.add_argument("--mesh", default=None, help="data,model e.g. 2,2 (default: no mesh)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -22,6 +22,7 @@ import jax
 from repro.configs import SHAPES, get_config, smoke_variant
 from repro.core import Collaboration
 from repro.data import ShardedPipeline, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.models import encdec as _encdec
@@ -44,6 +45,7 @@ def main(argv=None) -> int:
     ap.add_argument("--run", default="cli-run")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

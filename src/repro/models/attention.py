@@ -207,11 +207,10 @@ def attention_layer(
     window = cfg.attn_window if kind == "attn_local" else 0
     if getattr(cfg, "use_pallas", False):
         from repro.kernels.flash_attention import flash_attention_pallas
-        from repro.kernels.ops import INTERPRET
 
         out = flash_attention_pallas(
             q, k, v, causal=causal, window=window, logit_softcap=cfg.attn_softcap,
-            block_q=cfg.attn_chunk_q, block_kv=cfg.attn_chunk_kv, interpret=INTERPRET,
+            block_q=cfg.attn_chunk_q, block_kv=cfg.attn_chunk_kv,
         )
     else:
         out = flash_attention(

@@ -2,7 +2,7 @@
 
 from .checkpoint import CheckpointManager
 from .step import build_train_step, init_state, init_state_abstract, shard_state, state_shardings
-from .trainer import FaultInjector, Trainer, TrainerConfig
+from .trainer import FaultInjector, NodeFailure, Trainer, TrainerConfig
 
 __all__ = [
     "CheckpointManager",
@@ -12,6 +12,7 @@ __all__ = [
     "shard_state",
     "state_shardings",
     "FaultInjector",
+    "NodeFailure",
     "Trainer",
     "TrainerConfig",
 ]

@@ -96,9 +96,14 @@ class CheckpointManager:
         self.collaborator = collaborator
         # workspace mode indexes inline (the paper's Inline-Sync write path);
         # native mode indexes offline after the MEU export (LW-Offline).
+        # A restore reads each shard byte once, so remote shards bypass the
+        # client chunk cache and read-ahead: filling the cache with
+        # multi-GB extents only copies them (again and again as stripes merge).
         self.ws = Workspace(
             collab, collaborator, home_dc,
             extraction_mode="inline-sync" if mode == "workspace" else "none",
+            chunk_cache_bytes=0,
+            readahead=False,
         )
         self.native = NativeSession(collab.dc(home_dc), collaborator)
         self.meu = MEU(collab, collab.dc(home_dc), collaborator)
@@ -207,19 +212,24 @@ class CheckpointManager:
             shard_arrays.append(arrays)
 
         leaves = _flatten_with_paths(state_like)
+        treedef = jax.tree_util.tree_structure(state_like)
+        targets = (
+            treedef.flatten_up_to(shardings) if shardings is not None else [None] * len(leaves)
+        )
         rebuilt = []
-        for path, like in leaves:
+        for (path, like), target in zip(leaves, targets):
+            # pop each shard's piece and place each leaf as soon as it is
+            # rebuilt: the host holds the read shards plus one leaf, never a
+            # second whole copy of the state
             ax = split_axes[path]
             if ax < 0:
-                arr = shard_arrays[0][path]
+                arr = shard_arrays[0].pop(path)
             else:
-                arr = np.concatenate([sa[path] for sa in shard_arrays], axis=ax)
+                arr = np.concatenate([sa.pop(path) for sa in shard_arrays], axis=ax)
             if hasattr(like, "shape"):
                 # scidata stores 0-d arrays as [1] (ascontiguousarray quirk)
                 arr = arr.reshape(like.shape)
-            rebuilt.append(arr.astype(like.dtype) if hasattr(like, "dtype") else arr)
-        treedef = jax.tree_util.tree_structure(state_like)
-        out = jax.tree_util.tree_unflatten(treedef, rebuilt)
-        if shardings is not None:
-            out = jax.tree.map(jax.device_put, out, shardings)
-        return out
+            if hasattr(like, "dtype"):
+                arr = arr.astype(like.dtype, copy=False)
+            rebuilt.append(arr if target is None else jax.device_put(arr, target))
+        return jax.tree_util.tree_unflatten(treedef, rebuilt)

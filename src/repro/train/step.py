@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import HAS_VMA_SHARD_MAP, shard_map
 from repro.distributed.collectives import hierarchical_grad_mean
 from repro.distributed.sharding import batch_shardings, batch_spec, param_shardings
 from repro.optim.adamw import AdamW
@@ -155,59 +154,6 @@ def build_train_step(
 
     if cross_pod == "auto":
         step_fn = body
-    elif not HAS_VMA_SHARD_MAP:
-        # Pre-vma jax: the partitioner aborts on any differentiated scan
-        # inside a partial-manual region, so the model math cannot run under
-        # shard_map.  Equivalent formulation: vmap over an explicit leading
-        # pod dim yields per-pod mean gradients with NO cross-pod reduction
-        # (GSPMD keeps vmapped dims independent), then a scan-free
-        # partial-manual region performs just the pod hop — the same
-        # hierarchical/compressed wire traffic, identical numerics.
-        n_pods = mesh.shape["pod"]
-
-        def step_fn(state, batch):
-            mb = jax.tree.map(
-                lambda x: x.reshape(n_pods, x.shape[0] // n_pods, *x.shape[1:]),
-                batch,
-            )
-
-            def per_pod(b):
-                loss, _, grads = _microbatched_grads(
-                    model, state["params"], b, microbatches, loss_chunk
-                )
-                return loss, grads
-
-            losses, pgrads = jax.vmap(per_pod)(mb)
-            ef = state.get("ef")
-
-            def hop(pg, e):
-                g = jax.tree.map(lambda x: x[0], pg)  # strip the pod block dim
-                return hierarchical_grad_mean(
-                    g, e, compress=(cross_pod == "compressed")
-                )
-
-            pod_specs = jax.tree.map(lambda _: P("pod"), pgrads)
-            ef_specs = jax.tree.map(lambda _: P("pod"), ef)
-            grads, new_ef = shard_map(
-                hop,
-                mesh=mesh,
-                in_specs=(pod_specs, ef_specs),
-                out_specs=(jax.tree.map(lambda _: P(), pgrads), ef_specs),
-                axis_names={"pod"},
-                check_vma=False,
-            )(pgrads, ef)
-            loss = losses.mean()  # == pmean of per-pod means
-            new_params, new_opt, stats = optimizer.update(
-                grads, state["opt_state"], state["params"]
-            )
-            new_state: TrainState = {
-                "params": new_params,
-                "opt_state": new_opt,
-                "step": state["step"] + 1,
-            }
-            if "ef" in state:
-                new_state["ef"] = new_ef if cross_pod == "compressed" else state["ef"]
-            return new_state, {"loss": loss, **stats}
     else:
         # manual over pod, auto over data/model.  Specs describe only the
         # pod axis: batch and ef are pod-split on dim 0, everything else is
@@ -232,7 +178,7 @@ def build_train_step(
         def step_fn(state, batch):
             st_specs, b_specs = specs_of(state, batch)
             out_specs = (st_specs, {"loss": P(), "grad_norm": P(), "lr": P()})
-            return shard_map(
+            return jax.shard_map(
                 body_manual,
                 mesh=mesh,
                 in_specs=(st_specs, b_specs),

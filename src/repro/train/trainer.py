@@ -16,11 +16,13 @@ Responsibilities (large-scale runnability, DESIGN.md §4):
 The loop is deliberately synchronous-SPMD (one jit per step) — the shape a
 real multi-pod JAX deployment has; fault events are modeled as exceptions
 raised by an injectable ``fault_hook`` because a CPU container cannot kill
-real TPU workers.
+real TPU workers.  Only that injected :class:`NodeFailure` triggers a
+restore: any other error (a device OOM, a runtime fault) propagates.
 """
 
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
@@ -28,7 +30,6 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import numpy as np
 
-from repro.compat import set_mesh
 from repro.data.pipeline import ShardedPipeline, WorkStealingBalancer
 from repro.distributed.sharding import batch_shardings
 from repro.optim.adamw import AdamW
@@ -36,11 +37,16 @@ from repro.train.checkpoint import CheckpointManager
 from repro.train.step import (
     build_train_step,
     init_state,
+    init_state_abstract,
     shard_state,
     state_shardings,
 )
 
-__all__ = ["Trainer", "TrainerConfig", "FaultInjector"]
+__all__ = ["Trainer", "TrainerConfig", "FaultInjector", "NodeFailure"]
+
+
+class NodeFailure(Exception):
+    """A modeled node loss, raised by a ``fault_hook``; the loop restores."""
 
 
 class FaultInjector:
@@ -53,7 +59,7 @@ class FaultInjector:
     def __call__(self, step: int) -> None:
         if step in self.fail_at and step not in self.fired:
             self.fired.append(step)
-            raise RuntimeError(f"injected node failure at step {step}")
+            raise NodeFailure(f"injected node failure at step {step}")
 
 
 @dataclass
@@ -86,14 +92,19 @@ class Trainer:
         self.cfg = cfg
         self.ckpt = ckpt
         self.fault_hook = fault_hook
-        n_pods = mesh.shape.get("pod", 0) if cfg.cross_pod != "auto" else 0
-        self.state = init_state(model, optimizer, jax.random.PRNGKey(seed), n_pods=n_pods)
-        self._abstract = jax.eval_shape(lambda: self.state)
+        self.seed = seed
+        self._n_pods = mesh.shape.get("pod", 0) if cfg.cross_pod != "auto" else 0
+        self._abstract = init_state_abstract(model, optimizer, n_pods=self._n_pods)
         self.shardings = state_shardings(self._abstract, mesh)
-        self.state = shard_state(self.state, self.shardings)
+        self.state = self._init_state()
         self.step_fn = self._build()
         self.metrics_log: List[Dict[str, float]] = []
         self.balancer: Optional[WorkStealingBalancer] = None
+
+    def _init_state(self):
+        """Fresh state from ``seed``, created already sharded on the mesh."""
+        init = functools.partial(init_state, self.model, self.optimizer, n_pods=self._n_pods)
+        return jax.jit(init, out_shardings=self.shardings)(jax.random.PRNGKey(self.seed))
 
     def _build(self):
         return build_train_step(
@@ -128,7 +139,7 @@ class Trainer:
         """Run to global step ``n_steps`` with restart-on-failure."""
         restarts = 0
         t_loop = time.perf_counter()
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             while self.current_step() < n_steps:
                 step = self.current_step()
                 try:
@@ -136,7 +147,9 @@ class Trainer:
                         self.fault_hook(step)
                     batch = self._device_batch(self.pipeline.batch_at(step))
                     t0 = time.perf_counter()
-                    self.state, metrics = self.step_fn(self.state, batch)
+                    self.state, metrics = jax.block_until_ready(
+                        self.step_fn(self.state, batch)
+                    )
                     dt = time.perf_counter() - t0
                     if self.balancer is not None:
                         self.balancer.report(self.pipeline.dp_rank, dt)
@@ -149,20 +162,22 @@ class Trainer:
                     }
                     self.metrics_log.append(row)
                     if self.ckpt and self.cfg.ckpt_every and (step + 1) % self.cfg.ckpt_every == 0:
-                        self.ckpt.save(jax.tree.map(np.asarray, self.state), step + 1)
-                except RuntimeError as exc:
-                    # node failure: restore the latest published checkpoint
+                        t0 = time.perf_counter()
+                        info = self.ckpt.save(jax.tree.map(np.asarray, self.state), step + 1)
+                        # the stall: device→host copy plus the whole save
+                        self.metrics_log.append(
+                            {"step": step + 1, "event": "save",
+                             "seconds": time.perf_counter() - t0, **info}
+                        )
+                except NodeFailure as exc:
+                    # restore the latest published checkpoint
                     restarts += 1
                     if restarts > self.cfg.max_restarts or self.ckpt is None:
                         raise
                     latest = self.ckpt.latest_step()
                     if latest is None:
                         # no checkpoint yet: restart from scratch
-                        n_pods = self.mesh.shape.get("pod", 0) if self.cfg.cross_pod != "auto" else 0
-                        self.state = shard_state(
-                            init_state(self.model, self.optimizer, jax.random.PRNGKey(0), n_pods=n_pods),
-                            self.shardings,
-                        )
+                        self.state = self._init_state()
                     else:
                         self.state = self.ckpt.restore(
                             self._abstract, latest, shardings=self.shardings

@@ -34,7 +34,8 @@ def test_flash_attention_vs_ref(B, S, T, H, Kv, hd, causal, window, cap, bq, bk,
     k = jax.random.normal(ks[1], (B, T, Kv, hd), dt)
     v = jax.random.normal(ks[2], (B, T, Kv, hd), dt)
     out = flash_attention_pallas(
-        q, k, v, causal=causal, window=window, logit_softcap=cap, block_q=bq, block_kv=bk
+        q, k, v, causal=causal, window=window, logit_softcap=cap, block_q=bq, block_kv=bk,
+        interpret=True,
     )
     ref = attention_ref(q, k, v, causal=causal, window=window, logit_softcap=cap)
     np.testing.assert_allclose(
@@ -62,7 +63,7 @@ def test_wkv6_pallas_vs_ref(B, S, H, C, chunk):
     v = jax.random.normal(ks[2], (B, S, H, C))
     w = jax.nn.sigmoid(jax.random.normal(ks[3], (B, S, H, C))) * 0.5 + 0.45
     u = jax.random.normal(ks[4], (H, C)) * 0.1
-    out = wkv6_pallas(r, k, v, w, u, chunk=chunk)
+    out = wkv6_pallas(r, k, v, w, u, chunk=chunk, interpret=True)
     ref, _ = wkv6_ref(r, k, v, w, u)
     np.testing.assert_allclose(out, ref, atol=1e-4, rtol=1e-4)
 
@@ -90,7 +91,7 @@ def test_mamba_pallas_vs_ref(B, S, di, ds, chunk, bd):
     A = -jnp.exp(jax.random.normal(ks[2], (di, ds)) * 0.5)
     Bm = jax.random.normal(ks[3], (B, S, ds))
     Cm = jax.random.normal(ks[4], (B, S, ds))
-    y = mamba_scan_pallas(u, delta, A, Bm, Cm, chunk=chunk, block_d=bd)
+    y = mamba_scan_pallas(u, delta, A, Bm, Cm, chunk=chunk, block_d=bd, interpret=True)
     ref, _ = mamba_scan_ref(u, delta, A, Bm, Cm)
     np.testing.assert_allclose(y, ref, atol=1e-4, rtol=1e-4)
 

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import cost_analysis_dict
 from repro.roofline import parse_collectives, roofline
 from repro.roofline.hlo_loops import region_multipliers, split_regions
 from tests._multidev import run_multidev
@@ -27,8 +26,8 @@ def test_cost_analysis_counts_loops_once():
             x = x @ ws[i]
         return x
 
-    cs = cost_analysis_dict(jax.jit(scanned).lower(x, ws).compile())
-    cu = cost_analysis_dict(jax.jit(unrolled).lower(x, ws).compile())
+    cs = jax.jit(scanned).lower(x, ws).compile().cost_analysis()
+    cu = jax.jit(unrolled).lower(x, ws).compile().cost_analysis()
     assert cu["flops"] >= (N - 1) * cs["flops"]  # scan counted ~once
 
 
@@ -38,9 +37,9 @@ def test_loop_aware_collective_bytes():
         """
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.roofline import parse_collectives
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         N, D = 8, 64
         ws = jax.ShapeDtypeStruct((N, D, D), jnp.float32,
             sharding=NamedSharding(mesh, P(None, None, 'model')))
@@ -52,7 +51,7 @@ def test_loop_aware_collective_bytes():
                 return (c @ w) @ w.T, None   # all-reduce over model per step
             return jax.lax.scan(body, x, ws)[0].sum()
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             comp = jax.jit(scanned).lower(x, ws).compile()
         colls = parse_collectives(comp.as_text(), n_devices=8)
         in_loop = [c for c in colls if c.kind == 'all-reduce' and c.wire_bytes_per_chip > 0]
@@ -119,7 +118,7 @@ def test_analytic_matches_unrolled_cost():
     fn = jax.jit(
         lambda p, b: jax.value_and_grad(lambda pp: model.train_loss(pp, b, loss_chunk=S)[0])(p)
     )
-    cost = cost_analysis_dict(fn.lower(params_abs, batch_abs).compile())
+    cost = fn.lower(params_abs, batch_abs).compile().cost_analysis()
     analytic = cell_flops(cfg, shape)
     # loss-chunk scan has 1 trip at loss_chunk=S; flash scans have 1 block;
     # unit loop unrolled ⇒ cost_analysis sees everything.

@@ -5,10 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import set_mesh
 from repro.configs import ARCHS, smoke_variant
 from repro.configs.base import ShapeConfig
 from repro.core import Collaboration
+from repro.launch.mesh import make_mesh
 from repro.models.model import Model
 from repro.optim import AdamW, AdamWConfig, cosine_schedule, global_norm
 from repro.train import CheckpointManager
@@ -32,10 +32,10 @@ def test_microbatch_equivalence():
     state1 = init_state(model, opt, key)
     state4 = jax.tree.map(jnp.copy, state1)
     batch = model.make_batch(key, TINY)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     s1 = build_train_step(model, opt, mesh, microbatches=1, loss_chunk=16)
     s4 = build_train_step(model, opt, mesh, microbatches=4, loss_chunk=16)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state1, m1 = s1(state1, batch)
         state4, m4 = s4(state4, batch)
     np.testing.assert_allclose(float(m1["loss"]), float(m4["loss"]), rtol=1e-5)
@@ -47,14 +47,14 @@ def test_train_modes_agree_across_pods():
     out = run_multidev(
         """
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.configs import ARCHS, smoke_variant
         from repro.configs.base import ShapeConfig
         from repro.models.model import Model
         from repro.optim import AdamW, AdamWConfig
         from repro.train.step import build_train_step, init_state, state_shardings, shard_state
         from repro.distributed.sharding import batch_shardings
-        mesh = jax.make_mesh((2,2,2), ('pod','data','model'))
+        mesh = make_mesh((2,2,2), ('pod','data','model'))
         cfg = smoke_variant(ARCHS['codeqwen1.5-7b'])
         model = Model(cfg)
         opt = AdamW(AdamWConfig(peak_lr=1e-3, warmup_steps=2, total_steps=50))
@@ -70,7 +70,7 @@ def test_train_modes_agree_across_pods():
             batch = model.make_batch(key, tiny)
             bs = batch_shardings(jax.eval_shape(lambda: batch), mesh)
             batch = jax.tree.map(jax.device_put, batch, bs)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 for _ in range(3):
                     state, m = step(state, batch)
             res[mode] = float(m['loss'])
@@ -110,7 +110,7 @@ def test_checkpoint_restart_reproduces_uninterrupted_run(collab):
     pipe = ShardedPipeline(
         SyntheticLM(vocab_size=cfg.vocab_size, seq_len=32, period=8), global_batch=4
     )
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
 
     ckpt = CheckpointManager(collab, run="replay", home_dc="dc0")
     t_fail = Trainer(
@@ -125,6 +125,42 @@ def test_checkpoint_restart_reproduces_uninterrupted_run(collab):
     r2 = t_clean.run(10)
     assert r1["final_step"] == r2["final_step"] == 10
     np.testing.assert_allclose(r1["final_loss"], r2["final_loss"], rtol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "exc",
+    [
+        RuntimeError("device fault"),
+        jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory"),
+        ValueError("bad batch"),
+    ],
+    ids=lambda e: type(e).__name__,
+)
+def test_errors_other_than_the_injected_fault_propagate(collab, exc):
+    """With a checkpoint to fall back on, only a NodeFailure restores; a
+    device or runtime error surfaces at once."""
+    from repro.data import ShardedPipeline, SyntheticLM
+    from repro.train import Trainer, TrainerConfig
+
+    cfg, model, opt = _setup()
+    pipe = ShardedPipeline(
+        SyntheticLM(vocab_size=cfg.vocab_size, seq_len=32, period=8), global_batch=4
+    )
+
+    def hook(step):
+        if step == 2:
+            raise exc
+
+    trainer = Trainer(
+        model, opt, make_mesh((1,), ("data",)), pipe,
+        TrainerConfig(loss_chunk=16, ckpt_every=1),
+        ckpt=CheckpointManager(collab, run="propagate", home_dc="dc0"), fault_hook=hook,
+    )
+    with pytest.raises(type(exc)):
+        trainer.run(4)
+    assert trainer.ckpt.latest_step() == 2
+    assert not any("event" in m and m["event"] != "save" for m in trainer.metrics_log)
+    assert trainer.current_step() == 2
 
 
 def test_optimizer_convergence_quadratic():
