@@ -399,12 +399,18 @@ class Span:
 
 
 class SpanBuffer:
-    """Bounded span sink (deque; oldest spans age out first)."""
+    """Bounded span sink (deque; oldest spans age out first).
+
+    ``dropped`` counts the spans that aged out, so a reader can tell a
+    window that lost spans from a quiet one."""
 
     def __init__(self, maxlen: int = TRACE_BUFFER_SPANS):
         self._spans: "deque[Span]" = deque(maxlen=max(16, int(maxlen)))
+        self.dropped = 0
 
     def add(self, span: Span) -> None:
+        if len(self._spans) == self._spans.maxlen:
+            self.dropped += 1
         self._spans.append(span)
 
     def __len__(self) -> int:
@@ -418,6 +424,7 @@ class SpanBuffer:
 
     def clear(self) -> None:
         self._spans.clear()
+        self.dropped = 0
 
 
 # exception type name -> span status; by-name so core.telemetry stays
@@ -446,7 +453,7 @@ class _SpanCtx:
     """Context manager that pushes a span on the tracer's thread-local stack
     so nested spans (and RPC envelopes) parent to it."""
 
-    __slots__ = ("_tracer", "_name", "_parent", "_tags", "_span")
+    __slots__ = ("_tracer", "_name", "_parent", "_tags", "_span", "_region")
 
     def __init__(self, tracer: "Tracer", name: str, parent: Optional[Tuple[int, int]],
                  tags: Optional[Dict[str, Any]]):
@@ -454,6 +461,7 @@ class _SpanCtx:
         self._name = name
         self._parent = parent
         self._tags = tags
+        self._region = None
 
     def __enter__(self) -> Span:
         tr = self._tracer
@@ -461,11 +469,16 @@ class _SpanCtx:
         span = tr.start_span(self._name, parent=parent, tags=self._tags)
         self._span = span
         tr._stack().append(span)
+        if tr.region is not None:
+            self._region = tr.region(self._name)
+            self._region.__enter__()
         return span
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
         tr = self._tracer
         span = self._span
+        if self._region is not None:
+            self._region.__exit__(exc_type, exc, tb)
         stack = tr._stack()
         if stack and stack[-1] is span:
             stack.pop()
@@ -489,12 +502,24 @@ class Tracer:
       the context stack.
 
     ``enabled=False`` turns every entry point into a near-free no-op.
+
+    ``region`` is an optional ``region(name)`` context-manager factory held
+    open for the life of every ``span()`` context, so the span also lands on
+    another tool's timeline under the same name (a profiler annotation, for
+    one).  Without it ``span()`` does nothing more.
     """
 
-    def __init__(self, site: str, buffer: SpanBuffer, enabled: bool = True):
+    def __init__(
+        self,
+        site: str,
+        buffer: SpanBuffer,
+        enabled: bool = True,
+        region: Optional[Callable[[str], Any]] = None,
+    ):
         self.site = site
         self.buffer = buffer
         self.enabled = enabled
+        self.region = region
         self.last_trace: Optional[int] = None
         #: process-unique id base: ids are ``(site_number << 40) | counter``
         self._id_base = next(_SITE_IDS) << 40
@@ -582,7 +607,7 @@ class Tracer:
 
 class Telemetry:
     """Per-node / per-plane bundle: one registry + one span buffer + the
-    tracer that writes into it."""
+    tracer that writes into it (``region``: see :class:`Tracer`)."""
 
     def __init__(
         self,
@@ -591,6 +616,7 @@ class Telemetry:
         trace_enabled: Optional[bool] = None,
         trace_buffer_spans: Optional[int] = None,
         hist_buckets: Optional[int] = None,
+        region: Optional[Callable[[str], Any]] = None,
     ):
         self.site = site
         self.registry = MetricsRegistry(
@@ -600,7 +626,8 @@ class Telemetry:
             TRACE_BUFFER_SPANS if trace_buffer_spans is None else trace_buffer_spans
         )
         self.tracer = Tracer(
-            site, self.spans, enabled=TRACE_ENABLED if trace_enabled is None else trace_enabled
+            site, self.spans, enabled=TRACE_ENABLED if trace_enabled is None else trace_enabled,
+            region=region,
         )
 
     def add_collector(self, prefix: str, fn: Callable[[], Dict[str, Any]]) -> None:

@@ -64,10 +64,13 @@ from .scidata import (
     read_dataset_via,
     read_header_via,
     serialize_scidata,
-    write_scidata as _write_scidata_backend,
 )
+from .telemetry import SpanBuffer, Tracer
 
 __all__ = ["Workspace", "NativeSession", "WriteResult"]
+
+#: a native session's tracer when it is given none: records nothing
+_NO_TRACER = Tracer("native", SpanBuffer(), enabled=False)
 
 
 class WriteResult(int):
@@ -906,12 +909,17 @@ class NativeSession:
     No FUSE layer, no metadata RPCs — the paper's native-data-access path.
     Files written here are invisible in the workspace until
     :class:`~repro.core.meu.MEU` exports their metadata.
+
+    With a ``tracer``, :meth:`write_scidata` records its two phases as
+    spans: ``scidata.serialize`` and ``store.write`` (the backend write and
+    the de-sync xattr).
     """
 
-    def __init__(self, dc: DataCenter, collaborator: str):
+    def __init__(self, dc: DataCenter, collaborator: str, *, tracer: Optional[Tracer] = None):
         self.dc = dc
         self.backend: StorageBackend = dc.backend
         self.collaborator = collaborator
+        self.tracer = _NO_TRACER if tracer is None else tracer
 
     def write(self, path: str, data: bytes) -> int:
         path = _norm(path)
@@ -937,11 +945,12 @@ class NativeSession:
 
     def write_scidata(self, path: str, arrays: Dict[str, np.ndarray], attrs: Dict[str, Any]) -> int:
         path = _norm(path)
-        n = _write_scidata_backend(
-            self.backend, path, arrays, attrs, owner=self.collaborator
-        )
-        self._desync(path)
-        return n
+        with self.tracer.span("scidata.serialize"):
+            blob = serialize_scidata(arrays, attrs)
+        with self.tracer.span("store.write", bytes=len(blob)):
+            self.backend.write(path, blob, owner=self.collaborator)
+            self._desync(path)
+        return len(blob)
 
     def offline_index(self, paths: List[str], attr_filter: Optional[List[str]] = None) -> int:
         """LW-Offline extraction on the local DC's DTNs (§III-B5)."""

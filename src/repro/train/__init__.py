@@ -2,6 +2,7 @@
 
 from .checkpoint import CheckpointManager
 from .step import build_train_step, init_state, init_state_abstract, shard_state, state_shardings
+from .telemetry import TELEMETRY
 from .trainer import FaultInjector, NodeFailure, Trainer, TrainerConfig
 
 __all__ = [
@@ -11,6 +12,7 @@ __all__ = [
     "init_state_abstract",
     "shard_state",
     "state_shardings",
+    "TELEMETRY",
     "FaultInjector",
     "NodeFailure",
     "Trainer",

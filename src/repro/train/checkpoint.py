@@ -27,9 +27,7 @@ topology) is therefore reshard-on-load by construction.
 
 from __future__ import annotations
 
-import io
 import json
-import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -37,7 +35,9 @@ import jax
 import numpy as np
 
 from repro.core.meu import MEU
+from repro.core.scidata import read_header
 from repro.core.workspace import NativeSession, Workspace
+from repro.train.telemetry import TELEMETRY, span
 
 __all__ = ["CheckpointManager", "CheckpointInfo"]
 
@@ -94,6 +94,10 @@ class CheckpointManager:
         self.n_shards = n_shards
         self.base = base.rstrip("/")
         self.collaborator = collaborator
+        # saves and restores report into the train layer's bundle (see
+        # repro.train.telemetry); the collaboration's trace tools find its
+        # spans beside the workspace's
+        collab.register_telemetry(TELEMETRY)
         # workspace mode indexes inline (the paper's Inline-Sync write path);
         # native mode indexes offline after the MEU export (LW-Offline).
         # A restore reads each shard byte once, so remote shards bypass the
@@ -105,7 +109,7 @@ class CheckpointManager:
             chunk_cache_bytes=0,
             readahead=False,
         )
-        self.native = NativeSession(collab.dc(home_dc), collaborator)
+        self.native = NativeSession(collab.dc(home_dc), collaborator, tracer=TELEMETRY.tracer)
         self.meu = MEU(collab, collab.dc(home_dc), collaborator)
 
     # -- save -------------------------------------------------------------------
@@ -141,46 +145,41 @@ class CheckpointManager:
         return f"{self.base}/{self.run}/step{step:08d}/shard{shard}.sci"
 
     def save(self, state, step: int) -> Dict[str, float]:
-        """Returns timing/accounting for the benchmark harness."""
-        t0 = time.perf_counter()
-        payloads = self._shard_payloads(state)
-        t_pack = time.perf_counter() - t0
-
-        t1 = time.perf_counter()
-        total_bytes = 0
-        for s, (arrays, attrs) in enumerate(payloads):
-            attrs = dict(attrs, step=step)
-            path = self._path(step, s)
-            if self.mode == "workspace":
-                total_bytes += self.ws.write_scidata(path, arrays, attrs)
-            else:
-                total_bytes += self.native.write_scidata(path, arrays, attrs)
-        t_write = time.perf_counter() - t1
-
-        t2 = time.perf_counter()
-        export_report = None
-        if self.mode == "native":
-            # one batched metadata export publishes the new step (§III-B3)
-            export_report = self.meu.export(f"{self.base}/{self.run}")
-            # LW-Offline indexing so the step is SDS-discoverable (§III-B5)
-            paths = [self._path(step, s) for s in range(self.n_shards)]
-            self.collab.dc(self.home_dc).offline_index(paths)
-        # workspace mode indexed inline during the writes (Inline-Sync)
-        t_publish = time.perf_counter() - t2
-
+        """Write ``state`` as step ``step``; returns its size and the
+        seconds of its spans (``write_s`` sums the shards' ``ckpt.write``)."""
+        with span("ckpt.save", step=step) as sp_save:
+            with span("ckpt.pack"):
+                payloads = self._shard_payloads(state)
+            writer = self.ws if self.mode == "workspace" else self.native
+            total_bytes, write_s = 0, 0.0
+            for s, (arrays, attrs) in enumerate(payloads):
+                with span("ckpt.write", shard=s) as sp:
+                    n = writer.write_scidata(self._path(step, s), arrays, dict(attrs, step=step))
+                    sp.tags["bytes"] = n
+                total_bytes += n
+                write_s += sp.end - sp.start
+            with span("ckpt.publish") as sp_publish:
+                if self.mode == "native":
+                    # one batched metadata export publishes the new step (§III-B3)
+                    with span("meu.export"):
+                        self.meu.export(f"{self.base}/{self.run}")
+                    # LW-Offline indexing so the step is SDS-discoverable (§III-B5)
+                    with span("ckpt.index"):
+                        paths = [self._path(step, s) for s in range(self.n_shards)]
+                        self.collab.dc(self.home_dc).offline_index(paths)
+                # workspace mode indexed inline during the writes (Inline-Sync)
         return {
             "bytes": float(total_bytes),
-            "pack_s": t_pack,
-            "write_s": t_write,
-            "publish_s": t_publish,
-            "total_s": t_pack + t_write + t_publish,
-            "meu_rpcs": float(export_report.rpc_calls) if export_report else 0.0,
+            "write_s": write_s,
+            "publish_s": sp_publish.end - sp_publish.start,
+            "total_s": sp_save.end - sp_save.start,
         }
 
     # -- discovery + restore -------------------------------------------------------
     def list_steps(self) -> List[int]:
         """SDS attribute query — no directory crawling (§III-B5)."""
-        rows = self.ws.search(f"run = {self.run}")
+        with span("ckpt.discover"):
+            rows = self.ws.search(f"run = {self.run}")
         steps = sorted({int(r["attrs"]["step"]) for r in rows if "step" in r.get("attrs", {})})
         return steps
 
@@ -189,47 +188,67 @@ class CheckpointManager:
         return steps[-1] if steps else None
 
     def restore(self, state_like, step: Optional[int] = None, *, shardings=None):
-        """Rebuild a state pytree; reshard-on-load when ``shardings`` given."""
+        """Rebuild a state pytree; reshard-on-load when ``shardings`` given.
+
+        Three phases under one ``ckpt.restore`` span: read every shard;
+        assemble each leaf on the host and start its copy to the devices
+        (``ckpt.put``); wait for the copies (the returned tree is ready)."""
         if step is None:
             step = self.latest_step()
             if step is None:
                 raise FileNotFoundError(f"no checkpoints for run {self.run!r}")
-        # read every shard through the workspace (any pod can restore any run)
-        shard_arrays: List[Dict[str, np.ndarray]] = []
-        split_axes: Dict[str, int] = {}
-        for s in range(self.n_shards):
-            path = self._path(step, s)
-            attrs = self.ws.read_attrs(path)
-            split_axes = json.loads(attrs["split_axes"])
-            arrays = {}
-            from repro.core.scidata import read_header
+        traced = TELEMETRY.tracer.enabled
+        with span("ckpt.restore", step=step, shards=self.n_shards) as sp_restore:
+            # read every shard through the workspace (any pod can restore any run)
+            shard_arrays: List[Dict[str, np.ndarray]] = []
+            split_axes: Dict[str, int] = {}
+            total_bytes = 0
+            datapath = self.ws.datapath
+            for s in range(self.n_shards):
+                with span("ckpt.read", shard=s) as sp:
+                    wire0 = datapath.wire_seconds
+                    path = self._path(step, s)
+                    attrs = self.ws.read_attrs(path)
+                    split_axes = json.loads(attrs["split_axes"])
+                    entry = self.ws.stat(path)
+                    dc = self.collab.dc(entry["dc_id"])
+                    hdr = read_header(dc.backend, path)
+                    arrays = {d["name"]: self.ws.read_dataset(path, d["name"]) for d in hdr.datasets}
+                    if traced:
+                        sp.tags["bytes"] = sum(a.nbytes for a in arrays.values())
+                        sp.wire_s = datapath.wire_seconds - wire0
+                        total_bytes += sp.tags["bytes"]
+                shard_arrays.append(arrays)
 
-            entry = self.ws.stat(path)
-            dc = self.collab.dc(entry["dc_id"])
-            hdr = read_header(dc.backend, path)
-            for d in hdr.datasets:
-                arrays[d["name"]] = self.ws.read_dataset(path, d["name"])
-            shard_arrays.append(arrays)
-
-        leaves = _flatten_with_paths(state_like)
-        treedef = jax.tree_util.tree_structure(state_like)
-        targets = (
-            treedef.flatten_up_to(shardings) if shardings is not None else [None] * len(leaves)
-        )
-        rebuilt = []
-        for (path, like), target in zip(leaves, targets):
-            # pop each shard's piece and place each leaf as soon as it is
-            # rebuilt: the host holds the read shards plus one leaf, never a
-            # second whole copy of the state
-            ax = split_axes[path]
-            if ax < 0:
-                arr = shard_arrays[0].pop(path)
-            else:
-                arr = np.concatenate([sa.pop(path) for sa in shard_arrays], axis=ax)
-            if hasattr(like, "shape"):
-                # scidata stores 0-d arrays as [1] (ascontiguousarray quirk)
-                arr = arr.reshape(like.shape)
-            if hasattr(like, "dtype"):
-                arr = arr.astype(like.dtype, copy=False)
-            rebuilt.append(arr if target is None else jax.device_put(arr, target))
+            leaves = _flatten_with_paths(state_like)
+            treedef = jax.tree_util.tree_structure(state_like)
+            targets = (
+                treedef.flatten_up_to(shardings) if shardings is not None else [None] * len(leaves)
+            )
+            with span("ckpt.assemble"):
+                # pop each shard's piece and start each leaf's copy to its
+                # device as soon as the leaf is rebuilt: the copies overlap
+                # the next leaves' assembly, and the host holds the read
+                # shards plus one leaf, never a second whole copy of the state
+                rebuilt = []
+                for (path, like), target in zip(leaves, targets):
+                    ax = split_axes[path]
+                    if ax < 0:
+                        arr = shard_arrays[0].pop(path)
+                    else:
+                        arr = np.concatenate([sa.pop(path) for sa in shard_arrays], axis=ax)
+                    if hasattr(like, "shape"):
+                        # scidata stores 0-d arrays as [1] (ascontiguousarray quirk)
+                        arr = arr.reshape(like.shape)
+                    if hasattr(like, "dtype"):
+                        arr = arr.astype(like.dtype, copy=False)
+                    if target is not None:
+                        with span("ckpt.put"):
+                            arr = jax.device_put(arr, target)
+                    rebuilt.append(arr)
+            with span("ckpt.place"):
+                # the copies the assembly did not hide
+                rebuilt = jax.block_until_ready(rebuilt)
+            if traced:
+                sp_restore.tags["bytes"] = total_bytes
         return jax.tree_util.tree_unflatten(treedef, rebuilt)

@@ -1,0 +1,67 @@
+"""The train layer's telemetry: spans of steps, saves and restores.
+
+One :class:`~repro.core.telemetry.Telemetry` bundle, :data:`TELEMETRY`,
+takes the spans of every :class:`~repro.train.Trainer` and
+:class:`~repro.train.CheckpointManager`, so a save's spans nest under the
+loop iteration that triggered it.  Every span also opens a
+``jax.profiler.TraceAnnotation`` of its name, which puts it on the
+profiler's host timeline, on the same clock as the device ops, whenever a
+trace is taken.
+
+Spans (parent > children; tags in brackets):
+
+- ``train.iter`` > ``train.batch`` [bytes], ``train.step``, ``ckpt.d2h``
+  [bytes], ``ckpt.save``: one iteration of ``Trainer.run``; ``train.batch``
+  is ``batch_at`` plus ``device_put``, ``train.step`` the dispatch through
+  ``block_until_ready``, ``ckpt.d2h`` the state's copy to the host;
+- ``ckpt.save`` [step] > ``ckpt.pack``, ``ckpt.write`` [shard, bytes],
+  ``ckpt.publish``; ``ckpt.write`` > ``scidata.serialize``, ``store.write``
+  [bytes] (native mode); ``ckpt.publish`` > ``meu.export``, ``ckpt.index``;
+- ``ckpt.discover``: the SDS query for the run's steps;
+- ``ckpt.restore`` [step, shards, bytes] > ``ckpt.read`` [shard, bytes;
+  ``wire_s`` the data path's modeled seconds], ``ckpt.assemble`` (every
+  leaf rebuilt on the host) > ``ckpt.put`` (one leaf's ``device_put``),
+  then ``ckpt.place`` (the wait for the copies the assembly did not hide).
+
+With the tracer off nothing is recorded or annotated and no tag is
+computed; :func:`span` still times its block, since the trainer's rows and
+its straggler balancer take their seconds from it.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+
+from repro.core.telemetry import Span, Telemetry, now
+
+__all__ = ["TELEMETRY", "span"]
+
+#: the train package's bundle
+TELEMETRY = Telemetry("train", region=jax.profiler.TraceAnnotation)
+
+
+class _Unrecorded:
+    """A span timed on the session clock and kept nowhere (tracer off)."""
+
+    __slots__ = ("_span",)
+
+    def __init__(self, name: str, tags: dict):
+        self._span = Span(0, 0, None, name, "", 0.0, tags or None)
+
+    def __enter__(self) -> Span:
+        self._span.start = now()
+        return self._span
+
+    def __exit__(self, *exc: Any) -> None:
+        self._span.end = now()
+
+
+def span(name: str, **tags: Any):
+    """``with span(name, **tags) as sp:`` — a span of :data:`TELEMETRY`'s
+    tracer, or an unrecorded but timed one when the tracer is off."""
+    tracer = TELEMETRY.tracer
+    if tracer.enabled:
+        return tracer.span(name, **tags)
+    return _Unrecorded(name, tags)

@@ -23,7 +23,6 @@ restore: any other error (a device OOM, a runtime fault) propagates.
 from __future__ import annotations
 
 import functools
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
@@ -41,6 +40,7 @@ from repro.train.step import (
     shard_state,
     state_shardings,
 )
+from repro.train.telemetry import TELEMETRY, span
 
 __all__ = ["Trainer", "TrainerConfig", "FaultInjector", "NodeFailure"]
 
@@ -135,60 +135,72 @@ class Trainer:
     def current_step(self) -> int:
         return int(self.state["step"])
 
+    def _step(self, step: int) -> None:
+        """Take step ``step + 1`` and save after it when due; its rows take
+        their seconds from the spans (see repro.train.telemetry)."""
+        if self.fault_hook is not None:
+            self.fault_hook(step)
+        with span("train.batch") as sp:
+            batch_np = self.pipeline.batch_at(step)
+            batch = self._device_batch(batch_np)
+            if TELEMETRY.tracer.enabled:
+                sp.tags = {"bytes": sum(x.nbytes for x in jax.tree.leaves(batch_np))}
+        with span("train.step") as sp:
+            self.state, metrics = jax.block_until_ready(self.step_fn(self.state, batch))
+        dt = sp.end - sp.start
+        if self.balancer is not None:
+            self.balancer.report(self.pipeline.dp_rank, dt)
+        row = {
+            "step": step + 1,
+            "loss": float(metrics["loss"]),
+            "grad_norm": float(metrics["grad_norm"]),
+            "lr": float(metrics["lr"]),
+            "seconds": dt,
+        }
+        self.metrics_log.append(row)
+        if self.ckpt and self.cfg.ckpt_every and (step + 1) % self.cfg.ckpt_every == 0:
+            with span("ckpt.d2h") as sp:
+                host = jax.tree.map(np.asarray, self.state)
+            if TELEMETRY.tracer.enabled:
+                sp.tags = {"bytes": sum(x.nbytes for x in jax.tree.leaves(host))}
+            info = self.ckpt.save(host, step + 1)
+            del host
+            # the stall: device→host copy plus the whole save
+            self.metrics_log.append(
+                {"step": step + 1, "event": "save", "seconds": sp.end - sp.start + info["total_s"], **info}
+            )
+
     def run(self, n_steps: int) -> Dict[str, Any]:
-        """Run to global step ``n_steps`` with restart-on-failure."""
+        """Run to global step ``n_steps`` with restart-on-failure.
+
+        Each loop iteration is a ``train.iter`` span."""
         restarts = 0
-        t_loop = time.perf_counter()
         with jax.set_mesh(self.mesh):
-            while self.current_step() < n_steps:
-                step = self.current_step()
-                try:
-                    if self.fault_hook is not None:
-                        self.fault_hook(step)
-                    batch = self._device_batch(self.pipeline.batch_at(step))
-                    t0 = time.perf_counter()
-                    self.state, metrics = jax.block_until_ready(
-                        self.step_fn(self.state, batch)
-                    )
-                    dt = time.perf_counter() - t0
-                    if self.balancer is not None:
-                        self.balancer.report(self.pipeline.dp_rank, dt)
-                    row = {
-                        "step": step + 1,
-                        "loss": float(metrics["loss"]),
-                        "grad_norm": float(metrics["grad_norm"]),
-                        "lr": float(metrics["lr"]),
-                        "seconds": dt,
-                    }
-                    self.metrics_log.append(row)
-                    if self.ckpt and self.cfg.ckpt_every and (step + 1) % self.cfg.ckpt_every == 0:
-                        t0 = time.perf_counter()
-                        info = self.ckpt.save(jax.tree.map(np.asarray, self.state), step + 1)
-                        # the stall: device→host copy plus the whole save
+            step = self.current_step()
+            while step < n_steps:
+                with span("train.iter", step=step + 1):
+                    try:
+                        self._step(step)
+                    except NodeFailure as exc:
+                        # restore the latest published checkpoint
+                        restarts += 1
+                        if restarts > self.cfg.max_restarts or self.ckpt is None:
+                            raise
+                        latest = self.ckpt.latest_step()
+                        if latest is None:
+                            # no checkpoint yet: restart from scratch
+                            self.state = self._init_state()
+                        else:
+                            self.state = self.ckpt.restore(
+                                self._abstract, latest, shardings=self.shardings
+                            )
                         self.metrics_log.append(
-                            {"step": step + 1, "event": "save",
-                             "seconds": time.perf_counter() - t0, **info}
+                            {"step": self.current_step(), "event": f"restart({exc})"}
                         )
-                except NodeFailure as exc:
-                    # restore the latest published checkpoint
-                    restarts += 1
-                    if restarts > self.cfg.max_restarts or self.ckpt is None:
-                        raise
-                    latest = self.ckpt.latest_step()
-                    if latest is None:
-                        # no checkpoint yet: restart from scratch
-                        self.state = self._init_state()
-                    else:
-                        self.state = self.ckpt.restore(
-                            self._abstract, latest, shardings=self.shardings
-                        )
-                    self.metrics_log.append(
-                        {"step": self.current_step(), "event": f"restart({exc})"}
-                    )
+                    step = self.current_step()
         return {
-            "final_step": self.current_step(),
+            "final_step": step,
             "restarts": restarts,
-            "wall_s": time.perf_counter() - t_loop,
             "final_loss": next(
                 (m["loss"] for m in reversed(self.metrics_log) if "loss" in m), None
             ),
