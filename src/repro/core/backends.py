@@ -103,6 +103,14 @@ class StorageBackend:
     def read(self, path: str, *, offset: int = 0, length: int = -1) -> bytes:
         raise NotImplementedError
 
+    def adopt(self, path: str, data: bytearray, *, owner: str = "") -> int:
+        """A full rewrite of ``path`` with a buffer the caller hands over and
+        never touches again; returns the bytes the store copied.  A store
+        that can keep ``data`` itself as the file's content copies none;
+        this default copies it through :meth:`write`."""
+        self.write(path, data, owner=owner)
+        return len(data)
+
     # -- deferred variants (data-plane pipelining) ---------------------------
     # The simulated PFS delay (store_delay_for) is normally slept inside
     # read/write.  The striped data path overlaps store fetches with wire
@@ -247,28 +255,47 @@ class MemoryBackend(StorageBackend):
             time.sleep(delay)
         return n
 
+    def _file_locked(self, path: str, owner: str) -> bytearray:
+        """The file a write lands in, created (and its parents) if missing;
+        its mtime set to now.  Call with the lock held."""
+        self._require_parent(path)
+        buf = self._files.get(path)
+        now = time.time()
+        if not isinstance(buf, bytearray):
+            buf = self._files[path] = bytearray()
+            self._meta[path] = {"ctime": now, "mtime": now, "owner": owner}
+        self._meta[path]["mtime"] = now
+        return buf
+
     def write_deferred(
         self, path: str, data: bytes, *, offset: int = 0, owner: str = ""
     ) -> Tuple[int, float]:
         path = _norm(path)
         with self._lock:
-            self._require_parent(path)
-            buf = self._files.get(path)
-            now = time.time()
-            if buf is None or not isinstance(buf, bytearray):
-                buf = bytearray()
-                self._files[path] = buf
-                self._meta[path] = {"ctime": now, "mtime": now, "owner": owner}
+            buf = self._file_locked(path, owner)
             if offset > len(buf):
                 buf.extend(b"\x00" * (offset - len(buf)))
             buf[offset : offset + len(data)] = data
             if offset == 0:
                 # full rewrite: drop any stale tail (O_TRUNC semantics)
                 del buf[len(data):]
-            self._meta[path]["mtime"] = now
             self._bytes_written += len(data)
         self.invalidate_sync_up(path)
         return len(data), self.store_delay_for(len(data))
+
+    def adopt(self, path: str, data: bytearray, *, owner: str = "") -> int:
+        """A full rewrite that keeps ``data`` itself as the file: no copy
+        (anything but a ``bytearray`` is copied in, as by ``write``)."""
+        if type(data) is not bytearray:
+            return super().adopt(path, data, owner=owner)
+        path = _norm(path)
+        with self._lock:
+            self._file_locked(path, owner)
+            self._files[path] = data
+            self._bytes_written += len(data)
+        self.invalidate_sync_up(path)
+        self._store_delay(len(data))
+        return 0
 
     def read(self, path: str, *, offset: int = 0, length: int = -1) -> bytes:
         out, delay = self.read_deferred(path, offset=offset, length=length)

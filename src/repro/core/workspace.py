@@ -911,8 +911,10 @@ class NativeSession:
     :class:`~repro.core.meu.MEU` exports their metadata.
 
     With a ``tracer``, :meth:`write_scidata` records its two phases as
-    spans: ``scidata.serialize`` and ``store.write`` (the backend write and
-    the de-sync xattr).
+    spans: ``scidata.serialize`` (the one-pass build of the container) and
+    ``store.write`` (the hand-over of the blob to the store, tagged with the
+    ``bytes`` it holds and the bytes the store ``copied``, and the de-sync
+    xattr).
     """
 
     def __init__(self, dc: DataCenter, collaborator: str, *, tracer: Optional[Tracer] = None):
@@ -947,10 +949,13 @@ class NativeSession:
         path = _norm(path)
         with self.tracer.span("scidata.serialize"):
             blob = serialize_scidata(arrays, attrs)
-        with self.tracer.span("store.write", bytes=len(blob)):
-            self.backend.write(path, blob, owner=self.collaborator)
+        n = len(blob)
+        with self.tracer.span("store.write", bytes=n):
+            # the store keeps the fresh blob; ``copied`` is what it copied
+            copied = self.backend.adopt(path, blob, owner=self.collaborator)
+            self.tracer.annotate(copied=copied)
             self._desync(path)
-        return len(blob)
+        return n
 
     def offline_index(self, paths: List[str], attr_filter: Optional[List[str]] = None) -> int:
         """LW-Offline extraction on the local DC's DTNs (§III-B5)."""

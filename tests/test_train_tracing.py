@@ -4,8 +4,9 @@
   the span tree the module documents, with parent links: each save nests
   under the loop iteration that triggered it, and each save and each
   restore is one trace;
-- the byte and shard tags equal what the rows and the state say, and every
-  timing in the rows is the duration of its span;
+- the byte and shard tags equal what the rows and the state say, the
+  in-memory store copied none of a shard's bytes (it kept the container
+  as built), and every timing in the rows is the duration of its span;
 - with the tracer off nothing is buffered, and the rows and the restored
   state are those of a traced run;
 - the spans also land in the JAX profiler's trace, inside an enclosing
@@ -99,7 +100,10 @@ def test_spans_form_the_documented_tree(traced):
         assert [w.tags["shard"] for w in writes] == list(range(SHARDS))
         for w in writes:
             assert names(w) == ["scidata.serialize", "store.write"]
-            assert kids[w.span_id][1].tags["bytes"] == w.tags["bytes"] > 0
+            store = kids[w.span_id][1]
+            assert store.tags["bytes"] == w.tags["bytes"] > 0
+            # the in-memory store kept the built container: nothing copied
+            assert store.tags["copied"] == 0
         assert names(kids[save.span_id][-1]) == ["meu.export", "ckpt.index"]
     restore = roots[-1]
     assert names(restore) == ["ckpt.read"] * SHARDS + ["ckpt.assemble", "ckpt.place"]
