@@ -1,4 +1,4 @@
-"""Config registry: the ten assigned architectures + the four shape cells.
+"""Config registry: the architectures + the four shape cells.
 
 Selection surface for every launcher/benchmark: ``--arch <id>`` resolves
 through :func:`get_config`; :func:`applicable_shapes` encodes the
@@ -11,6 +11,7 @@ from typing import Dict, List, Tuple
 
 from .base import (
     LayerSpec,
+    MLASpec,
     MambaSpec,
     ModelConfig,
     MoESpec,
@@ -24,6 +25,7 @@ from .gemma2_2b import CONFIG as _gemma2
 from .internvl2_2b import CONFIG as _internvl2
 from .jamba_v0_1_52b import CONFIG as _jamba
 from .llama4_maverick_400b import CONFIG as _llama4
+from .moonlight_16b_a3b import CONFIG as _moonlight
 from .nemotron_4_15b import CONFIG as _nemotron
 from .olmoe_1b_7b import CONFIG as _olmoe
 from .rwkv6_7b import CONFIG as _rwkv6
@@ -41,6 +43,7 @@ __all__ = [
     "ShapeConfig",
     "LayerSpec",
     "MoESpec",
+    "MLASpec",
     "MambaSpec",
     "RWKVSpec",
 ]
@@ -58,6 +61,7 @@ ARCHS: Dict[str, ModelConfig] = {
         _llama4,
         _olmoe,
         _internvl2,
+        _moonlight,
     )
 }
 

@@ -20,6 +20,7 @@ from typing import Dict, Optional, Tuple
 
 __all__ = [
     "MoESpec",
+    "MLASpec",
     "MambaSpec",
     "RWKVSpec",
     "LayerSpec",
@@ -32,13 +33,42 @@ __all__ = [
 
 @dataclass(frozen=True)
 class MoESpec:
-    n_experts: int
+    n_experts: int                # experts the router scores
     top_k: int
     d_ff: int                     # per-expert hidden width
-    shared_expert: bool = False   # Llama4-style always-on expert
+    n_shared: int = 0             # always-on experts: one MLP of n_shared × d_ff
     router_jitter: float = 0.0
     load_balance_coef: float = 0.01
     capacity_factor: float = 1.25  # per-expert slots = ceil(S·K·cf/E)
+    scoring: str = "softmax"      # 'softmax' | 'sigmoid'
+    routed_scale: float = 1.0     # renormalised top-k weights × this
+    balance: str = "switch"       # 'switch' (top-1, batch) | 'seq' (DeepSeek-V3, per row)
+    bias_rate: float = 0.0        # > 0: a choice-only correction bias, b += rate·sign(mean load − load)
+    n_held: int = 0               # experts held here (expert parallelism); 0 ⇒ all
+    first_held: int = 0           # id of the first held expert
+
+    def __post_init__(self):
+        assert self.scoring in ("softmax", "sigmoid"), self.scoring
+        assert self.balance in ("switch", "seq"), self.balance
+        assert 0 <= self.first_held and self.first_held + self.held <= self.n_experts
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class MLASpec:
+    """Multi-head latent attention (DeepSeek-V2/V3), without query compression."""
+
+    kv_lora_rank: int             # width of the shared key/value latent
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int         # rotary key dims, shared by every head
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclass(frozen=True)
@@ -58,11 +88,11 @@ class RWKVSpec:
 class LayerSpec:
     """One position in the repeating pattern unit."""
 
-    mixer: str  # 'attn' | 'attn_local' | 'mamba' | 'rwkv'
+    mixer: str  # 'attn' | 'attn_local' | 'mla' | 'mamba' | 'rwkv'
     ffn: str    # 'dense' | 'moe' | 'rwkv_cmix'
 
     def __post_init__(self):
-        assert self.mixer in ("attn", "attn_local", "mamba", "rwkv"), self.mixer
+        assert self.mixer in ("attn", "attn_local", "mla", "mamba", "rwkv"), self.mixer
         assert self.ffn in ("dense", "moe", "rwkv_cmix"), self.ffn
 
 
@@ -74,6 +104,7 @@ class ModelConfig:
     n_layers: int
     pattern: Tuple[LayerSpec, ...]
     vocab_size: int
+    lead: Tuple[LayerSpec, ...] = ()  # layers run once, before the repeated pattern
     n_heads: int = 0
     n_kv_heads: int = 0
     head_dim: int = 0              # 0 ⇒ d_model // n_heads
@@ -91,6 +122,7 @@ class ModelConfig:
     post_block_norm: bool = False  # Gemma2 sandwich norms
     tie_embeddings: bool = True
     moe: Optional[MoESpec] = None
+    mla: Optional[MLASpec] = None
     mamba: Optional[MambaSpec] = None
     rwkv: Optional[RWKVSpec] = None
     # encoder-decoder (Seamless backbone): n_layers is the decoder depth
@@ -117,23 +149,26 @@ class ModelConfig:
     sub_quadratic: bool = False    # eligible for long_500k
 
     def __post_init__(self):
-        assert self.n_layers % len(self.pattern) == 0, (
-            f"{self.name}: n_layers={self.n_layers} not a multiple of "
-            f"pattern len {len(self.pattern)}"
+        assert (self.n_layers - len(self.lead)) % len(self.pattern) == 0, (
+            f"{self.name}: n_layers={self.n_layers} less {len(self.lead)} leading "
+            f"layers not a multiple of pattern len {len(self.pattern)}"
         )
-        if any(s.mixer in ("attn", "attn_local") for s in self.pattern):
+        layers = self.lead + self.pattern
+        if any(s.mixer in ("attn", "attn_local", "mla") for s in layers):
             assert self.n_heads > 0 and self.n_kv_heads > 0
-        if any(s.ffn == "moe" for s in self.pattern):
+        if any(s.mixer == "mla" for s in layers):
+            assert self.mla is not None
+        if any(s.ffn == "moe" for s in layers):
             assert self.moe is not None
-        if any(s.mixer == "mamba" for s in self.pattern):
+        if any(s.mixer == "mamba" for s in layers):
             assert self.mamba is not None
-        if any(s.mixer == "rwkv" for s in self.pattern):
+        if any(s.mixer == "rwkv" for s in layers):
             assert self.rwkv is not None
 
     # -- derived ---------------------------------------------------------------
     @property
     def n_units(self) -> int:
-        return self.n_layers // len(self.pattern)
+        return (self.n_layers - len(self.lead)) // len(self.pattern)
 
     @property
     def resolved_head_dim(self) -> int:
@@ -175,7 +210,7 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
     """
     kw: Dict = dict(
         d_model=64,
-        n_layers=len(cfg.pattern),   # one unit
+        n_layers=len(cfg.lead) + len(cfg.pattern),   # the leading layers and one unit
         d_ff=128,
         vocab_size=512,
         dtype="float32",
@@ -190,12 +225,11 @@ def smoke_variant(cfg: ModelConfig) -> ModelConfig:
         kw["n_kv_heads"] = max(1, 4 * cfg.n_kv_heads // max(cfg.n_heads, 1))
         kw["head_dim"] = 16
     if cfg.moe is not None:
-        kw["moe"] = MoESpec(
-            n_experts=4,
-            top_k=min(cfg.moe.top_k, 2),
-            d_ff=64,
-            shared_expert=cfg.moe.shared_expert,
+        kw["moe"] = dataclasses.replace(
+            cfg.moe, n_experts=4, top_k=min(cfg.moe.top_k, 2), d_ff=64, n_held=0, first_held=0,
         )
+    if cfg.mla is not None:
+        kw["mla"] = MLASpec(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
     if cfg.mamba is not None:
         kw["mamba"] = MambaSpec(d_state=8, d_conv=4, expand=2)
     if cfg.rwkv is not None:

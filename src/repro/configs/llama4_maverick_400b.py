@@ -22,6 +22,6 @@ CONFIG = ModelConfig(
     activation="swiglu",
     norm="rmsnorm",
     rope_theta=500_000.0,
-    moe=MoESpec(n_experts=128, top_k=1, d_ff=8192, shared_expert=True),
+    moe=MoESpec(n_experts=128, top_k=1, d_ff=8192, n_shared=1),
     tie_embeddings=False,
 )

@@ -92,6 +92,9 @@ _RULES: Sequence[Tuple[str, List[Tuple[int, str]]]] = (
     (r"(mixer|self_attn|cross_attn|attn)/wk/w$", [(1, "model"), (2, "model")]),
     (r"(mixer|self_attn|cross_attn|attn)/wv/w$", [(1, "model"), (2, "model")]),
     (r"(mixer|self_attn|cross_attn|attn)/w[qkv]/b$", [(0, "model"), (1, "model")]),
+    # MLA: the key/value expansion is per head; the latent projection is small
+    (r"mixer/wkv_b/w$",                    [(1, "model")]),
+    (r"mixer/wkv_a/w$",                    []),
     # attention output: row-parallel (heads are the contraction dim)
     (r"(mixer|self_attn|cross_attn|attn)/wo/w$", [(0, "model"), (1, "model")]),
     # dense MLP: column-parallel in, row-parallel out
@@ -231,10 +234,11 @@ def cache_shardings(cache_abstract, mesh: Mesh, *, batch: int):
         leaf_name = path.split("/")[-1]
         spec: List[Optional[Any]] = [None] * leaf.ndim
         # layout: [L, B, T, Kv, hd] (attn) / [L, B, K-1, di] (conv) /
-        #         [L, B, di, ds] (ssm) / [L, B, H, C, C] (wkv) / [L,B,1,D]
+        #         [L, B, di, ds] (ssm) / [L, B, H, C, C] (wkv) / [L,B,1,D] /
+        #         [L, B, T, latent or rope] (mla: c, k_pe)
         if leaf.ndim >= 2 and batch % dp == 0 and batch >= dp:
             spec[1] = ax if len(ax) > 1 else ax[0]
-        elif leaf_name in ("k", "v", "self_k", "self_v", "cross_k", "cross_v"):
+        elif leaf_name in ("k", "v", "self_k", "self_v", "cross_k", "cross_v", "c", "k_pe"):
             # CP: shard the cache sequence dim (index 2) when batch can't split
             if leaf.ndim >= 3 and leaf.shape[2] % cp == 0 and leaf.shape[2] >= cp:
                 spec[2] = cp_axis

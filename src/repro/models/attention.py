@@ -7,6 +7,10 @@ twin of the Pallas TPU kernel in :mod:`repro.kernels.flash_attention`; the
 model dispatches to the kernel when ``cfg.use_pallas`` is set and to this
 implementation otherwise (CPU dry-runs, correctness oracles).
 
+Multi-head latent attention (MLA, DeepSeek-V2/V3) shares this attention:
+its keys and values are expanded from one normed latent per token, and its
+decode cache holds only that latent and the rotary key.
+
 Supports: causal and bidirectional attention, sliding-window masks (Gemma2
 local layers), attention-logit softcapping, GQA with arbitrary group counts,
 partial-fraction RoPE, and single-token decode against a KV cache (the decode
@@ -16,8 +20,9 @@ to collectives when the cache is sequence-sharded).
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +35,10 @@ __all__ = [
     "attention_layer",
     "decode_attention_layer",
     "init_kv_cache",
+    "init_mla",
+    "mla_layer",
+    "decode_mla_layer",
+    "init_mla_cache",
 ]
 
 _BIG_NEG = -1e30
@@ -80,7 +89,7 @@ def _mask_block(
 def flash_attention(
     q: jax.Array,  # [B, S, H, hd]
     k: jax.Array,  # [B, T, Kv, hd]
-    v: jax.Array,  # [B, T, Kv, hd]
+    v: jax.Array,  # [B, T, Kv, dv]
     *,
     causal: bool = True,
     window: int = 0,
@@ -94,12 +103,15 @@ def flash_attention(
 
     Ragged lengths are zero-padded to the chunk grid; padded *keys* are
     masked out (causally for causal attention, by valid length otherwise)
-    and padded query rows are sliced away.
+    and padded query rows are sliced away.  Key blocks that the causal or
+    window mask hides from a whole query block are skipped.  The backward
+    pass recomputes each block's scores from the saved row log-sum-exp, so
+    what it keeps is O(B·S·H·d), never one residual per block pair.  The
+    value head size (``dv``) may differ from the query/key one.
     """
     B, S, H, hd = q.shape
     T, Kv = k.shape[1], k.shape[2]
     assert H % Kv == 0, (H, Kv)
-    G = H // Kv
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     cq = min(chunk_q, S)
     ck = min(chunk_kv, T)
@@ -110,57 +122,179 @@ def flash_attention(
         q = jnp.pad(q, ((0, 0), (0, S_pad - S), (0, 0), (0, 0)))
         k = jnp.pad(k, ((0, 0), (0, T_pad - T), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, T_pad - T), (0, 0), (0, 0)))
-        S, T = S_pad, T_pad
-    nq, nk = S // cq, T // ck
+    grid = _Grid(causal, int(window or 0), float(logit_softcap or 0.0), cq, ck,
+                 int(q_offset), float(scale), T_real)
+    out = _flash(q, k, v, grid)
+    return out[:, :S_real].astype(q.dtype)
 
-    qb = q.reshape(B, nq, cq, Kv, G, hd)
-    kb = k.reshape(B, nk, ck, Kv, hd)
-    vb = v.reshape(B, nk, ck, Kv, hd)
-    q_pos = q_offset + jnp.arange(S, dtype=jnp.int32).reshape(nq, cq)
-    k_pos = jnp.arange(T, dtype=jnp.int32).reshape(nk, ck)
 
-    def one_q_block(q_chunk, q_positions):
+class _Grid(NamedTuple):
+    """The static geometry of one flash_attention call."""
+
+    causal: bool
+    window: int
+    softcap: float
+    cq: int
+    ck: int
+    q_offset: int
+    scale: float
+    t_real: int
+
+    def kv_range(self, i, nk: int):
+        """Key blocks [lo, hi) that query block ``i`` (traced) can see."""
+        q_first = self.q_offset + i * self.cq
+        hi = nk
+        if self.causal:
+            hi = jnp.minimum(nk, (q_first + self.cq - 1) // self.ck + 1)
+        lo = 0
+        if self.window > 0:
+            lo = jnp.maximum(0, (q_first - self.window + 1) // self.ck)
+        return lo, hi
+
+    def q_range(self, j, nq: int):
+        """Query blocks [lo, hi) that can see key block ``j`` (traced)."""
+        lo = 0
+        if self.causal:
+            lo = jnp.clip((j * self.ck - self.q_offset) // self.cq, 0, nq)
+        hi = nq
+        if self.window > 0:
+            last = ((j + 1) * self.ck + self.window - 2 - self.q_offset) // self.cq
+            hi = jnp.clip(last + 1, 0, nq)
+        return lo, hi
+
+    def scores(self, q_chunk, k_chunk, q_positions, k_positions):
+        """Scaled, capped and masked scores of one block pair [B, Kv, G, cq, ck],
+        with the cap's tanh (or None) for the backward."""
+        s = jnp.einsum("bqkgd,btkd->bkgqt", q_chunk, k_chunk, preferred_element_type=jnp.float32)
+        s = s * self.scale
+        capped = None
+        if self.softcap > 0.0:
+            capped = jnp.tanh(s / self.softcap)
+            s = self.softcap * capped
+        ok = _mask_block(q_positions, k_positions, causal=self.causal, window=self.window)
+        ok &= (k_positions < self.t_real)[None, :]  # padded keys never attended
+        return jnp.where(ok[None, None, None], s, _BIG_NEG), ok, capped
+
+
+def _blocks(q, k, v, grid: _Grid):
+    B, S, H, hd = q.shape
+    T, Kv, dv = k.shape[1], k.shape[2], v.shape[-1]
+    nq, nk = S // grid.cq, T // grid.ck
+    qb = jnp.moveaxis(q.reshape(B, nq, grid.cq, Kv, H // Kv, hd), 1, 0)
+    kb = jnp.moveaxis(k.reshape(B, nk, grid.ck, Kv, hd), 1, 0)
+    vb = jnp.moveaxis(v.reshape(B, nk, grid.ck, Kv, dv), 1, 0)
+    q_pos = grid.q_offset + jnp.arange(S, dtype=jnp.int32).reshape(nq, grid.cq)
+    k_pos = jnp.arange(T, dtype=jnp.int32).reshape(nk, grid.ck)
+    return qb, kb, vb, q_pos, k_pos
+
+
+def _flash_fwd_blocks(q, k, v, grid: _Grid):
+    """Output [B, S, H, dv] and row log-sum-exp [nq, B, Kv, G, cq]."""
+    from repro.distributed.vma import vary
+
+    B, S, H, _ = q.shape
+    Kv, dv = k.shape[2], v.shape[-1]
+    G = H // Kv
+    qb, kb, vb, q_pos, k_pos = _blocks(q, k, v, grid)
+    nq, nk = qb.shape[0], kb.shape[0]
+    cq = grid.cq
+
+    def one_q_block(i, q_chunk, q_positions):
         # q_chunk: [B, cq, Kv, G, hd]; q_positions: [cq]
-        from repro.distributed.vma import vary
-
         m0, l0, acc0 = vary((
             jnp.full((B, Kv, G, cq), _BIG_NEG, dtype=jnp.float32),
             jnp.zeros((B, Kv, G, cq), dtype=jnp.float32),
-            jnp.zeros((B, Kv, G, cq, hd), dtype=jnp.float32),
+            jnp.zeros((B, Kv, G, cq, dv), dtype=jnp.float32),
         ))
 
-        def kv_step(carry, inp):
+        def kv_step(j, carry):
             m, l, acc = carry
-            k_chunk, v_chunk, k_positions = inp  # [B, ck, Kv, hd], [ck]
-            s = jnp.einsum(
-                "bqkgd,btkd->bkgqt", q_chunk, k_chunk, preferred_element_type=jnp.float32
-            )
-            s = s * scale
-            if logit_softcap and logit_softcap > 0.0:
-                s = logit_softcap * jnp.tanh(s / logit_softcap)
-            ok = _mask_block(q_positions, k_positions, causal=causal, window=window)
-            ok &= (k_positions < T_real)[None, :]  # padded keys never attended
-            s = jnp.where(ok[None, None, None], s, _BIG_NEG)
+            s, _, _ = grid.scores(q_chunk, kb[j], q_positions, k_pos[j])
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
             l_new = l * corr + p.sum(axis=-1)
             acc_new = acc * corr[..., None] + jnp.einsum(
-                "bkgqt,btkd->bkgqd", p, v_chunk, preferred_element_type=jnp.float32
+                "bkgqt,btkd->bkgqd", p, vb[j], preferred_element_type=jnp.float32
             )
-            return (m_new, l_new, acc_new), None
+            return m_new, l_new, acc_new
 
-        (m, l, acc), _ = jax.lax.scan(
-            kv_step, (m0, l0, acc0), (jnp.moveaxis(kb, 1, 0), jnp.moveaxis(vb, 1, 0), k_pos)
-        )
-        out = acc / jnp.maximum(l, 1e-30)[..., None]  # [B, Kv, G, cq, hd]
-        return jnp.einsum("bkgqd->bqkgd", out)
+        lo, hi = grid.kv_range(i, nk)
+        m, l, acc = jax.lax.fori_loop(lo, hi, kv_step, (m0, l0, acc0))
+        l = jnp.maximum(l, 1e-30)
+        out = acc / l[..., None]  # [B, Kv, G, cq, dv]
+        return jnp.einsum("bkgqd->bqkgd", out), m + jnp.log(l)
 
-    out_blocks = jax.lax.map(
-        lambda args: one_q_block(*args), (jnp.moveaxis(qb, 1, 0), q_pos)
-    )  # [nq, B, cq, Kv, G, hd]
-    out = jnp.moveaxis(out_blocks, 0, 1).reshape(B, S, H, hd)
-    return out[:, :S_real].astype(q.dtype)
+    out_blocks, lse = jax.lax.map(
+        lambda args: one_q_block(*args), (jnp.arange(nq, dtype=jnp.int32), qb, q_pos)
+    )  # [nq, B, cq, Kv, G, dv], [nq, B, Kv, G, cq]
+    out = jnp.moveaxis(out_blocks, 0, 1).reshape(B, S, H, dv)
+    return out, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash(q, k, v, grid: _Grid):
+    return _flash_fwd_blocks(q, k, v, grid)[0]
+
+
+def _flash_fwd(q, k, v, grid: _Grid):
+    out, lse = _flash_fwd_blocks(q, k, v, grid)
+    return out, (q, k, v, out.astype(q.dtype), lse)
+
+
+def _flash_bwd(grid: _Grid, res, g):
+    """FlashAttention-2's backward: for each key block, the query blocks that
+    see it; scores and probabilities are recomputed from the saved log-sum-exp."""
+    q, k, v, out, lse = res
+    B, S, H, hd = q.shape
+    Kv, dv = k.shape[2], v.shape[-1]
+    G = H // Kv
+    qb, kb, vb, q_pos, k_pos = _blocks(q, k, v, grid)
+    nq, nk = qb.shape[0], kb.shape[0]
+    gb = jnp.moveaxis(g.reshape(B, nq, grid.cq, Kv, G, dv), 1, 0)
+    ob = jnp.moveaxis(out.reshape(B, nq, grid.cq, Kv, G, dv), 1, 0)
+    # D = rowsum(dO ∘ O), the softmax's Jacobian term: [nq, B, Kv, G, cq]
+    delta = jnp.einsum("nbqkgd,nbqkgd->nbkgq", gb.astype(jnp.float32), ob.astype(jnp.float32))
+
+    from repro.distributed.vma import vary
+
+    def one_kv_block(dq, inp):
+        j, k_chunk, v_chunk, k_positions = inp
+
+        def q_step(i, carry):
+            dk, dv_, dq = carry
+            q_chunk, g_chunk = qb[i], gb[i]
+            s, ok, capped = grid.scores(q_chunk, k_chunk, q_pos[i], k_positions)
+            p = jnp.exp(s - lse[i][..., None])
+            p = jnp.where(ok[None, None, None], p, 0.0)
+            dv_ = dv_ + jnp.einsum("bkgqt,bqkgd->btkd", p, g_chunk, preferred_element_type=jnp.float32)
+            dp = jnp.einsum("bqkgd,btkd->bkgqt", g_chunk, v_chunk, preferred_element_type=jnp.float32)
+            ds = p * (dp - delta[i][..., None])
+            if capped is not None:
+                ds = ds * (1.0 - jnp.square(capped))
+            ds = ds * grid.scale
+            dk = dk + jnp.einsum("bkgqt,bqkgd->btkd", ds, q_chunk, preferred_element_type=jnp.float32)
+            dq_i = jnp.einsum("bkgqt,btkd->bqkgd", ds, k_chunk, preferred_element_type=jnp.float32)
+            dq = jax.lax.dynamic_update_index_in_dim(dq, dq[i] + dq_i, i, axis=0)
+            return dk, dv_, dq
+
+        zeros_k, zeros_v = vary((jnp.zeros(k_chunk.shape, jnp.float32),
+                                 jnp.zeros(v_chunk.shape, jnp.float32)))
+        lo, hi = grid.q_range(j, nq)
+        dk, dv_, dq = jax.lax.fori_loop(lo, hi, q_step, (zeros_k, zeros_v, dq))
+        return dq, (dk, dv_)
+
+    dq0 = vary(jnp.zeros(qb.shape, jnp.float32))
+    dq, (dk, dv_) = jax.lax.scan(
+        one_kv_block, dq0, (jnp.arange(nk, dtype=jnp.int32), kb, vb, k_pos)
+    )
+    dq = jnp.moveaxis(dq, 0, 1).reshape(q.shape).astype(q.dtype)
+    dk = jnp.moveaxis(dk, 0, 1).reshape(k.shape).astype(k.dtype)
+    dv_ = jnp.moveaxis(dv_, 0, 1).reshape(v.shape).astype(v.dtype)
+    return dq, dk, dv_
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -297,3 +431,100 @@ def decode_attention_layer(
     out = out.reshape(B, 1, cfg.n_heads, hd)
     out = jnp.einsum("bshd,hdm->bsm", out.astype(dtype), p["wo"]["w"].astype(dtype))
     return out, cache_k, cache_v
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V3 MLA, no query compression)
+# ---------------------------------------------------------------------------
+
+
+def init_mla(key, cfg, *, param_dtype) -> Params:
+    """wq [D, H, nope+rope]; wkv_a [D, latent+rope]; kv_norm [latent];
+    wkv_b [latent, H, nope+v]; wo [H, v, D]."""
+    m, H, D = cfg.mla, cfg.n_heads, cfg.d_model
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    return {
+        "wq": init_dense(k1, D, (H, m.qk_head_dim), param_dtype=param_dtype),
+        "wkv_a": init_dense(k2, D, (m.kv_lora_rank + m.qk_rope_head_dim,), param_dtype=param_dtype),
+        "kv_norm": init_norm("rmsnorm", m.kv_lora_rank, param_dtype=param_dtype),
+        "wkv_b": init_dense(k3, m.kv_lora_rank, (H, m.qk_nope_head_dim + m.v_head_dim),
+                            param_dtype=param_dtype),
+        "wo": {
+            "w": (
+                jax.random.normal(k4, (H, m.v_head_dim, D), dtype=jnp.float32)
+                / math.sqrt(H * m.v_head_dim)
+            ).astype(param_dtype)
+        },
+    }
+
+
+def _mla_project(p: Params, x: jax.Array, positions, cfg, *, dtype):
+    """Queries [B, S, H, nope+rope] (rotary part rotated), the normed latent
+    [B, S, latent] and the rotated rotary key [B, S, rope]: what the cache keeps."""
+    m = cfg.mla
+    q = dense(p["wq"], x, dtype=dtype)
+    kva = dense(p["wkv_a"], x, dtype=dtype)
+    c = norm(p["kv_norm"], kva[..., : m.kv_lora_rank], kind="rmsnorm")
+    # interleaved pairs: the source de-interleaves before rotate_half, which
+    # gives the same scores
+    freqs = rope_freqs(m.qk_rope_head_dim, 1.0, cfg.rope_theta)
+    q_pe = apply_rope(q[..., m.qk_nope_head_dim :], positions, freqs)
+    k_pe = apply_rope(kva[..., None, m.kv_lora_rank :], positions, freqs)[:, :, 0]
+    q = jnp.concatenate([q[..., : m.qk_nope_head_dim], q_pe], axis=-1)
+    return q, c, k_pe
+
+
+def _mla_keys_values(p: Params, c: jax.Array, k_pe: jax.Array, cfg, *, dtype):
+    """Per-head keys [B, T, H, nope+rope] and values [B, T, H, v] from the latent."""
+    m = cfg.mla
+    kv = dense(p["wkv_b"], c, dtype=dtype)
+    k_nope, v = kv[..., : m.qk_nope_head_dim], kv[..., m.qk_nope_head_dim :]
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :].astype(k_nope.dtype), (*k_nope.shape[:-1], k_pe.shape[-1]))
+    return jnp.concatenate([k_nope, k_pe], axis=-1), v
+
+
+def mla_layer(p: Params, x: jax.Array, positions: jax.Array, cfg, *, dtype, return_cache: bool = False):
+    """Train/prefill MLA, causal. Returns (out, (latent, rotary key) or None)."""
+    q, c, k_pe = _mla_project(p, x, positions, cfg, dtype=dtype)
+    k, v = _mla_keys_values(p, c, k_pe, cfg, dtype=dtype)
+    out = flash_attention(
+        q, k, v, causal=True, scale=1.0 / math.sqrt(cfg.mla.qk_head_dim),
+        chunk_q=cfg.attn_chunk_q, chunk_kv=cfg.attn_chunk_kv,
+    )
+    out = jnp.einsum("bshd,hdm->bsm", out, p["wo"]["w"].astype(dtype))
+    return out, ((c, k_pe) if return_cache else None)
+
+
+def init_mla_cache(cfg, batch: int, max_len: int, *, n_layers_of_kind: int, dtype) -> Dict:
+    """The latent cache: kv_lora_rank + qk_rope_head_dim numbers a token and layer."""
+    m = cfg.mla
+    return {
+        "c": jnp.zeros((n_layers_of_kind, batch, max_len, m.kv_lora_rank), dtype=dtype),
+        "k_pe": jnp.zeros((n_layers_of_kind, batch, max_len, m.qk_rope_head_dim), dtype=dtype),
+    }
+
+
+def decode_mla_layer(p: Params, x: jax.Array, cache_c: jax.Array, cache_kpe: jax.Array, pos, cfg, *, dtype):
+    """One-token MLA decode against the latent cache; ``pos`` a scalar or [B].
+
+    Keys and values are expanded from the cached latent at every step (the
+    plain form, not the absorbed one).  Returns (out [B,1,D], cache_c, cache_kpe)."""
+    B = x.shape[0]
+    T = cache_c.shape[1]
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    q, c_new, kpe_new = _mla_project(p, x, pos_b[:, None], cfg, dtype=dtype)
+
+    def write_row(cache, new, at):
+        return jax.lax.dynamic_update_slice_in_dim(cache, new.astype(cache.dtype), at, axis=0)
+
+    cache_c = jax.vmap(write_row)(cache_c, c_new, pos_b)
+    cache_kpe = jax.vmap(write_row)(cache_kpe, kpe_new, pos_b)
+    k, v = _mla_keys_values(p, cache_c.astype(dtype), cache_kpe.astype(dtype), cfg, dtype=dtype)
+    s = jnp.einsum("bhd,bthd->bht", q[:, 0], k, preferred_element_type=jnp.float32)
+    s = s / math.sqrt(cfg.mla.qk_head_dim)
+    ok = jnp.arange(T, dtype=jnp.int32)[None, None, :] <= pos_b[:, None, None]
+    s = jnp.where(ok, s, _BIG_NEG)
+    prob = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bht,bthd->bhd", prob.astype(dtype), v)[:, None]
+    out = jnp.einsum("bshd,hdm->bsm", out.astype(dtype), p["wo"]["w"].astype(dtype))
+    return out, cache_c, cache_kpe

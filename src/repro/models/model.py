@@ -54,6 +54,22 @@ class Model:
     def decode_step(self, params, cache, token, pos):
         return self._mod.decode_step(params, cache, token, pos, self.cfg)
 
+    # -- state moved outside the optimizer -------------------------------------------
+    def state_leaves(self, params):
+        """Pytree of bools: True at leaves the optimizer must leave alone
+        (the MoE correction biases), moved by :meth:`after_step` instead."""
+        if self.cfg.is_encdec:
+            return jax.tree.map(lambda _: False, params)
+        return transformer.state_leaves(params)
+
+    def after_step(self, params, metrics):
+        """Params after the step's own state updates: each MoE correction bias
+        moves by the expert loads in the step's ``metrics["moe"]["load"]``."""
+        moe = metrics.get("moe")
+        if not moe or not moe["load"]:
+            return params
+        return transformer.update_router_bias(params, moe["load"], self.cfg)
+
     def init_decode_cache(self, batch: int, max_len: int):
         if self.cfg.is_encdec:
             return encdec.init_decode_cache(

@@ -5,10 +5,16 @@ one-hot einsums, the canonical TPU formulation: no gather/scatter in the hot
 path, and under GSPMD the dispatch einsums lower to all-to-alls when experts
 are sharded on the `model` axis and tokens on `data`.
 
-Covers the three assigned MoE archs:
+Covers the MoE archs:
 - OLMoE:  64 experts, top-8, tiny experts (d_ff=1024)
 - Jamba:  16 experts, top-2 on alternating layers
 - Llama4: 128 experts, top-1 + an always-on shared expert
+- Moonlight (DeepSeek-V3): 64 experts, top-6, sigmoid scores with a
+  choice-only correction bias, weights × 2.446, 2 shared experts, the
+  sequence-wise balance loss
+
+A layer may hold only some of the experts (``MoESpec.n_held`` from
+``first_held``): the expert-parallel share of one chip.
 
 The dispatch einsum costs 2·B·S·(E·C)·D FLOPs (E·C ≈ S·top_k·cf), which the
 roofline's MODEL_FLOPS/HLO_FLOPs ratio makes visible as routing overhead —
@@ -29,8 +35,9 @@ __all__ = ["init_moe", "moe_layer"]
 
 
 def init_moe(key, cfg, *, param_dtype) -> Params:
+    """Router over all ``n_experts``; expert stacks of the ``held`` experts only."""
     spec = cfg.moe
-    d, f, e = cfg.d_model, spec.d_ff, spec.n_experts
+    d, f, e = cfg.d_model, spec.d_ff, spec.held
     keys = jax.random.split(key, 5)
 
     def expert_stack(k, shape, fan_in):
@@ -38,26 +45,57 @@ def init_moe(key, cfg, *, param_dtype) -> Params:
         return w.astype(param_dtype)
 
     p: Params = {
-        "router": init_dense(keys[0], d, (e,), param_dtype=param_dtype),
+        "router": init_dense(keys[0], d, (spec.n_experts,), param_dtype=param_dtype),
         "w_gate": {"w": expert_stack(keys[1], (e, d, f), d)},
         "w_up": {"w": expert_stack(keys[2], (e, d, f), d)},
         "w_down": {"w": expert_stack(keys[3], (e, f, d), f)},
     }
-    if spec.shared_expert:
+    if spec.bias_rate > 0.0:
+        p["router_bias"] = jnp.zeros((spec.n_experts,), jnp.float32)
+    if spec.n_shared:
         from .layers import init_mlp
 
-        p["shared"] = init_mlp(keys[4], d, f, activation=cfg.activation, param_dtype=param_dtype)
+        p["shared"] = init_mlp(keys[4], d, spec.n_shared * f, activation=cfg.activation,
+                               param_dtype=param_dtype)
     return p
 
 
-def _top_k_gating(
-    logits: jax.Array, top_k: int
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (gate weights [B,S,K], expert ids [B,S,K], full probs [B,S,E])."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, idx = jax.lax.top_k(probs, top_k)
-    gates = gates / jnp.maximum(gates.sum(axis=-1, keepdims=True), 1e-9)
-    return gates, idx, probs
+def _route(p: Params, x: jax.Array, spec) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Returns (weights [B,S,K], expert ids [B,S,K], scores [B,S,E]), in f32.
+
+    Softmax or sigmoid scores over every expert; the top k of the scores
+    plus the correction bias (where the layer has one) are chosen, and their
+    scores, renormalised and times ``routed_scale``, weight them."""
+    logits = dense(p["router"], x, dtype=jnp.float32)  # fp32 routing
+    if spec.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    choose = scores + jax.lax.stop_gradient(p["router_bias"]) if "router_bias" in p else scores
+    _, idx = jax.lax.top_k(choose, spec.top_k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = weights / jnp.maximum(weights.sum(axis=-1, keepdims=True), 1e-9)
+    if spec.routed_scale != 1.0:
+        weights = weights * spec.routed_scale
+    return weights, idx, scores
+
+
+def _balance_loss(idx: jax.Array, scores: jax.Array, spec) -> jax.Array:
+    E, K = spec.n_experts, spec.top_k
+    if spec.balance == "seq":
+        # DeepSeek-V3's sequence-wise loss, per row then averaged: with f_i the
+        # share of the row's choices at expert i times E/K and P_i the row's
+        # mean of the normalised scores, L = coef·Σ_i f_i P_i
+        S = idx.shape[1]
+        counts = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum(axis=(1, 2))  # [B, E]
+        f = counts * (E / (K * S))
+        P = (scores / scores.sum(axis=-1, keepdims=True)).mean(axis=1)
+        return jnp.mean(jnp.sum(f * P, axis=-1)) * spec.load_balance_coef
+    # Switch/GShard: E * Σ_e fraction_e * mean_prob_e
+    assign1 = jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32)
+    fraction = assign1.mean(axis=(0, 1))
+    mean_prob = scores.mean(axis=(0, 1))
+    return E * jnp.sum(fraction * mean_prob) * spec.load_balance_coef
 
 
 def moe_layer(
@@ -67,8 +105,13 @@ def moe_layer(
     *,
     dtype,
     capacity_factor: float = 1.25,
-) -> Tuple[jax.Array, jax.Array]:
-    """Returns (output [B,S,D], load-balance aux loss scalar).
+) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """Returns (output [B,S,D], load-balance aux loss scalar, stats).
+
+    ``stats`` holds ``load``, the choices at each of the router's experts
+    [E], and three counters: ``routed``, the choices bound for the experts
+    held here; ``kept``, those within capacity; ``slots``, the expert slots
+    computed.
 
     ``cfg.moe_block`` > 0 dispatches in sequence blocks: the one-hot
     dispatch/combine einsums cost 2·(E·C)·D per token with E·C ≈ S_blk·K·cf,
@@ -83,8 +126,8 @@ def moe_layer(
     if blk and blk < S and S % blk == 0:
         nb = S // blk
         xb = x.reshape(B * nb, blk, D)
-        out, aux = _moe_dispatch(p, xb, cfg, dtype=dtype, capacity_factor=cf)
-        return out.reshape(B, S, D), aux
+        out, aux, stats = _moe_dispatch(p, xb, cfg, dtype=dtype, capacity_factor=cf)
+        return out.reshape(B, S, D), aux, stats
     return _moe_dispatch(p, x, cfg, dtype=dtype, capacity_factor=cf)
 
 
@@ -95,26 +138,28 @@ def _moe_dispatch(
     *,
     dtype,
     capacity_factor: float = 1.25,
-) -> Tuple[jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """Route over all experts; dispatch to and compute only the held ones.
+
+    Expert parallelism: the router is replicated and each chip computes its
+    held experts' part of the result, for the choices bound to them.  With
+    every expert held this is the whole layer."""
     spec = cfg.moe
     B, S, D = x.shape
-    E, K = spec.n_experts, spec.top_k
-    C = max(1, int(math.ceil(S * K * capacity_factor / E)))
+    E, K = spec.held, spec.top_k
+    C = max(1, int(math.ceil(S * K * capacity_factor / spec.n_experts)))
 
-    router_logits = dense(p["router"], x, dtype=jnp.float32)  # fp32 routing
-    gates, idx, probs = _top_k_gating(router_logits, K)
-
-    # load-balance loss (Switch/GShard): E * Σ_e fraction_e * mean_prob_e
-    assign1 = jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32)
-    fraction = assign1.mean(axis=(0, 1))
-    mean_prob = probs.mean(axis=(0, 1))
-    aux = E * jnp.sum(fraction * mean_prob) * spec.load_balance_coef
+    gates, idx, scores = _route(p, x, spec)
+    aux = _balance_loss(idx, scores, spec)
+    load = jax.nn.one_hot(idx, spec.n_experts, dtype=jnp.int32).sum(axis=(0, 1, 2))
+    idx = idx - spec.first_held  # ids among the held experts; one_hot drops the rest
 
     # build dispatch (one-hot over capacity slots) and combine tensors
     dispatch = jnp.zeros((B, S, E, C), dtype=jnp.bool_)
     combine = jnp.zeros((B, S, E, C), dtype=jnp.float32)
     # slots already used per expert as we sweep the K choices
     used = jnp.zeros((B, E), dtype=jnp.int32)
+    routed = jnp.zeros((), jnp.int32)
     for k in range(K):
         onehot_e = jax.nn.one_hot(idx[..., k], E, dtype=jnp.int32)  # [B,S,E]
         # position within each expert queue (exclusive cumsum along S) + carry
@@ -124,6 +169,9 @@ def _moe_dispatch(
         dispatch = dispatch | (slot.astype(jnp.bool_) & (onehot_e > 0)[..., None])
         combine = combine + slot * onehot_e[..., None] * gates[..., k][..., None, None]
         used = used + jnp.sum(onehot_e * within.astype(jnp.int32), axis=1)
+        routed = routed + jnp.sum(onehot_e)
+    stats = {"load": load, "routed": routed, "kept": jnp.sum(used),
+             "slots": jnp.asarray(B * E * C, jnp.int32)}
 
     # dispatch: gather expert inputs  [E, B, C, D]
     xd = x.astype(dtype)
@@ -150,8 +198,8 @@ def _moe_dispatch(
         "bsec,ebcd->bsd", combine.astype(dtype), expert_out, preferred_element_type=dtype
     )
 
-    if spec.shared_expert:
+    if spec.n_shared:
         from .layers import mlp
 
         y = y + mlp(p["shared"], x, activation=cfg.activation, dtype=dtype)
-    return y.astype(x.dtype), aux
+    return y.astype(x.dtype), aux, stats

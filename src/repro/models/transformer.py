@@ -7,10 +7,18 @@ unit-sized regardless of depth (Jamba's 8-layer unit, Gemma2's 2-layer
 local/global unit, plain archs' 1-layer unit).  Activation rematerialization
 wraps the scanned unit body (``cfg.remat``).
 
+Leading layers (``cfg.lead``, e.g. DeepSeek-V3's first dense layer) run
+once before the scan, each under ``lead/layer<i>``.
+
 Three entry points per model: ``train_loss`` (causal LM loss, sequence-
 chunked so [B,S,V] logits never materialize), ``prefill`` (forward + cache
 build), ``decode_step`` (one token against caches).  Caches are stacked per
 pattern position, mirroring the parameter layout, so decode also scans.
+
+An MoE layer with a correction bias (``MoESpec.bias_rate``) keeps it as the
+parameter ``ffn/router_bias``, which the optimizer leaves alone
+(:func:`state_leaves`); after each step :func:`update_router_bias` moves it
+by the step's expert loads, which ``train_loss`` returns.
 """
 
 from __future__ import annotations
@@ -24,8 +32,12 @@ import jax.numpy as jnp
 from .attention import (
     attention_layer,
     decode_attention_layer,
+    decode_mla_layer,
     init_attention,
     init_kv_cache,
+    init_mla,
+    init_mla_cache,
+    mla_layer,
 )
 from .layers import (
     Params,
@@ -59,6 +71,8 @@ __all__ = [
     "init_decode_cache",
     "count_params",
     "count_active_params",
+    "state_leaves",
+    "update_router_bias",
 ]
 
 
@@ -81,6 +95,8 @@ def _init_layer(key, cfg, spec) -> Params:
     p: Params = {"norm1": init_norm(cfg.norm, cfg.d_model, param_dtype=pdt)}
     if spec.mixer in ("attn", "attn_local"):
         p["mixer"] = init_attention(keys[0], cfg, param_dtype=pdt)
+    elif spec.mixer == "mla":
+        p["mixer"] = init_mla(keys[0], cfg, param_dtype=pdt)
     elif spec.mixer == "mamba":
         p["mixer"] = init_mamba(keys[0], cfg, param_dtype=pdt)
     elif spec.mixer == "rwkv":
@@ -96,6 +112,10 @@ def _init_layer(key, cfg, spec) -> Params:
         p["norm1_post"] = init_norm(cfg.norm, cfg.d_model, param_dtype=pdt)
         p["norm2_post"] = init_norm(cfg.norm, cfg.d_model, param_dtype=pdt)
     return p
+
+
+#: folded into the init key for the leading layers' keys
+_LEAD_KEY = 0x1EAD
 
 
 def init_lm(key, cfg) -> Params:
@@ -115,6 +135,12 @@ def init_lm(key, cfg) -> Params:
         pos_keys = jax.vmap(lambda k, i=i: jax.random.fold_in(k, i))(unit_keys)
         units[f"pos{i}"] = jax.vmap(lambda k, s=spec: _init_layer(k, cfg, s))(pos_keys)
     params["units"] = units
+    if cfg.lead:
+        k_lead = jax.random.fold_in(key, _LEAD_KEY)
+        params["lead"] = {
+            f"layer{i}": _init_layer(jax.random.fold_in(k_lead, i), cfg, spec)
+            for i, spec in enumerate(cfg.lead)
+        }
     return params
 
 
@@ -140,6 +166,9 @@ def _mixer_apply(lp, spec, h, positions, cfg, dt):
     if spec.mixer in ("attn", "attn_local"):
         out, _ = attention_layer(lp["mixer"], h, positions, cfg, kind=spec.mixer, dtype=dt)
         return out
+    if spec.mixer == "mla":
+        out, _ = mla_layer(lp["mixer"], h, positions, cfg, dtype=dt)
+        return out
     if spec.mixer == "mamba":
         return mamba_layer(lp["mixer"], h, cfg, dtype=dt)
     if spec.mixer == "rwkv":
@@ -149,14 +178,14 @@ def _mixer_apply(lp, spec, h, positions, cfg, dt):
 
 
 def _ffn_apply(lp, spec, h, cfg, dt):
-    """Returns (out, aux)."""
+    """Returns (out, aux, MoE stats or None)."""
     if spec.ffn == "dense":
-        return mlp(lp["ffn"], h, activation=cfg.activation, dtype=dt), 0.0
+        return mlp(lp["ffn"], h, activation=cfg.activation, dtype=dt), 0.0, None
     if spec.ffn == "moe":
         return moe_layer(lp["ffn"], h, cfg, dtype=dt)
     if spec.ffn == "rwkv_cmix":
         out, _ = rwkv_cmix(lp["ffn"], h, cfg, dtype=dt)
-        return out, 0.0
+        return out, 0.0, None
     raise ValueError(spec.ffn)
 
 
@@ -175,25 +204,51 @@ def _sp_constrain(x, cfg):
     return jax.lax.with_sharding_constraint(x, P(None, "model", None))
 
 
+#: the MoE counters summed over layers: choices bound for held experts,
+#: choices kept within capacity, expert slots computed
+_MOE_COUNTERS = ("routed", "kept", "slots")
+
+
+def _zero_counters():
+    from repro.distributed.vma import vary
+
+    return vary({name: jnp.zeros((), jnp.int32) for name in _MOE_COUNTERS})
+
+
+def _layer_train(lp, spec, x, positions, cfg, dt):
+    """One block: (x, aux, MoE stats or None)."""
+    h = norm(lp["norm1"], x, kind=cfg.norm)
+    mix = _mixer_apply(lp, spec, h, positions, cfg, dt)
+    if cfg.post_block_norm:
+        mix = norm(lp["norm1_post"], mix, kind=cfg.norm)
+    x = _sp_constrain(x + mix, cfg)
+    h = norm(lp["norm2"], x, kind=cfg.norm)
+    f, aux, stats = _ffn_apply(lp, spec, h, cfg, dt)
+    if cfg.post_block_norm:
+        f = norm(lp["norm2_post"], f, kind=cfg.norm)
+    return _sp_constrain(x + f, cfg), aux, stats
+
+
+def _add_counters(counters, stats):
+    if stats is None:
+        return counters
+    return {name: counters[name] + stats[name] for name in _MOE_COUNTERS}
+
+
 def _unit_body_train(cfg):
     dt = _dtype(cfg)
 
     def body(carry, unit_params, positions):
-        x, aux = carry
+        """Returns the new carry and the expert loads of the unit's biased MoE layers."""
+        x, aux, counters = carry
+        loads = {}
         for i, spec in enumerate(cfg.pattern):
-            lp = unit_params[f"pos{i}"]
-            h = norm(lp["norm1"], x, kind=cfg.norm)
-            mix = _mixer_apply(lp, spec, h, positions, cfg, dt)
-            if cfg.post_block_norm:
-                mix = norm(lp["norm1_post"], mix, kind=cfg.norm)
-            x = _sp_constrain(x + mix, cfg)
-            h = norm(lp["norm2"], x, kind=cfg.norm)
-            f, aux_i = _ffn_apply(lp, spec, h, cfg, dt)
-            if cfg.post_block_norm:
-                f = norm(lp["norm2_post"], f, kind=cfg.norm)
-            x = _sp_constrain(x + f, cfg)
+            x, aux_i, stats = _layer_train(unit_params[f"pos{i}"], spec, x, positions, cfg, dt)
             aux = aux + aux_i
-        return x, aux
+            counters = _add_counters(counters, stats)
+            if stats is not None and "router_bias" in unit_params[f"pos{i}"]["ffn"]:
+                loads[f"pos{i}"] = stats["load"]
+        return (x, aux, counters), loads
 
     return body
 
@@ -204,30 +259,58 @@ _REMAT_POLICIES = {
 }
 
 
-def lm_hidden(params, batch: Dict[str, jax.Array], cfg) -> Tuple[jax.Array, jax.Array]:
-    """Embeddings → stacked units → final norm.  Returns (hidden, moe_aux)."""
+def _remat(fn, cfg):
+    if cfg.remat in _REMAT_POLICIES:
+        return jax.checkpoint(fn, policy=_REMAT_POLICIES[cfg.remat], prevent_cse=False)
+    return fn
+
+
+def _hidden(params, batch: Dict[str, jax.Array], cfg):
+    """Embeddings → leading layers → stacked units → final norm.
+
+    Returns (hidden, moe_aux, moe) with ``moe`` None for a model without
+    MoE layers, else the counters summed over layers and ``load``: the
+    expert loads of each biased layer, keyed by its bias's parameter path."""
     x = _embed_inputs(params, batch, cfg)
     B, S = batch["tokens"].shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    body = _unit_body_train(cfg)
-
-    def scan_fn(carry, unit_params):
-        return body(carry, unit_params, positions), None
-
-    if cfg.remat in _REMAT_POLICIES:
-        scan_fn = jax.checkpoint(scan_fn, policy=_REMAT_POLICIES[cfg.remat], prevent_cse=False)
-
+    dt = _dtype(cfg)
     from repro.distributed.vma import vary
 
+    aux, counters = vary(jnp.zeros((), jnp.float32)), _zero_counters()
+    loads: Dict[str, jax.Array] = {}
+    for i, spec in enumerate(cfg.lead):
+        lp = params["lead"][f"layer{i}"]
+        layer = _remat(lambda lp, x, spec=spec: _layer_train(lp, spec, x, positions, cfg, dt), cfg)
+        x, aux_i, stats = layer(lp, x)
+        aux = aux + aux_i
+        counters = _add_counters(counters, stats)
+        if stats is not None and "router_bias" in lp["ffn"]:
+            loads[f"lead/layer{i}/ffn/router_bias"] = stats["load"]
+
+    body = _unit_body_train(cfg)
+    scan_fn = _remat(lambda carry, unit_params: body(carry, unit_params, positions), cfg)
+    carry = (x, aux, counters)
     if cfg.scan_layers:
-        (x, aux), _ = jax.lax.scan(scan_fn, (x, vary(jnp.zeros((), jnp.float32))), params["units"])
+        carry, unit_loads = jax.lax.scan(scan_fn, carry, params["units"])
     else:
-        carry = (x, vary(jnp.zeros((), jnp.float32)))
+        per_unit = []
         for u in range(cfg.n_units):
             unit = jax.tree.map(lambda leaf: leaf[u], params["units"])
-            carry, _ = scan_fn(carry, unit)
-        x, aux = carry
+            carry, ul = scan_fn(carry, unit)
+            per_unit.append(ul)
+        unit_loads = jax.tree.map(lambda *ls: jnp.stack(ls), *per_unit)
+    x, aux, counters = carry
+    for pos, load in unit_loads.items():
+        loads[f"units/{pos}/ffn/router_bias"] = load
     x = norm(params["final_norm"], x, kind=cfg.norm)
+    has_moe = any(s.ffn == "moe" for s in cfg.lead + cfg.pattern)
+    return x, aux, (dict(counters, load=loads) if has_moe else None)
+
+
+def lm_hidden(params, batch: Dict[str, jax.Array], cfg) -> Tuple[jax.Array, jax.Array]:
+    """Embeddings → leading layers → stacked units → final norm.  Returns (hidden, moe_aux)."""
+    x, aux, _ = _hidden(params, batch, cfg)
     return x, aux
 
 
@@ -245,8 +328,10 @@ def train_loss(
     *,
     loss_chunk: int = 256,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Causal LM cross-entropy, sequence-chunked so [B,S,V] never exists."""
-    x, aux = lm_hidden(params, batch, cfg)
+    """Causal LM cross-entropy, sequence-chunked so [B,S,V] never exists.
+
+    The metrics hold ``moe`` (see :func:`_hidden`) for a model with MoE layers."""
+    x, aux, moe = _hidden(params, batch, cfg)
     targets = batch["targets"]
     B, S = targets.shape
     c = min(loss_chunk, S)
@@ -282,7 +367,37 @@ def train_loss(
         chunk_loss, vary(jnp.zeros((), jnp.float32)), (jnp.moveaxis(xc, 1, 0), jnp.moveaxis(tc, 1, 0))
     )
     loss = total / (B * S) + aux
-    return loss, {"loss": loss, "moe_aux": aux}
+    metrics = {"loss": loss, "moe_aux": aux}
+    if moe is not None:
+        metrics["moe"] = moe
+    return loss, metrics
+
+
+def state_leaves(params):
+    """True at each leaf that is model state moved outside the optimizer:
+    the MoE correction biases."""
+    from repro.distributed.sharding import path_of
+
+    return jax.tree_util.tree_map_with_path(
+        lambda kp, _: path_of(kp).endswith("router_bias"), params
+    )
+
+
+def update_router_bias(params, loads: Dict[str, jax.Array], cfg):
+    """b_i += rate·sign(mean_j c_j − c_i) for each biased MoE layer, with c
+    the step's choices per expert (DeepSeek-V3, arXiv:2412.19437 §2.1.2)."""
+    from repro.distributed.sharding import path_of
+
+    rate = cfg.moe.bias_rate
+
+    def update(kp, leaf):
+        load = loads.get(path_of(kp))
+        if load is None:
+            return leaf
+        load = load.astype(jnp.float32)
+        return leaf + rate * jnp.sign(load.mean(axis=-1, keepdims=True) - load)
+
+    return jax.tree_util.tree_map_with_path(update, params)
 
 
 # ---------------------------------------------------------------------------
@@ -290,76 +405,163 @@ def train_loss(
 # ---------------------------------------------------------------------------
 
 
-def init_decode_cache(cfg, batch: int, max_len: int) -> Dict:
-    """Stacked-per-position cache pytree matching the scan layout."""
+def _cache_entry(cfg, spec, batch: int, max_len: int, n: int) -> Dict[str, Any]:
+    """The cache of one layer kind, stacked over ``n`` layers."""
     dt = _dtype(cfg)
-    cache: Dict[str, Any] = {}
-    for i, spec in enumerate(cfg.pattern):
-        if spec.mixer in ("attn", "attn_local"):
-            # local layers never need more than the window
-            T = max_len
-            if spec.mixer == "attn_local" and cfg.attn_window:
-                T = min(max_len, cfg.attn_window)
-            kv = init_kv_cache(cfg, batch, T, n_layers_of_kind=cfg.n_units, dtype=dt)
-            entry: Dict[str, Any] = {"k": kv["k"], "v": kv["v"]}
-        elif spec.mixer == "mamba":
-            mc = init_mamba_cache(cfg, batch, n_layers_of_kind=cfg.n_units, dtype=dt)
-            entry = {"conv": mc["conv"], "ssm": mc["ssm"]}
-        elif spec.mixer == "rwkv":
-            rc = init_rwkv_cache(cfg, batch, n_layers_of_kind=cfg.n_units, dtype=dt)
-            entry = {"wkv": rc["wkv"], "tshift": rc["tshift"]}
-        else:
-            raise ValueError(spec.mixer)
-        if spec.ffn == "rwkv_cmix":
-            entry["cshift"] = jnp.zeros((cfg.n_units, batch, 1, cfg.d_model), dtype=dt)
-        cache[f"pos{i}"] = entry
+    if spec.mixer in ("attn", "attn_local"):
+        # local layers never need more than the window
+        T = max_len
+        if spec.mixer == "attn_local" and cfg.attn_window:
+            T = min(max_len, cfg.attn_window)
+        kv = init_kv_cache(cfg, batch, T, n_layers_of_kind=n, dtype=dt)
+        entry: Dict[str, Any] = {"k": kv["k"], "v": kv["v"]}
+    elif spec.mixer == "mla":
+        entry = init_mla_cache(cfg, batch, max_len, n_layers_of_kind=n, dtype=dt)
+    elif spec.mixer == "mamba":
+        mc = init_mamba_cache(cfg, batch, n_layers_of_kind=n, dtype=dt)
+        entry = {"conv": mc["conv"], "ssm": mc["ssm"]}
+    elif spec.mixer == "rwkv":
+        rc = init_rwkv_cache(cfg, batch, n_layers_of_kind=n, dtype=dt)
+        entry = {"wkv": rc["wkv"], "tshift": rc["tshift"]}
+    else:
+        raise ValueError(spec.mixer)
+    if spec.ffn == "rwkv_cmix":
+        entry["cshift"] = jnp.zeros((n, batch, 1, cfg.d_model), dtype=dt)
+    return entry
+
+
+def init_decode_cache(cfg, batch: int, max_len: int) -> Dict:
+    """Stacked-per-position cache pytree matching the scan layout; a leading
+    layer's cache is under ``lead/layer<i>`` with a leading dim of 1, so every
+    leaf is [layers, batch, ...]."""
+    cache: Dict[str, Any] = {
+        f"pos{i}": _cache_entry(cfg, spec, batch, max_len, cfg.n_units)
+        for i, spec in enumerate(cfg.pattern)
+    }
+    if cfg.lead:
+        cache["lead"] = {
+            f"layer{i}": _cache_entry(cfg, spec, batch, max_len, 1) for i, spec in enumerate(cfg.lead)
+        }
     return cache
 
 
-def _unit_body_decode(cfg):
-    dt = _dtype(cfg)
+def _layer_decode(lp, lc, spec, x, pos, cfg, dt):
+    """One block, one token against its cache: (x, new cache)."""
+    nc: Dict[str, Any] = {}
+    h = norm(lp["norm1"], x, kind=cfg.norm)
+    if spec.mixer in ("attn", "attn_local"):
+        # local windows use a ring cache sized to the window: the
+        # cache was allocated at min(max_len, window), so it rolls
+        # exactly when it was clamped to the window size
+        T = lc["k"].shape[1]
+        rolling = spec.mixer == "attn_local" and bool(cfg.attn_window) and T == cfg.attn_window
+        write_pos = pos % T if rolling else pos
+        mix, ck, cv = decode_attention_layer(
+            lp["mixer"], h, lc["k"], lc["v"], write_pos, cfg,
+            kind=spec.mixer, dtype=dt, rolling=rolling, abs_pos=pos,
+        )
+        nc["k"], nc["v"] = ck, cv
+    elif spec.mixer == "mla":
+        mix, nc["c"], nc["k_pe"] = decode_mla_layer(lp["mixer"], h, lc["c"], lc["k_pe"], pos, cfg, dtype=dt)
+    elif spec.mixer == "mamba":
+        mix, conv, ssm = mamba_decode_step(lp["mixer"], h, lc["conv"], lc["ssm"], cfg, dtype=dt)
+        nc["conv"], nc["ssm"] = conv, ssm
+    elif spec.mixer == "rwkv":
+        mix, st = rwkv_tmix(lp["mixer"], h, cfg, dtype=dt, state={"wkv": lc["wkv"], "shift": lc["tshift"]})
+        nc["wkv"], nc["tshift"] = st["wkv"], st["shift"]
+    if cfg.post_block_norm:
+        mix = norm(lp["norm1_post"], mix, kind=cfg.norm)
+    x = x + mix
+    h = norm(lp["norm2"], x, kind=cfg.norm)
+    if spec.ffn == "rwkv_cmix":
+        f, st = rwkv_cmix(lp["ffn"], h, cfg, dtype=dt, state={"shift": lc["cshift"]})
+        nc["cshift"] = st["shift"]
+    else:
+        f = _ffn_apply(lp, spec, h, cfg, dt)[0]
+    if cfg.post_block_norm:
+        f = norm(lp["norm2_post"], f, kind=cfg.norm)
+    return x + f, nc
 
-    def body(x, unit_params, unit_cache, pos):
-        new_cache: Dict[str, Any] = {}
+
+def _layer_prefill(lp, lc, spec, x, positions, cfg, dt):
+    """One block over the prompt, filling its cache: (x, new cache)."""
+    S = x.shape[1]
+    nc: Dict[str, Any] = {}
+    h = norm(lp["norm1"], x, kind=cfg.norm)
+    if spec.mixer in ("attn", "attn_local"):
+        mix, kv = attention_layer(
+            lp["mixer"], h, positions, cfg, kind=spec.mixer, dtype=dt, return_kv=True
+        )
+        k_new, v_new = kv
+        T = lc["k"].shape[1]
+        if T >= S:
+            nc["k"] = jax.lax.dynamic_update_slice_in_dim(lc["k"], k_new.astype(lc["k"].dtype), 0, axis=1)
+            nc["v"] = jax.lax.dynamic_update_slice_in_dim(lc["v"], v_new.astype(lc["v"].dtype), 0, axis=1)
+        else:  # rolling window cache keeps the tail
+            nc["k"] = k_new[:, S - T :].astype(lc["k"].dtype)
+            nc["v"] = v_new[:, S - T :].astype(lc["v"].dtype)
+    elif spec.mixer == "mla":
+        mix, (c, k_pe) = mla_layer(lp["mixer"], h, positions, cfg, dtype=dt, return_cache=True)
+        for name, new in (("c", c), ("k_pe", k_pe)):
+            nc[name] = jax.lax.dynamic_update_slice_in_dim(lc[name], new.astype(lc[name].dtype), 0, axis=1)
+    elif spec.mixer == "mamba":
+        # rerun the mixer capturing final states
+        from .mamba import mamba_layer_with_state
+
+        mix, conv, ssm = mamba_layer_with_state(lp["mixer"], h, cfg, dtype=dt)
+        nc["conv"], nc["ssm"] = conv.astype(lc["conv"].dtype), ssm
+    elif spec.mixer == "rwkv":
+        mix, st = rwkv_tmix(
+            lp["mixer"], h, cfg, dtype=dt,
+            state={"wkv": lc["wkv"], "shift": lc["tshift"]},
+        )
+        nc["wkv"], nc["tshift"] = st["wkv"], st["shift"].astype(lc["tshift"].dtype)
+    if cfg.post_block_norm:
+        mix = norm(lp["norm1_post"], mix, kind=cfg.norm)
+    x = x + mix
+    h = norm(lp["norm2"], x, kind=cfg.norm)
+    if spec.ffn == "rwkv_cmix":
+        f, st = rwkv_cmix(lp["ffn"], h, cfg, dtype=dt, state={"shift": lc["cshift"]})
+        nc["cshift"] = st["shift"].astype(lc["cshift"].dtype)
+    else:
+        f = _ffn_apply(lp, spec, h, cfg, dt)[0]
+    if cfg.post_block_norm:
+        f = norm(lp["norm2_post"], f, kind=cfg.norm)
+    return x + f, nc
+
+
+def _through_layers(layer_fn, params, cache, x, cfg):
+    """The leading layers, then the scanned units, each as ``layer_fn(lp, lc,
+    spec, x) → (x, new cache)``; returns (x, new cache)."""
+    new_cache: Dict[str, Any] = {}
+    if cfg.lead:
+        new_cache["lead"] = {}
+        for i, spec in enumerate(cfg.lead):
+            name = f"layer{i}"
+            lc = jax.tree.map(lambda leaf: leaf[0], cache["lead"][name])
+            x, nc = layer_fn(params["lead"][name], lc, spec, x)
+            new_cache["lead"][name] = jax.tree.map(lambda leaf: leaf[None], nc)
+    units_cache = {k: v for k, v in cache.items() if k != "lead"}
+
+    def body(x, inp):
+        unit_params, unit_cache = inp
+        unit_new: Dict[str, Any] = {}
         for i, spec in enumerate(cfg.pattern):
-            lp = unit_params[f"pos{i}"]
-            lc = unit_cache[f"pos{i}"]
-            nc: Dict[str, Any] = {}
-            h = norm(lp["norm1"], x, kind=cfg.norm)
-            if spec.mixer in ("attn", "attn_local"):
-                # local windows use a ring cache sized to the window: the
-                # cache was allocated at min(max_len, window), so it rolls
-                # exactly when it was clamped to the window size
-                T = lc["k"].shape[1]
-                rolling = spec.mixer == "attn_local" and bool(cfg.attn_window) and T == cfg.attn_window
-                write_pos = pos % T if rolling else pos
-                mix, ck, cv = decode_attention_layer(
-                    lp["mixer"], h, lc["k"], lc["v"], write_pos, cfg,
-                    kind=spec.mixer, dtype=dt, rolling=rolling, abs_pos=pos,
-                )
-                nc["k"], nc["v"] = ck, cv
-            elif spec.mixer == "mamba":
-                mix, conv, ssm = mamba_decode_step(lp["mixer"], h, lc["conv"], lc["ssm"], cfg, dtype=dt)
-                nc["conv"], nc["ssm"] = conv, ssm
-            elif spec.mixer == "rwkv":
-                mix, st = rwkv_tmix(lp["mixer"], h, cfg, dtype=dt, state={"wkv": lc["wkv"], "shift": lc["tshift"]})
-                nc["wkv"], nc["tshift"] = st["wkv"], st["shift"]
-            if cfg.post_block_norm:
-                mix = norm(lp["norm1_post"], mix, kind=cfg.norm)
-            x = x + mix
-            h = norm(lp["norm2"], x, kind=cfg.norm)
-            if spec.ffn == "rwkv_cmix":
-                f, st = rwkv_cmix(lp["ffn"], h, cfg, dtype=dt, state={"shift": lc["cshift"]})
-                nc["cshift"] = st["shift"]
-            else:
-                f, _ = _ffn_apply(lp, spec, h, cfg, dt)
-            if cfg.post_block_norm:
-                f = norm(lp["norm2_post"], f, kind=cfg.norm)
-            x = x + f
-            new_cache[f"pos{i}"] = nc
-        return x, new_cache
+            x, unit_new[f"pos{i}"] = layer_fn(unit_params[f"pos{i}"], unit_cache[f"pos{i}"], spec, x)
+        return x, unit_new
 
-    return body
+    if cfg.scan_layers:
+        x, units_new = jax.lax.scan(body, x, (params["units"], units_cache))
+    else:
+        slices = []
+        for u in range(cfg.n_units):
+            unit_p = jax.tree.map(lambda l: l[u], params["units"])
+            unit_c = jax.tree.map(lambda l: l[u], units_cache)
+            x, nc = body(x, (unit_p, unit_c))
+            slices.append(nc)
+        units_new = jax.tree.map(lambda *ls: jnp.stack(ls), *slices)
+    new_cache.update(units_new)
+    return x, new_cache
 
 
 def decode_step(params, cache: Dict, token: jax.Array, pos: jax.Array, cfg):
@@ -372,23 +574,8 @@ def decode_step(params, cache: Dict, token: jax.Array, pos: jax.Array, cfg):
     x = embed(params["embed"], token, dtype=dt)
     if cfg.post_block_norm:
         x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype=dt)
-    body = _unit_body_decode(cfg)
-
-    def scan_fn(x, inp):
-        unit_params, unit_cache = inp
-        x, new_cache = body(x, unit_params, unit_cache, pos)
-        return x, new_cache
-
-    if cfg.scan_layers:
-        x, new_cache = jax.lax.scan(scan_fn, x, (params["units"], cache))
-    else:
-        slices = []
-        for u in range(cfg.n_units):
-            unit_p = jax.tree.map(lambda l: l[u], params["units"])
-            unit_c = jax.tree.map(lambda l: l[u], cache)
-            x, nc = scan_fn(x, (unit_p, unit_c))
-            slices.append(nc)
-        new_cache = jax.tree.map(lambda *ls: jnp.stack(ls), *slices)
+    layer = lambda lp, lc, spec, x: _layer_decode(lp, lc, spec, x, pos, cfg, dt)
+    x, new_cache = _through_layers(layer, params, cache, x, cfg)
     x = norm(params["final_norm"], x, kind=cfg.norm)
     logits = _logits(params, x, cfg)
     return new_cache, logits
@@ -398,74 +585,17 @@ def prefill(params, batch: Dict[str, jax.Array], cfg, *, max_len: int):
     """Forward over a prompt, building decode caches.
 
     Implemented as hidden-pass + per-position cache fill; attention caches
-    are populated from the layer K/V projections, recurrent caches from the
-    chunked-scan final states.  Returns (cache, last_logits [B,1,V]).
+    are populated from the layer K/V projections (MLA's from its latent and
+    rotary key), recurrent caches from the chunked-scan final states.
+    Returns (cache, last_logits [B,1,V]).
     """
     dt = _dtype(cfg)
     x = _embed_inputs(params, batch, cfg)
     B, S = batch["tokens"].shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     cache = init_decode_cache(cfg, B, max_len)
-
-    def body(carry, inp):
-        x = carry
-        unit_params, unit_cache = inp
-        new_cache: Dict[str, Any] = {}
-        for i, spec in enumerate(cfg.pattern):
-            lp = unit_params[f"pos{i}"]
-            lc = unit_cache[f"pos{i}"]
-            nc: Dict[str, Any] = {}
-            h = norm(lp["norm1"], x, kind=cfg.norm)
-            if spec.mixer in ("attn", "attn_local"):
-                mix, kv = attention_layer(
-                    lp["mixer"], h, positions, cfg, kind=spec.mixer, dtype=dt, return_kv=True
-                )
-                k_new, v_new = kv
-                T = lc["k"].shape[1]
-                if T >= S:
-                    nc["k"] = jax.lax.dynamic_update_slice_in_dim(lc["k"], k_new.astype(lc["k"].dtype), 0, axis=1)
-                    nc["v"] = jax.lax.dynamic_update_slice_in_dim(lc["v"], v_new.astype(lc["v"].dtype), 0, axis=1)
-                else:  # rolling window cache keeps the tail
-                    nc["k"] = k_new[:, S - T :].astype(lc["k"].dtype)
-                    nc["v"] = v_new[:, S - T :].astype(lc["v"].dtype)
-            elif spec.mixer == "mamba":
-                # rerun the mixer capturing final states
-                from .mamba import mamba_layer_with_state
-
-                mix, conv, ssm = mamba_layer_with_state(lp["mixer"], h, cfg, dtype=dt)
-                nc["conv"], nc["ssm"] = conv.astype(lc["conv"].dtype), ssm
-            elif spec.mixer == "rwkv":
-                mix, st = rwkv_tmix(
-                    lp["mixer"], h, cfg, dtype=dt,
-                    state={"wkv": lc["wkv"], "shift": lc["tshift"]},
-                )
-                nc["wkv"], nc["tshift"] = st["wkv"], st["shift"].astype(lc["tshift"].dtype)
-            if cfg.post_block_norm:
-                mix = norm(lp["norm1_post"], mix, kind=cfg.norm)
-            x = x + mix
-            h = norm(lp["norm2"], x, kind=cfg.norm)
-            if spec.ffn == "rwkv_cmix":
-                f, st = rwkv_cmix(lp["ffn"], h, cfg, dtype=dt, state={"shift": lc["cshift"]})
-                nc["cshift"] = st["shift"].astype(lc["cshift"].dtype)
-            else:
-                f, _ = _ffn_apply(lp, spec, h, cfg, dt)
-            if cfg.post_block_norm:
-                f = norm(lp["norm2_post"], f, kind=cfg.norm)
-            x = x + f
-            new_cache[f"pos{i}"] = nc
-        return x, new_cache
-
-    if cfg.scan_layers:
-        x, new_cache = jax.lax.scan(body, x, (params["units"], cache))
-    else:
-        slices = []
-        for u in range(cfg.n_units):
-            unit_p = jax.tree.map(lambda l: l[u], params["units"])
-            unit_c = jax.tree.map(lambda l: l[u], cache)
-            x, nc = body(x, (unit_p, unit_c))
-            slices.append(nc)
-        new_cache = jax.tree.map(lambda *ls: jnp.stack(ls), *slices)
-
+    layer = lambda lp, lc, spec, x: _layer_prefill(lp, lc, spec, x, positions, cfg, dt)
+    x, new_cache = _through_layers(layer, params, cache, x, cfg)
     x = norm(params["final_norm"], x, kind=cfg.norm)
     last_logits = _logits(params, x[:, -1:, :], cfg)
     return new_cache, last_logits

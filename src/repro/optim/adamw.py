@@ -69,31 +69,39 @@ class AdamW:
         zeros = lambda t: jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), t)
         return {"mu": zeros(params), "nu": zeros(params), "count": jnp.zeros((), jnp.int32)}
 
-    def update(self, grads, state, params) -> Tuple[Any, Dict[str, Any], Dict[str, jax.Array]]:
+    def update(self, grads, state, params, frozen=None) -> Tuple[Any, Dict[str, Any], Dict[str, jax.Array]]:
+        """``frozen``: a pytree of bools like ``params``; a True leaf and its
+        moments are returned as they were (state the model moves itself)."""
         cfg = self.cfg
+        if frozen is None:
+            frozen = jax.tree.map(lambda _: False, params)
         grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)
         count = state["count"] + 1
         cf = count.astype(jnp.float32)
         lr = self.schedule(count)
 
-        def moments(g, mu, nu):
+        def moments(g, mu, nu, still):
+            if still:
+                return mu, nu
             g = g.astype(jnp.float32)
             mu2 = cfg.b1 * mu + (1 - cfg.b1) * g
             nu2 = cfg.b2 * nu + (1 - cfg.b2) * jnp.square(g)
             return mu2, nu2
 
-        mus_nus = jax.tree.map(moments, grads, state["mu"], state["nu"])
+        mus_nus = jax.tree.map(moments, grads, state["mu"], state["nu"], frozen)
         mu = jax.tree.map(lambda t: t[0], mus_nus, is_leaf=lambda t: isinstance(t, tuple))
         nu = jax.tree.map(lambda t: t[1], mus_nus, is_leaf=lambda t: isinstance(t, tuple))
 
         b1c = 1 - cfg.b1 ** cf
         b2c = 1 - cfg.b2 ** cf
 
-        def step(p, m, v):
+        def step(p, m, v, still):
+            if still:
+                return p
             upd = (m / b1c) / (jnp.sqrt(v / b2c) + cfg.eps)
             upd = upd + cfg.weight_decay * p.astype(jnp.float32)
             return (p.astype(jnp.float32) - lr * upd).astype(p.dtype)
 
-        new_params = jax.tree.map(step, params, mu, nu)
+        new_params = jax.tree.map(step, params, mu, nu, frozen)
         new_state = {"mu": mu, "nu": nu, "count": count}
         return new_params, new_state, {"grad_norm": gnorm, "lr": lr}

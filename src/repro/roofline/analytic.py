@@ -31,6 +31,15 @@ def _attn_layer_flops(cfg, S: int, T_ctx: float, *, decode: bool) -> float:
     return proj + scores
 
 
+def _mla_layer_flops(cfg, T_ctx: float) -> float:
+    """MLA per token: query, latent, key/value and output projections, then
+    scores over nope+rope and values over v per head."""
+    m, D, H = cfg.mla, cfg.d_model, cfg.n_heads
+    proj = 2 * D * H * m.qk_head_dim + 2 * D * (m.kv_lora_rank + m.qk_rope_head_dim)
+    proj += 2 * m.kv_lora_rank * H * (m.qk_nope_head_dim + m.v_head_dim) + 2 * H * m.v_head_dim * D
+    return proj + 2 * H * (m.qk_head_dim + m.v_head_dim) * T_ctx
+
+
 def _mlp_flops(cfg, d_ff: int) -> float:
     n_mat = 3 if cfg.activation == "swiglu" else 2
     return n_mat * 2 * cfg.d_model * d_ff
@@ -51,7 +60,7 @@ def _moe_layer_flops(cfg, S_block: int, *, capacity_factor: float = 1.25) -> flo
     dispatch = 2 * EC * D * 2  # dispatch + combine
     n_mat = 3 if cfg.activation == "swiglu" else 2
     experts = K * capacity_factor * n_mat * 2 * D * F
-    shared = _mlp_flops(cfg, F) if spec.shared_expert else 0.0
+    shared = _mlp_flops(cfg, spec.n_shared * F)
     return router + dispatch + experts + shared
 
 
@@ -92,9 +101,11 @@ def flops_breakdown(cfg, shape, *, moe_block: int = 0) -> Dict[str, float]:
         T_full = float(S)
     else:
         T_full = (S + 1) / 2.0  # causal average
-    for spec in cfg.pattern:
-        n = cfg.n_units
-        if spec.mixer in ("attn", "attn_local"):
+    layers = [(spec, 1) for spec in cfg.lead] + [(spec, cfg.n_units) for spec in cfg.pattern]
+    for spec, n in layers:
+        if spec.mixer == "mla":
+            out["mixer"] += n * _mla_layer_flops(cfg, T_full)
+        elif spec.mixer in ("attn", "attn_local"):
             T_ctx = T_full
             if spec.mixer == "attn_local" and cfg.attn_window:
                 T_ctx = min(T_full, float(cfg.attn_window))

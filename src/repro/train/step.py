@@ -20,6 +20,10 @@ is the standard memory-bounded training configuration.
 
 State pytree: {params, opt_state{mu,nu,count}, step, [ef]}.  All entries
 inherit parameter shardings leaf-for-leaf; ``ef`` carries a leading pod dim.
+Leaves the model marks as its own state (``Model.state_leaves``: MoE
+correction biases) are left alone by the optimizer and moved after it by
+``Model.after_step`` from the step's expert loads; a step's metrics then
+also hold ``moe``, the MoE counters summed over layers.
 """
 
 from __future__ import annotations
@@ -83,12 +87,13 @@ def state_shardings(state_abstract, mesh: Mesh, *, fsdp: bool = False):
 
 
 def _microbatched_grads(model, params, batch, microbatches: int, loss_chunk: int):
-    """Mean loss/grads over ``microbatches`` sequential slices (lax.scan)."""
+    """Mean loss/grads over ``microbatches`` sequential slices (lax.scan);
+    MoE counters and loads (``metrics["moe"]``) summed over them."""
     loss_fn = lambda p, b: model.train_loss(p, b, loss_chunk=loss_chunk)
 
     if microbatches == 1:
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
-        return loss, metrics, grads
+        return loss, {k: metrics[k] for k in ("loss", "moe") if k in metrics}, grads
 
     from repro.distributed.vma import vary
 
@@ -100,17 +105,20 @@ def _microbatched_grads(model, params, batch, microbatches: int, loss_chunk: int
 
     def acc_step(carry, one):
         g_acc, l_acc = carry
-        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, one)
+        (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, one)
         g_acc = jax.tree.map(lambda a, g: a + g.astype(jnp.float32), g_acc, grads)
-        return (g_acc, l_acc + loss), None
+        return (g_acc, l_acc + loss), metrics.get("moe")
 
-    (g_acc, l_acc), _ = jax.lax.scan(
+    (g_acc, l_acc), moe = jax.lax.scan(
         acc_step, (zero_grads, vary(jnp.zeros((), jnp.float32))), mb
     )
     inv = 1.0 / microbatches
     grads = jax.tree.map(lambda g: g * inv, g_acc)
     loss = l_acc * inv
-    return loss, {"loss": loss}, grads
+    metrics = {"loss": loss}
+    if moe is not None:
+        metrics["moe"] = jax.tree.map(lambda x: x.sum(axis=0), moe)
+    return loss, metrics, grads
 
 
 def build_train_step(
@@ -134,14 +142,19 @@ def build_train_step(
             model, state["params"], batch, microbatches, loss_chunk
         )
         ef = state.get("ef")
+        moe = metrics.get("moe")
         if cross_pod != "auto":
             grads, ef = hierarchical_grad_mean(
                 grads, ef, compress=(cross_pod == "compressed")
             )
             loss = jax.lax.pmean(loss, "pod")
+            if moe is not None:
+                moe = jax.lax.psum(moe, "pod")
         new_params, new_opt, stats = optimizer.update(
-            grads, state["opt_state"], state["params"]
+            grads, state["opt_state"], state["params"], frozen=model.state_leaves(state["params"])
         )
+        if moe is not None:
+            new_params = model.after_step(new_params, {"moe": moe})
         new_state: TrainState = {
             "params": new_params,
             "opt_state": new_opt,
@@ -150,6 +163,8 @@ def build_train_step(
         if "ef" in state:
             new_state["ef"] = ef if cross_pod == "compressed" else state["ef"]
         out_metrics = {"loss": loss, **stats}
+        if moe is not None:
+            out_metrics["moe"] = {k: v for k, v in moe.items() if k != "load"}
         return new_state, out_metrics
 
     if cross_pod == "auto":
@@ -177,7 +192,7 @@ def build_train_step(
 
         def step_fn(state, batch):
             st_specs, b_specs = specs_of(state, batch)
-            out_specs = (st_specs, {"loss": P(), "grad_norm": P(), "lr": P()})
+            out_specs = (st_specs, P())
             return jax.shard_map(
                 body_manual,
                 mesh=mesh,

@@ -10,10 +10,13 @@ trace is taken.
 
 Spans (parent > children; tags in brackets):
 
-- ``train.iter`` > ``train.batch`` [bytes], ``train.step``, ``ckpt.d2h``
-  [bytes], ``ckpt.save``: one iteration of ``Trainer.run``; ``train.batch``
-  is ``batch_at`` plus ``device_put``, ``train.step`` the dispatch through
-  ``block_until_ready``, ``ckpt.d2h`` the state's copy to the host;
+- ``train.iter`` > ``train.batch`` [bytes], ``train.step`` [MoE models:
+  ``moe_routed``, ``moe_kept``, ``moe_slots``], ``ckpt.d2h`` [bytes],
+  ``ckpt.save``: one iteration of ``Trainer.run``; ``train.batch`` is
+  ``batch_at`` plus ``device_put``, ``train.step`` the dispatch through
+  ``block_until_ready`` (its tags the step's MoE counters summed over
+  layers: choices bound for the experts held here, those kept within
+  capacity, expert slots computed), ``ckpt.d2h`` the state's copy to the host;
 - ``ckpt.save`` [step] > ``ckpt.pack``, ``ckpt.write`` [shard, bytes],
   ``ckpt.publish``; ``ckpt.write`` > ``scidata.serialize``, ``store.write``
   [bytes] (native mode); ``ckpt.publish`` > ``meu.export``, ``ckpt.index``;

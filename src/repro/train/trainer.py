@@ -147,6 +147,8 @@ class Trainer:
                 sp.tags = {"bytes": sum(x.nbytes for x in jax.tree.leaves(batch_np))}
         with span("train.step") as sp:
             self.state, metrics = jax.block_until_ready(self.step_fn(self.state, batch))
+        if TELEMETRY.tracer.enabled and "moe" in metrics:
+            sp.tags = {f"moe_{k}": int(v) for k, v in metrics["moe"].items()}
         dt = sp.end - sp.start
         if self.balancer is not None:
             self.balancer.report(self.pipeline.dp_rank, dt)

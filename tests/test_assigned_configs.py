@@ -16,12 +16,14 @@ ASSIGNED = {
     "llama4-maverick-400b-a17b": (5120, 48, 40, 8, 8192, 202048),
     "olmoe-1b-7b": (2048, 16, 16, 16, 1024, 50304),
     "internvl2-2b": (2048, 24, 16, 8, 8192, 92553),
+    "moonlight-16b-a3b": (2048, 27, 16, 16, 1408, 163840),
 }
 
 MOE = {
     "jamba-v0.1-52b": (16, 2),
     "llama4-maverick-400b-a17b": (128, 1),
     "olmoe-1b-7b": (64, 8),
+    "moonlight-16b-a3b": (64, 6),
 }
 
 
@@ -55,7 +57,7 @@ def test_family_signatures():
     assert ARCHS["nemotron-4-15b"].activation == "relu2"
     assert ARCHS["stablelm-3b"].rope_fraction == 0.25
     assert ARCHS["seamless-m4t-medium"].is_encdec and ARCHS["seamless-m4t-medium"].n_enc_layers == 12
-    assert ARCHS["llama4-maverick-400b-a17b"].moe.shared_expert
+    assert ARCHS["llama4-maverick-400b-a17b"].moe.n_shared == 1
     assert ARCHS["olmoe-1b-7b"].qk_norm
     assert ARCHS["internvl2-2b"].frontend == "vision"
     assert ARCHS["seamless-m4t-medium"].frontend == "audio"
@@ -70,10 +72,10 @@ def test_shape_cells():
 
 
 def test_cell_count_and_skips():
-    """10 archs × 4 shapes = 40 assigned cells; long_500k runs only for the
+    """11 archs × 4 shapes = 44 cells; long_500k runs only for the
     sub-quadratic archs (jamba, gemma2, rwkv6)."""
     runnable = all_cells()
-    assert len(runnable) == 33  # 40 − 7 long_500k skips
+    assert len(runnable) == 36  # 44 − 8 long_500k skips
     long_runners = {c.name for c, s in runnable if s.name == "long_500k"}
     assert long_runners == {"jamba-v0.1-52b", "gemma2-2b", "rwkv6-7b"}
     for cfg in ARCHS.values():
