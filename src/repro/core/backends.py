@@ -33,6 +33,8 @@ import time
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Tuple
 
+import numpy as np
+
 __all__ = [
     "StatResult",
     "StorageBackend",
@@ -130,6 +132,24 @@ class StorageBackend:
     ) -> "Tuple[int, float]":
         return self.write(path, data, offset=offset, owner=owner), 0.0
 
+    # -- reads into a caller's array -----------------------------------------
+    def read_into_deferred(self, path: str, dest: np.ndarray, *, offset: int = 0) -> float:
+        """Fill ``dest`` (a writable array; a strided view too, filled in C
+        order) with the ``dest.nbytes`` bytes at ``offset``; ``EOFError`` if
+        the file ends first.  Returns the modeled store delay, unslept.  This
+        default reads the bytes, then copies them into ``dest``."""
+        data, delay = self.read_deferred(path, offset=offset, length=dest.nbytes)
+        if len(data) < dest.nbytes:
+            raise EOFError(f"{path}: {dest.nbytes} bytes at {offset} run past its end")
+        np.copyto(dest, np.frombuffer(data, dest.dtype, dest.size).reshape(dest.shape))
+        return delay
+
+    def read_into(self, path: str, dest: np.ndarray, *, offset: int = 0) -> None:
+        """:meth:`read_into_deferred` with the store delay slept."""
+        delay = self.read_into_deferred(path, dest, offset=offset)
+        if delay > 0:
+            time.sleep(delay)
+
     def create(self, path: str, *, owner: str = "") -> None:
         """Create an empty file (the paper's zero-size-file MEU workload)."""
         self.write(path, b"", owner=owner)
@@ -209,6 +229,12 @@ class MemoryBackend(StorageBackend):
     ``store_gbps`` (0 ⇒ free) models the PFS data-plane bandwidth so that
     benchmark ratios between metadata-bound and data-bound paths resemble a
     real Lustre deployment rather than RAM speed (DESIGN.md §8).
+
+    A read into a caller's array copies outside the store lock, so reads of
+    many files (or ranges) run in parallel.  What it copies from never
+    changes under it: a full rewrite swaps in a new buffer (as ``adopt``
+    does), and a partial write to a file that such a read is copying from
+    writes to a copy of the buffer instead.
     """
 
     def __init__(self, dc_id: str, *, store_gbps: float = 0.0, store_lat_s: float = 0.0):
@@ -220,6 +246,8 @@ class MemoryBackend(StorageBackend):
         self._files: Dict[str, Optional[bytearray]] = {"/": None}
         self._meta: Dict[str, Dict] = {"/": {"ctime": time.time(), "mtime": time.time(), "owner": ""}}
         self._bytes_written = 0
+        #: path -> reads into a caller's array copying from its buffer now
+        self._reading: Dict[str, int] = {}
 
     def store_delay_for(self, nbytes: int) -> float:
         delay = self.store_lat_s if nbytes > 0 else 0.0
@@ -273,12 +301,16 @@ class MemoryBackend(StorageBackend):
         path = _norm(path)
         with self._lock:
             buf = self._file_locked(path, owner)
-            if offset > len(buf):
-                buf.extend(b"\x00" * (offset - len(buf)))
-            buf[offset : offset + len(data)] = data
             if offset == 0:
-                # full rewrite: drop any stale tail (O_TRUNC semantics)
-                del buf[len(data):]
+                # full rewrite (O_TRUNC semantics) into a new buffer
+                self._files[path] = bytearray(data)
+            else:
+                if path in self._reading:
+                    # a read is copying from ``buf``: leave it as it is
+                    buf = self._files[path] = bytearray(buf)
+                if offset > len(buf):
+                    buf.extend(b"\x00" * (offset - len(buf)))
+                buf[offset : offset + len(data)] = data
             self._bytes_written += len(data)
         self.invalidate_sync_up(path)
         return len(data), self.store_delay_for(len(data))
@@ -311,6 +343,28 @@ class MemoryBackend(StorageBackend):
                 raise FileNotFoundError(path)
             out = bytes(buf[offset:]) if length < 0 else bytes(buf[offset : offset + length])
         return out, self.store_delay_for(len(out))
+
+    def read_into_deferred(self, path: str, dest: np.ndarray, *, offset: int = 0) -> float:
+        """One copy, from the stored buffer straight into ``dest``, made
+        outside the store lock (see the class notes)."""
+        path = _norm(path)
+        nbytes = dest.nbytes
+        with self._lock:
+            buf = self._files.get(path)
+            if not isinstance(buf, bytearray):
+                raise FileNotFoundError(path)
+            if offset + nbytes > len(buf):
+                raise EOFError(f"{path}: {nbytes} bytes at {offset} run past its end")
+            self._reading[path] = self._reading.get(path, 0) + 1
+        try:
+            # the view of ``buf`` dies with this statement, before the count drops
+            np.copyto(dest, np.frombuffer(buf, dest.dtype, dest.size, offset).reshape(dest.shape))
+        finally:
+            with self._lock:
+                left = self._reading.pop(path) - 1
+                if left:
+                    self._reading[path] = left
+        return self.store_delay_for(nbytes)
 
     def mkdir(self, path: str, *, owner: str = "", exist_ok: bool = True) -> None:
         path = _norm(path)
@@ -425,6 +479,27 @@ class PosixBackend(StorageBackend):
         with open(host, "rb") as fh:
             fh.seek(offset)
             return fh.read() if length < 0 else fh.read(length)
+
+    def read_into_deferred(self, path: str, dest: np.ndarray, *, offset: int = 0) -> float:
+        """``readinto`` a contiguous ``dest`` itself; a strided one through
+        one bounce buffer."""
+        host = self._host(path)
+        if not os.path.isfile(host):
+            raise FileNotFoundError(path)
+        direct = dest.flags.c_contiguous
+        flat = dest.reshape(-1).view(np.uint8) if direct else np.empty(dest.nbytes, np.uint8)
+        view = memoryview(flat)
+        got = 0
+        with open(host, "rb", buffering=0) as fh:
+            fh.seek(offset)
+            while got < len(view):
+                n = fh.readinto(view[got:])
+                if not n:
+                    raise EOFError(f"{path}: {dest.nbytes} bytes at {offset} run past its end")
+                got += n
+        if not direct:
+            np.copyto(dest, flat.view(dest.dtype).reshape(dest.shape))
+        return 0.0
 
     def mkdir(self, path: str, *, owner: str = "", exist_ok: bool = True) -> None:
         os.makedirs(self._host(path), exist_ok=exist_ok)

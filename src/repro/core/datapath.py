@@ -64,6 +64,8 @@ import time
 from collections import OrderedDict
 from typing import TYPE_CHECKING, Any, Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from .metadata import path_hash
 from .rpc import Channel, RetryPolicy, RpcError, RpcTimeout, RpcUnavailable
 from .telemetry import now as _tel_now
@@ -438,9 +440,9 @@ class DataPath:
     """One mount's striped/cached/read-ahead engine for cross-DC byte movement.
 
     All remote transfers flow through :meth:`read` / :meth:`read_range` /
-    :meth:`write`; the home-DC fast path stays in the workspace (a local read
-    is a plain PFS access — the cache and lanes model the *wide-area* story,
-    matching the paper's native-access framing).
+    :meth:`read_range_into` / :meth:`write`; the home-DC fast path stays in
+    the workspace (a local read is a plain PFS access — the cache and lanes
+    model the *wide-area* story, matching the paper's native-access framing).
     """
 
     def __init__(
@@ -678,8 +680,14 @@ class DataPath:
         return out
 
     def _fetch(
-        self, dc_id: str, path: str, ranges: Sequence[_Range], *, prefetch: bool = False
-    ) -> List[Tuple[int, bytes]]:
+        self,
+        dc_id: str,
+        path: str,
+        ranges: Sequence[_Range],
+        *,
+        prefetch: bool = False,
+        into: Optional[np.ndarray] = None,
+    ) -> List[Tuple[int, Any]]:
         """Move byte ranges from ``dc_id``'s PFS over the lane pool.
 
         Each merged range is ONE streaming store op (deferred — one PFS
@@ -688,11 +696,13 @@ class DataPath:
         time.  The pipelined makespan is computed analytically and slept
         once — the wall-clock a real laned, pipelined transfer pays.
         Nothing is cached here; the caller owns generation-checked
-        insertion."""
+        insertion.  With ``into`` the one range is one stream that the
+        store copies into that array (its part is ``(offset, into)``)."""
         dc = self.collab.dc(dc_id)
         self._require_live(dc)
         backend = dc.backend
-        parts: List[Tuple[int, bytes]] = []
+        parts: List[Tuple[int, Any]] = []
+        sizes: List[int] = []
         pieces: List[Tuple[float, int]] = []
         failure: Optional[RpcUnavailable] = None
         for s, e in merge_ranges(ranges):
@@ -705,13 +715,19 @@ class DataPath:
                 except RpcUnavailable as exc:
                     failure = exc
                     break
-            data, store_s = backend.read_deferred(path, offset=s, length=e - s)
-            if data:
+            if into is None:
+                data, store_s = backend.read_deferred(path, offset=s, length=e - s)
+                n = len(data)
+            else:
+                store_s = backend.read_into_deferred(path, into, offset=s)
+                data, n = into, e - s
+            if n:
                 parts.append((s, data))
-                chunks = self._chop(s, s + len(data))
+                sizes.append(n)
+                chunks = self._chop(s, s + n)
                 for cs, ce in chunks:
-                    pieces.append((store_s * (ce - cs) / len(data), ce - cs))
-            if len(data) < e - s:
+                    pieces.append((store_s * (ce - cs) / n, ce - cs))
+            if n < e - s:
                 break  # short read: EOF inside the range
         if failure is None:
             # a DTN crash while chunks were in flight fails the transfer
@@ -723,14 +739,14 @@ class DataPath:
             # the most recently read stream was in flight at the failure —
             # not confirmed; drop it (and its lane pieces) so a resume
             # refetches it rather than trusting a possibly-torn stream
-            s, data = parts.pop()
-            del pieces[len(pieces) - len(self._chop(s, s + len(data))) :]
+            s, _ = parts.pop()
+            del pieces[len(pieces) - len(self._chop(s, s + sizes.pop())) :]
         makespan = self._handshake_s(dc_id, len(pieces)) + self._makespan_in(
             pieces, self._lanes(dc_id)
         )
         if makespan > 0:
             time.sleep(makespan)
-        moved = sum(len(d) for _, d in parts)
+        moved = sum(sizes)
         with self._stats_lock:
             self.wire_seconds += makespan
             if failure is not None:
@@ -751,24 +767,31 @@ class DataPath:
         return parts
 
     def _fetch_resumable(
-        self, dc_id: str, path: str, ranges: Sequence[_Range], *, prefetch: bool = False
-    ) -> List[Tuple[int, bytes]]:
+        self,
+        dc_id: str,
+        path: str,
+        ranges: Sequence[_Range],
+        *,
+        prefetch: bool = False,
+        into: Optional[np.ndarray] = None,
+    ) -> List[Tuple[int, Any]]:
         """:meth:`_fetch` under the retry policy: an interrupted transfer
         keeps the streams already delivered and refetches only the
         ``subtract_ranges`` remainder after a decorrelated-jitter backoff —
         resume from the last completed stripe, not byte zero.  With no policy
-        installed this is exactly ``_fetch`` (fail-fast)."""
+        installed this is exactly ``_fetch`` (fail-fast).  A read ``into`` an
+        array is one stream, so a cut one is refetched whole."""
         policy = self.retry
         if policy is None:
-            return self._fetch(dc_id, path, ranges, prefetch=prefetch)
-        have: List[Tuple[int, bytes]] = []
+            return self._fetch(dc_id, path, ranges, prefetch=prefetch, into=into)
+        have: List[Tuple[int, Any]] = []
         remaining = merge_ranges(ranges)
         deadline = time.perf_counter() + policy.deadline_s
         backoff = policy.base_s
         attempt = 1
         while True:
             try:
-                have.extend(self._fetch(dc_id, path, remaining, prefetch=prefetch))
+                have.extend(self._fetch(dc_id, path, remaining, prefetch=prefetch, into=into))
                 return have
             except RpcUnavailable as exc:
                 kept = getattr(exc, "parts", ())
@@ -825,6 +848,18 @@ class DataPath:
         return self._read(dc_id, path, start, min(start, size), size, epoch) if end <= start else self._read(
             dc_id, path, start, end, size, epoch
         )
+
+    def read_range_into(self, dc_id: str, path: str, offset: int, dest: np.ndarray) -> None:
+        """Ranged remote read of ``dest.nbytes`` bytes at ``offset`` straight
+        into ``dest`` (a writable array; a strided view too, filled in C
+        order); ``EOFError`` if the file ends first.  It moves them as a
+        cache-off :meth:`read_range` does: one stream, the same liveness
+        checks, lane pieces, handshake and modeled seconds slept, the same
+        counters, histograms and ``data.read`` trace, and under a retry
+        policy the same resume.  It neither reads nor fills the chunk cache:
+        the caller keeps the bytes.  Safe to call from many threads at once."""
+        if dest.nbytes:
+            self._fetch_resumable(dc_id, path, [(offset, offset + dest.nbytes)], into=dest)
 
     def _align(self, start: int, end: int, size: int) -> _Range:
         a = self.range_align

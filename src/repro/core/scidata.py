@@ -26,7 +26,7 @@ import ctypes
 import json
 import os
 import struct
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple, Union
 
@@ -44,6 +44,7 @@ __all__ = [
     "read_header_via",
     "read_dataset_via",
     "dataset_range",
+    "submit_copy",
     "attr_type_of",
 ]
 
@@ -144,6 +145,12 @@ def serialize_scidata(
     jobs = [(start + entry["offset"], arr) for entry, arr in zip(directory, pieces) if arr.size]
     list(_POOL.map(fill, sorted(jobs, key=lambda job: -job[1].nbytes)))
     return blob
+
+
+def submit_copy(fn: Callable, *args) -> Future:
+    """Run ``fn(*args)`` on the copy threads (:data:`COPY_THREADS`), where
+    the serializer's copies run: for copies NumPy makes without the GIL."""
+    return _POOL.submit(fn, *args)
 
 
 def write_scidata(

@@ -67,7 +67,7 @@ from .scidata import (
 )
 from .telemetry import SpanBuffer, Tracer
 
-__all__ = ["Workspace", "NativeSession", "WriteResult"]
+__all__ = ["Workspace", "OpenScidata", "NativeSession", "WriteResult"]
 
 #: a native session's tracer when it is given none: records nothing
 _NO_TRACER = Tracer("native", SpanBuffer(), enabled=False)
@@ -242,6 +242,8 @@ class Workspace:
         # our own metadata publications must not evict our own freshly
         # written-through chunks
         self.plane.attach_cache(self.datapath.cache)
+        #: path -> the scidata container opened there (open_scidata)
+        self._open: Dict[str, "OpenScidata"] = {}
 
     # -- internals ---------------------------------------------------------------
     def _owner(self, path: str) -> int:
@@ -650,9 +652,29 @@ class Workspace:
         self._readahead(entry, path, sci, after=None)
         return sci.attrs
 
+    @_traced("ws.open_scidata")
+    def open_scidata(self, path: str) -> "OpenScidata":
+        """Open a scidata container: one stat and one header read (a remote
+        header through the data path, as :meth:`read_attrs` reads it).  Until
+        it is closed, :meth:`read_dataset` of ``path`` makes neither again
+        and no read-ahead, and reads each dataset straight into the array
+        the handle's ``into`` holds for it."""
+        path = _norm(path)
+        entry = self.stat(path)
+        if entry is None:
+            raise FileNotFoundError(path)
+        sci = read_header_via(self._range_reader(entry, path), path)
+        handle = self._open[path] = OpenScidata(self, entry["dc_id"], path, sci)
+        return handle
+
     @_traced("ws.read_dataset")
     def read_dataset(self, path: str, name: str) -> np.ndarray:
+        """One named array of a scidata container; of an open one (see
+        :meth:`open_scidata`), read into the array its handle holds for it."""
         path = _norm(path)
+        handle = self._open.get(path)
+        if handle is not None:
+            return handle.read(name)
         entry = self.stat(path)
         if entry is None:
             raise FileNotFoundError(path)
@@ -901,6 +923,55 @@ class Workspace:
         cache dies with the client — it is volatile client state."""
         self.datapath.close()
         self.plane.crash()
+
+
+class OpenScidata:
+    """A scidata container opened by :meth:`Workspace.open_scidata`.
+
+    ``into`` maps dataset names to the arrays (strided views too) that
+    :meth:`Workspace.read_dataset` fills and returns for them, each once;
+    such an array has the dataset's ``dtype.str`` and shape, or is 0-d for
+    one stored as ``[1]``.  A dataset with none is read into a fresh array.
+    Reads may run on many threads at once."""
+
+    def __init__(self, ws: Workspace, dc_id: str, path: str, sci: SciFile):
+        self._ws = ws
+        self._dc_id = dc_id
+        self.path = path
+        self.sci = sci
+        self.into: Dict[str, np.ndarray] = {}
+
+    @property
+    def attrs(self) -> Dict[str, Any]:
+        return self.sci.attrs
+
+    def read(self, name: str) -> np.ndarray:
+        """Dataset ``name``, read off the home DC's store or through
+        :meth:`DataPath.read_range_into`, straight into its array."""
+        entry = self.sci.dataset(name)
+        if entry is None:
+            raise KeyError(f"{self.path}: no dataset {name!r}")
+        dest = self.into.pop(name, None)
+        stored = np.dtype(entry["dtype"])
+        if dest is None:
+            dest = np.empty(entry["shape"], stored)
+        elif np.dtype(dest.dtype.str) != stored or np.atleast_1d(dest).shape != tuple(entry["shape"]):
+            raise ValueError(
+                f"{self.path}: {name!r} is {entry['dtype']} {entry['shape']}, "
+                f"not {dest.dtype.str} {list(dest.shape)}"
+            )
+        offset, _ = dataset_range(self.sci, entry)
+        ws = self._ws
+        if self._dc_id == ws.home_dc:
+            ws.collab.dc(self._dc_id).backend.read_into(self.path, dest, offset=offset)
+        else:
+            ws.datapath.read_range_into(self._dc_id, self.path, offset, dest)
+        return dest
+
+    def close(self) -> None:
+        """:meth:`Workspace.read_dataset` of this path reads as before."""
+        if self._ws._open.get(self.path) is self:
+            del self._ws._open[self.path]
 
 
 class NativeSession:

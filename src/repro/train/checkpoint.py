@@ -28,6 +28,7 @@ topology) is therefore reshard-on-load by construction.
 from __future__ import annotations
 
 import json
+from concurrent import futures
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -35,7 +36,7 @@ import jax
 import numpy as np
 
 from repro.core.meu import MEU
-from repro.core.scidata import read_header
+from repro.core.scidata import submit_copy
 from repro.core.workspace import NativeSession, Workspace
 from repro.train.telemetry import TELEMETRY, span
 
@@ -188,67 +189,103 @@ class CheckpointManager:
         return steps[-1] if steps else None
 
     def restore(self, state_like, step: Optional[int] = None, *, shardings=None):
-        """Rebuild a state pytree; reshard-on-load when ``shardings`` given.
+        """Rebuild a state pytree; reshard-on-load when ``shardings`` given
+        (host arrays, byte for byte as saved, without).
 
-        Three phases under one ``ckpt.restore`` span: read every shard;
-        assemble each leaf on the host and start its copy to the devices
-        (``ckpt.put``); wait for the copies (the returned tree is ready)."""
+        Under one ``ckpt.restore`` span: open every shard (``ckpt.open``: one
+        stat, one header read); then, largest leaf first, allocate the leaf
+        once and have ``read_dataset`` read each shard's piece of it straight
+        into its slot, on the copy threads (``ckpt.read`` waits for them);
+        cast it and start its copy to the devices (``ckpt.assemble`` >
+        ``ckpt.put``) while the next leaf's pieces are read; last, wait for
+        the copies (``ckpt.place``)."""
         if step is None:
             step = self.latest_step()
             if step is None:
                 raise FileNotFoundError(f"no checkpoints for run {self.run!r}")
-        traced = TELEMETRY.tracer.enabled
+        leaves = _flatten_with_paths(state_like)
+        treedef = jax.tree_util.tree_structure(state_like)
+        targets = (
+            treedef.flatten_up_to(shardings) if shardings is not None else [None] * len(leaves)
+        )
+        datapath = self.ws.datapath
         with span("ckpt.restore", step=step, shards=self.n_shards) as sp_restore:
-            # read every shard through the workspace (any pod can restore any run)
-            shard_arrays: List[Dict[str, np.ndarray]] = []
-            split_axes: Dict[str, int] = {}
-            total_bytes = 0
-            datapath = self.ws.datapath
-            for s in range(self.n_shards):
-                with span("ckpt.read", shard=s) as sp:
-                    wire0 = datapath.wire_seconds
-                    path = self._path(step, s)
-                    attrs = self.ws.read_attrs(path)
-                    split_axes = json.loads(attrs["split_axes"])
-                    entry = self.ws.stat(path)
-                    dc = self.collab.dc(entry["dc_id"])
-                    hdr = read_header(dc.backend, path)
-                    arrays = {d["name"]: self.ws.read_dataset(path, d["name"]) for d in hdr.datasets}
-                    if traced:
-                        sp.tags["bytes"] = sum(a.nbytes for a in arrays.values())
-                        sp.wire_s = datapath.wire_seconds - wire0
-                        total_bytes += sp.tags["bytes"]
-                shard_arrays.append(arrays)
-
-            leaves = _flatten_with_paths(state_like)
-            treedef = jax.tree_util.tree_structure(state_like)
-            targets = (
-                treedef.flatten_up_to(shardings) if shardings is not None else [None] * len(leaves)
-            )
-            with span("ckpt.assemble"):
-                # pop each shard's piece and start each leaf's copy to its
-                # device as soon as the leaf is rebuilt: the copies overlap
-                # the next leaves' assembly, and the host holds the read
-                # shards plus one leaf, never a second whole copy of the state
-                rebuilt = []
-                for (path, like), target in zip(leaves, targets):
-                    ax = split_axes[path]
-                    if ax < 0:
-                        arr = shard_arrays[0].pop(path)
-                    else:
-                        arr = np.concatenate([sa.pop(path) for sa in shard_arrays], axis=ax)
-                    if hasattr(like, "shape"):
-                        # scidata stores 0-d arrays as [1] (ascontiguousarray quirk)
-                        arr = arr.reshape(like.shape)
-                    if hasattr(like, "dtype"):
-                        arr = arr.astype(like.dtype, copy=False)
-                    if target is not None:
-                        with span("ckpt.put"):
-                            arr = jax.device_put(arr, target)
-                    rebuilt.append(arr)
+            wire0 = datapath.wire_seconds
+            shards = []
+            try:
+                for s in range(self.n_shards):
+                    with span("ckpt.open", shard=s) as sp:
+                        wire_open = datapath.wire_seconds
+                        shards.append(self.ws.open_scidata(self._path(step, s)))
+                        sp.wire_s = datapath.wire_seconds - wire_open
+                rebuilt = self._read_leaves(shards, leaves, targets, sp_restore.tags)
+            finally:
+                for h in shards:
+                    h.close()
             with span("ckpt.place"):
-                # the copies the assembly did not hide
+                # the copies the reads did not hide
                 rebuilt = jax.block_until_ready(rebuilt)
-            if traced:
-                sp_restore.tags["bytes"] = total_bytes
+            sp_restore.wire_s = datapath.wire_seconds - wire0
         return jax.tree_util.tree_unflatten(treedef, rebuilt)
+
+    def _read_leaves(self, shards, leaves, targets, tags: Dict[str, Any]) -> List[Any]:
+        """Each leaf read from the open ``shards`` and its copy to its target
+        started; ``tags`` gain the bytes read and those read straight into
+        their leaf."""
+        split_axes = json.loads(shards[0].attrs["split_axes"])
+        stored_bytes: Dict[str, int] = {}
+        for h in shards:
+            for d in h.sci.datasets:
+                stored_bytes[d["name"]] = stored_bytes.get(d["name"], 0) + d["nbytes"]
+        tags.update(bytes=0, into_bytes=0)
+        rebuilt: List[Any] = [None] * len(leaves)
+
+        def start(i: int):
+            """Allocate leaf ``i`` and queue the reads of its pieces."""
+            path, like = leaves[i]
+            ax = split_axes[path]
+            holders = shards[:1] if ax < 0 else shards
+            stored = holders[0].sci.dataset(path)["dtype"]
+            want = np.dtype(getattr(like, "dtype", stored))
+            # a type stored by its ``dtype.str`` alone (bfloat16: '<V2') is read as itself
+            dest = np.empty(np.shape(like), want if want.str == stored else stored)
+            slots = [dest] if ax < 0 else np.split(dest, len(shards), axis=ax)
+            for h, slot in zip(holders, slots):
+                h.into[path] = slot
+            reads = [submit_copy(self.ws.read_dataset, h.path, path) for h in holders]
+            return i, dest, slots, reads
+
+        def finish(i: int, dest: np.ndarray, slots, reads) -> None:
+            with span("ckpt.read", leaf=leaves[i][0]) as sp:
+                for slot, read in zip(slots, reads):
+                    got = read.result()
+                    if got is slot:
+                        tags["into_bytes"] += slot.nbytes
+                    else:
+                        # a wrapper of ``read_dataset`` handed back an array of
+                        # its own: that is what was read
+                        np.copyto(slot, got.reshape(slot.shape))
+            sp.tags["bytes"] = dest.nbytes
+            tags["bytes"] += dest.nbytes
+            with span("ckpt.assemble"):
+                arr = dest.astype(getattr(leaves[i][1], "dtype", dest.dtype), copy=False)
+                if targets[i] is not None:
+                    with span("ckpt.put"):
+                        arr = jax.device_put(arr, targets[i])
+            rebuilt[i] = arr
+
+        # the pieces of the next leaf are read while this one is placed
+        pending = []
+        try:
+            for i in sorted(range(len(leaves)), key=lambda i: -stored_bytes[leaves[i][0]]):
+                pending.append(start(i))
+                if len(pending) > 1:
+                    finish(*pending[0])
+                    pending.pop(0)
+            while pending:
+                finish(*pending[0])
+                pending.pop(0)
+        finally:
+            # after a failed read, the reads still running end before the shards close
+            futures.wait([read for *_, reads in pending for read in reads])
+        return rebuilt

@@ -21,10 +21,15 @@ Spans (parent > children; tags in brackets):
   ``ckpt.publish``; ``ckpt.write`` > ``scidata.serialize``, ``store.write``
   [bytes] (native mode); ``ckpt.publish`` > ``meu.export``, ``ckpt.index``;
 - ``ckpt.discover``: the SDS query for the run's steps;
-- ``ckpt.restore`` [step, shards, bytes] > ``ckpt.read`` [shard, bytes;
-  ``wire_s`` the data path's modeled seconds], ``ckpt.assemble`` (every
-  leaf rebuilt on the host) > ``ckpt.put`` (one leaf's ``device_put``),
-  then ``ckpt.place`` (the wait for the copies the assembly did not hide).
+- ``ckpt.restore`` [step, shards, bytes, into_bytes] > ``ckpt.open``
+  [shard] (one stat and one header read), then per leaf, largest first,
+  ``ckpt.read`` [leaf, bytes] (the wait for its pieces, read on the copy
+  threads) and ``ckpt.assemble`` (its cast) > ``ckpt.put`` (its
+  ``device_put``), then ``ckpt.place`` (the wait for the copies the reads
+  did not hide).  ``wire_s`` of ``ckpt.restore`` and ``ckpt.open`` is the
+  data path's modeled seconds; ``into_bytes`` counts the bytes read
+  straight into their leaf, ``bytes`` when no read handed back an array of
+  its own.
 
 With the tracer off nothing is recorded or annotated and no tag is
 computed; :func:`span` still times its block, since the trainer's rows and

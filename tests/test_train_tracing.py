@@ -106,14 +106,25 @@ def test_spans_form_the_documented_tree(traced):
             assert store.tags["copied"] == 0
         assert names(kids[save.span_id][-1]) == ["meu.export", "ckpt.index"]
     restore = roots[-1]
-    assert names(restore) == ["ckpt.read"] * SHARDS + ["ckpt.assemble", "ckpt.place"]
-    assemble = kids[restore.span_id][SHARDS]
-    assert names(assemble) == ["ckpt.put"] * len(jax.tree.leaves(restored))
-    reads = kids[restore.span_id][:SHARDS]
-    assert restore.tags["bytes"] == sum(r.tags["bytes"] for r in reads) > 0
+    n_leaves = len(jax.tree.leaves(restored))
+    assert names(restore) == (
+        ["ckpt.open"] * SHARDS + ["ckpt.read", "ckpt.assemble"] * n_leaves + ["ckpt.place"]
+    )
+    opens = kids[restore.span_id][:SHARDS]
+    assert [o.tags["shard"] for o in opens] == list(range(SHARDS))
+    reads = kids[restore.span_id][SHARDS:-1:2]
+    for assemble in kids[restore.span_id][SHARDS + 1:-1:2]:
+        assert names(assemble) == ["ckpt.put"]
+    # one read per leaf, largest first
+    assert len({r.tags["leaf"] for r in reads}) == n_leaves
+    sizes = [r.tags["bytes"] for r in reads]
+    assert sizes == sorted(sizes, reverse=True)
+    assert restore.tags["bytes"] == sum(sizes) > 0
+    # every piece was copied by the store straight into its leaf
+    assert restore.tags["into_bytes"] == restore.tags["bytes"]
     assert restore.tags["shards"] == SHARDS and restore.tags["step"] == STEPS
     # the in-memory channel models no wire time: the restore's time is host work
-    assert all(r.wire_s == 0.0 for r in reads)
+    assert all(r.wire_s == 0.0 for r in opens + reads)
 
     # each save and each restore is one trace, each its own
     def descendants(root):
@@ -136,7 +147,8 @@ def test_collaboration_assembles_a_checkpoint_trace(traced):
     collab, spans, _, restored = traced
     restore = [s for s in spans if s.name == "ckpt.restore"][-1]
     tree = collab.collect_trace(restore.trace_id)
-    assert tree["n_spans"] == SHARDS + 3 + len(jax.tree.leaves(restored))
+    # the restore, its opens, its place, and a read, a cast and a put per leaf
+    assert tree["n_spans"] == 2 + SHARDS + 3 * len(jax.tree.leaves(restored))
     assert [r["name"] for r in tree["roots"]] == ["ckpt.restore"]
 
 
