@@ -6,9 +6,7 @@
 Runs a compact collaboration workload (write + tag + search + cross-DC
 read-back, two workspaces on opposite DCs) once per canned
 :class:`repro.core.faults.FaultPlan` ("drops", "flaky", "crash", "chaos",
-"quorum", "lease-expiry" — see benchmarks/fig13_faults.py and
-benchmarks/fig14_quorum.py for the injection how-to) and asserts, for
-every cell of the matrix:
+"quorum", "lease-expiry") and asserts, for every cell of the matrix:
 
 - the workload **completes** (retries + backoff ride out every injected
   fault, including the mid-workload DTN crash of the "crash" plan);
@@ -28,7 +26,7 @@ every read-back must match what was written into the partition.
 
 Plans are seeded, so a red cell replays deterministically with the printed
 seed.  Exit code 0 = all cells green; the failing plan name is in the
-traceback otherwise.  scripts/tier1.sh runs this after the pytest ratchet.
+traceback otherwise.
 """
 
 from __future__ import annotations

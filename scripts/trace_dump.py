@@ -4,9 +4,9 @@
     PYTHONPATH=src python scripts/trace_dump.py                 # text timelines
     PYTHONPATH=src python scripts/trace_dump.py --plan chaos    # under faults
     PYTHONPATH=src python scripts/trace_dump.py --chrome t.json # Perfetto export
-    PYTHONPATH=src python scripts/trace_dump.py --smoke         # CI smoke cell
+    PYTHONPATH=src python scripts/trace_dump.py --smoke         # smoke check
 
-Builds a two-DC collaboration (benchmark channel model, so spans carry real
+Builds a two-DC collaboration (modeled channels, so spans carry real
 modeled wire time), runs a write / flush / cross-DC read / tag / search
 sequence — optionally under a canned :class:`repro.core.faults.FaultPlan` —
 then reassembles each operation's spans with
@@ -15,7 +15,7 @@ then reassembles each operation's spans with
 exports every buffered span as Chrome-trace JSON (load in chrome://tracing
 or Perfetto: sites are rows, traces are lanes).
 
-``--smoke`` is the tier-1 cell (scripts/tier1.sh): replay the chaos plan,
+``--smoke`` replays the chaos plan,
 then assert the unified scrape ``Workspace.telemetry()`` is non-empty and
 JSON-serializable and that ``collect_trace`` reassembles a non-empty tree
 for the last traced op.  Prints ``trace smoke: OK`` and exits 0 when green.
@@ -31,9 +31,9 @@ import sys
 sys.path.insert(
     0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
 )
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from repro.core import (  # noqa: E402
+    Channel,
     Collaboration,
     RetryPolicy,
     Workspace,
@@ -49,11 +49,17 @@ RETRY = RetryPolicy(
 
 
 def _make_collab() -> Collaboration:
-    from benchmarks.common import make_collab
-
-    # zeroed store keeps the dump quick; the channel latencies still give
+    # free stores keep the dump quick; the channel latencies still give
     # every cross-DC span real modeled wire time
-    return make_collab(store_gbps=0.0, store_lat_s=0.0)
+    def channels(a: str, b: str) -> Channel:
+        if a == b:
+            return Channel(name="intra", latency_s=5e-6)
+        return Channel(name="cross", latency_s=55e-6, gbps=100.0, stream_gbps=5.0)
+
+    collab = Collaboration(channel_policy=channels)
+    collab.add_datacenter("dc0", n_dtns=2)
+    collab.add_datacenter("dc1", n_dtns=2)
+    return collab
 
 
 def run_workload(plan_name: str) -> tuple:
@@ -101,7 +107,7 @@ def main(argv=None) -> int:
     ap.add_argument("--plan", default="", help="canned FaultPlan name ('' = none)")
     ap.add_argument("--chrome", default="", metavar="OUT.json",
                     help="also export all buffered spans as Chrome-trace JSON")
-    ap.add_argument("--smoke", action="store_true", help="CI smoke mode")
+    ap.add_argument("--smoke", action="store_true", help="smoke check")
     args = ap.parse_args(argv)
     if args.smoke:
         return smoke()

@@ -76,7 +76,7 @@ def applicable_shapes(cfg: ModelConfig) -> List[ShapeConfig]:
     """The assigned shape cells this architecture actually runs.
 
     ``long_500k`` requires sub-quadratic attention (SSM/hybrid/windowed);
-    pure full-attention archs record a SKIP for it (DESIGN.md §5).
+    pure full-attention archs record a SKIP for it.
     Every assigned arch has a decoder, so decode shapes always apply.
     """
     out = []

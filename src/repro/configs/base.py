@@ -134,16 +134,13 @@ class ModelConfig:
     # numerics
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
-    # performance knobs (hillclimbing surface)
+    # performance knobs
     attn_chunk_q: int = 512
     attn_chunk_kv: int = 1024
     ssm_chunk: int = 256
-    moe_block: int = 0             # MoE dispatch block (0 ⇒ whole sequence)
     scan_layers: bool = True
     remat: str = "unit"            # 'none'|'unit'|'dots'
     remat_loss_chunk: bool = False # recompute logits chunks in backward
-    seq_shard_activations: bool = False  # SP: residual stream S-sharded on 'model'
-    gather_ce: bool = False        # legacy take_along_axis CE (baseline only)
     use_pallas: bool = False       # TPU deployment flag; CPU dry-run uses jnp path
     # capability flags
     sub_quadratic: bool = False    # eligible for long_500k

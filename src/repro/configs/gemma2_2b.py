@@ -6,7 +6,7 @@ attention-logit softcap 50, final-logit softcap 30, sandwich (post-block)
 RMSNorms, GeGLU MLP, head_dim=256, tied embeddings scaled by sqrt(d_model).
 
 long_500k runs: half the layers are sliding-window (ring KV cache of 4096);
-the global layers decode O(S) against their cache — recorded in DESIGN.md.
+the global layers decode O(S) against their cache.
 """
 
 from .base import LayerSpec, ModelConfig

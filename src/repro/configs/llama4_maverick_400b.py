@@ -1,7 +1,7 @@
 """llama4-maverick-400b-a17b — MoE 128e top-1 with shared expert
 [hf:meta-llama/Llama-4-Scout-17B-16E].
 
-Assigned (literal, treated as source of truth — see DESIGN.md §5 note):
+Assigned (literal, treated as source of truth):
 48L, d_model=5120, 40H (GQA kv=8), d_ff=8192 per expert, vocab=202048,
 MoE 128 experts top-1 + an always-on shared expert (Llama4 signature).
 """

@@ -4,7 +4,7 @@ Assigned: 12L, d_model=1024, 16H (GQA kv=16 ⇒ MHA), d_ff=4096, vocab=256206.
 Per the assignment the modality frontend is a STUB: ``input_specs()``
 supplies precomputed speech-frame embeddings (frontend_dim=1024) and the
 backbone is the 12L encoder + 12L decoder transformer with cross-attention.
-Full attention ⇒ long_500k is skipped (DESIGN.md §Arch-applicability);
+Full attention ⇒ long_500k is skipped;
 decode shapes lower the enc-dec serve step (this is NOT encoder-only).
 """
 

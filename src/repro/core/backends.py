@@ -20,7 +20,7 @@ Consistency note (faithful to the paper, with one fix): the paper clears the
 ``sync`` flag of the *parent* directory when an entry changes; for MEU's
 subtree pruning to be sound the invalidation must propagate to *all*
 ancestors, otherwise a synced grandparent would hide a dirty subtree.  We
-propagate to the root and record the deviation in DESIGN.md.
+propagate to the root, a deviation from the paper.
 """
 
 from __future__ import annotations
@@ -228,7 +228,7 @@ class MemoryBackend(StorageBackend):
 
     ``store_gbps`` (0 ⇒ free) models the PFS data-plane bandwidth so that
     benchmark ratios between metadata-bound and data-bound paths resemble a
-    real Lustre deployment rather than RAM speed (DESIGN.md §8).
+    real Lustre deployment rather than RAM speed.
 
     A read into a caller's array copies outside the store lock, so reads of
     many files (or ranges) run in parallel.  What it copies from never

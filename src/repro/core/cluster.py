@@ -6,7 +6,7 @@ a set of DTNs that are (a) clients of the local PFS and (b) hosts of the
 metadata + discovery service shards.  Collaborator machines mount the
 workspace over *all* DTNs of *all* data centers.
 
-In the TPU-fleet adaptation (DESIGN.md §2) a :class:`DataCenter` is a pod and
+In the TPU-fleet adaptation a :class:`DataCenter` is a pod and
 its DTNs are the pod's I/O host group; the cross-DC channel is the DCN.
 """
 
@@ -38,7 +38,7 @@ from .telemetry import Telemetry, assemble_trace, fold_snapshots
 __all__ = ["DTN", "DataCenter", "Collaboration", "ChannelPolicy", "REPLICA_N"]
 
 #: default size of a path's replica set (owner + ring successors) — the N of
-#: "W of N" quorum writes; configs/scispace_testbed.py re-exports this
+#: "W of N" quorum writes
 REPLICA_N = 3
 
 
@@ -65,20 +65,13 @@ class DTN:
         db_dir: Optional[str],
         summary_bits: Optional[int] = None,
         trace_enabled: Optional[bool] = None,
-        trace_buffer_spans: Optional[int] = None,
-        hist_buckets: Optional[int] = None,
     ):
         self.dtn_id = dtn_id
         self.dc_id = dc_id
         self.backend = backend
         #: this node's metrics registry + span buffer; both RPC servers record
         #: server-side spans into it and ``Collaboration.observe()`` folds it
-        self.telemetry = Telemetry(
-            f"dtn{dtn_id}@{dc_id}",
-            trace_enabled=trace_enabled,
-            trace_buffer_spans=trace_buffer_spans,
-            hist_buckets=hist_buckets,
-        )
+        self.telemetry = Telemetry(f"dtn{dtn_id}@{dc_id}", trace_enabled=trace_enabled)
         if db_dir is None:
             meta_db = disc_db = ":memory:"
         else:
@@ -261,11 +254,9 @@ class Collaboration:
         self.quiesce_reason: Optional[str] = None
         #: the last heal-time reconcile's report (see :meth:`reconcile`)
         self.last_reconcile: Optional[Dict[str, object]] = None
-        #: telemetry knob defaults planes/DTNs inherit when built without
-        #: explicit values (set via :meth:`add_datacenter`'s kwargs)
+        #: tracing default planes/DTNs inherit when built without an
+        #: explicit value (set via :meth:`add_datacenter`'s ``trace_enabled``)
         self.trace_enabled: Optional[bool] = None
-        self.trace_buffer_spans: Optional[int] = None
-        self.hist_buckets: Optional[int] = None
         #: fabric-wide telemetry: cluster-scope spans (reconcile) land here
         self.telemetry = Telemetry("cluster")
         #: every span buffer in the fabric (DTNs, planes, the cluster bundle)
@@ -328,15 +319,12 @@ class Collaboration:
         store_lat_s: float = 0.0,
         summary_bits: Optional[int] = None,
         trace_enabled: Optional[bool] = None,
-        trace_buffer_spans: Optional[int] = None,
-        hist_buckets: Optional[int] = None,
     ) -> DataCenter:
         """Add a DC.  ``root=None`` ⇒ in-memory PFS; else a PosixBackend at root.
 
-        The telemetry knobs (``trace_enabled``, ``trace_buffer_spans``,
-        ``hist_buckets`` — see configs/scispace_testbed.py) flow into this
-        DC's DTN servers and become the collaboration-wide defaults planes
-        built afterwards inherit; ``None`` keeps the module defaults.
+        ``trace_enabled`` flows into this DC's DTN servers and becomes the
+        collaboration-wide default planes built afterwards inherit; ``None``
+        keeps the module default.
         """
         with self._lock:
             if dc_id in self.datacenters:
@@ -344,10 +332,6 @@ class Collaboration:
             if trace_enabled is not None:
                 self.trace_enabled = trace_enabled
                 self.telemetry.tracer.enabled = trace_enabled
-            if trace_buffer_spans is not None:
-                self.trace_buffer_spans = trace_buffer_spans
-            if hist_buckets is not None:
-                self.hist_buckets = hist_buckets
             backend: StorageBackend
             backend = (
                 MemoryBackend(dc_id, store_gbps=store_gbps, store_lat_s=store_lat_s)
@@ -359,8 +343,6 @@ class Collaboration:
                 dtn = DTN(
                     len(self.dtns), dc_id, backend, db_dir, summary_bits=summary_bits,
                     trace_enabled=self.trace_enabled,
-                    trace_buffer_spans=self.trace_buffer_spans,
-                    hist_buckets=self.hist_buckets,
                 )
                 dc.dtns.append(dtn)
                 self.dtns.append(dtn)

@@ -37,9 +37,8 @@ movement rides:
   chunk invalidated mid-flight never lands (generation check at insert).
 
 Knobs (``stripe_bytes``, ``data_lanes``, ``chunk_cache_bytes``,
-``readahead``) ride ``configs/scispace_testbed.py`` → ``Workspace``;
-``benchmarks/fig12_datapath.py`` measures the three pieces and
-``scripts/bench_gate.py`` pins their ratios.
+``readahead``) are ``Workspace`` keywords; their defaults are the module
+constants below.
 
 Fault tolerance: when a :class:`~repro.core.rpc.RetryPolicy` is installed
 (``retry=``), an interrupted striped transfer **resumes from the last
@@ -83,7 +82,7 @@ __all__ = [
     "RANGE_ALIGN",
 ]
 
-#: Default stripe chunk size.  Small enough that fig7-sized files (256-512 KB)
+#: Default stripe chunk size.  Small enough that 256-512 KB files
 #: still split across lanes, large enough that per-chunk PFS latency does not
 #: dominate large transfers.
 STRIPE_BYTES = 256 << 10
@@ -1075,7 +1074,7 @@ class DataPath:
         tracer = self.tracer
         if tracer is not None and tracer.enabled:
             # the worker thread has no foreground context, so this span roots
-            # its own trace — overlap with foreground reads (fig12) is visible
+            # its own trace — overlap with foreground reads is visible
             # as concurrent data.prefetch roots in the buffer
             with tracer.span("data.prefetch", path=path, dc=dc_id):
                 self._do_prefetch_inner(dc_id, path, ranges, epoch)

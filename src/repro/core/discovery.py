@@ -604,10 +604,6 @@ class DiscoveryService:
             # each origin, so a replica-local query can be judged fresh/stale
             "applied": self.applied_map(),
             "dtn_id": self.dtn_id,
-            # summary piggyback: every reply refreshes the caller's pruning
-            # cache for free (no extra RPC in the pruning protocol)
-            "summary": self.summary.to_message(),
-            "summary_epoch": self.clock.last_local(),
         }
 
     def get_attrs(self, paths: List[str]) -> List[Dict[str, Any]]:

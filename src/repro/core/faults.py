@@ -346,7 +346,7 @@ class FaultPlan:
 
 
 # ---------------------------------------------------------------------------
-# Canned plans — the seeded fault matrix (scripts/fault_matrix.py, fig13)
+# Canned plans — the seeded fault matrix (scripts/fault_matrix.py)
 # ---------------------------------------------------------------------------
 
 

@@ -60,7 +60,7 @@ __all__ = [
     "LeaseHeldElsewhere",
 ]
 
-#: default write-lease TTL; configs/scispace_testbed.py re-exports this
+#: write-lease TTL
 DEFAULT_LEASE_TTL_S = 5.0
 
 #: renew when less than this fraction of the TTL remains

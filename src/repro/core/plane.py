@@ -43,7 +43,7 @@ from typing import TYPE_CHECKING, Any, Dict, Iterable, List, Optional, Sequence,
 
 from itertools import count
 
-from .leases import DEFAULT_LEASE_TTL_S, Lease, LeaseManager
+from .leases import Lease, LeaseManager
 from .metadata import hash_placement, path_hash
 from .query import ShardSummary
 from .replication import WB_MAX_AGE_S, WB_MAX_PENDING, WriteBackJournal
@@ -306,23 +306,18 @@ class ServicePlane:
         home_dc: str,
         *,
         max_inflight: int = 8,
-        cache_entries: int = 4096,
         write_back: bool = False,
         subscribe: bool = True,
         journal_path: Optional[str] = None,
         wb_max_pending: int = WB_MAX_PENDING,
         wb_max_age_s: float = WB_MAX_AGE_S,
         prefer_replica: bool = False,
-        summary_ttl_s: float = 0.0,
         retry: Optional[RetryPolicy] = None,
         breaker_threshold: int = BREAKER_THRESHOLD,
         breaker_cooldown_s: float = BREAKER_COOLDOWN_S,
         failover: bool = True,
         write_quorum: int = WRITE_QUORUM,
-        lease_ttl_s: float = DEFAULT_LEASE_TTL_S,
         trace_enabled: Optional[bool] = None,
-        trace_buffer_spans: Optional[int] = None,
-        hist_buckets: Optional[int] = None,
     ):
         self.collab = collab
         self.home_dc = home_dc
@@ -330,24 +325,17 @@ class ServicePlane:
         self.prefer_replica = prefer_replica
         self.retry = retry
         #: degrade reads to home-DC replicas when the origin is unreachable
-        #: (off = the fail-fast baseline fig13 measures against)
+        #: (off = fail fast)
         self.failover = failover
-        #: this plane's metrics registry + span buffer + tracer; unset knobs
-        #: inherit the collaboration-wide defaults set by ``add_datacenter``
+        #: this plane's metrics registry + span buffer + tracer; an unset
+        #: ``trace_enabled`` inherits the collaboration-wide default set by
+        #: ``add_datacenter``
         ordinal = next(_holder_seq)
         self.telemetry = Telemetry(
             f"{home_dc}/plane{ordinal}",
             trace_enabled=(
                 trace_enabled if trace_enabled is not None
                 else getattr(collab, "trace_enabled", None)
-            ),
-            trace_buffer_spans=(
-                trace_buffer_spans if trace_buffer_spans is not None
-                else getattr(collab, "trace_buffer_spans", None)
-            ),
-            hist_buckets=(
-                hist_buckets if hist_buckets is not None
-                else getattr(collab, "hist_buckets", None)
             ),
         )
         register = getattr(collab, "register_telemetry", None)
@@ -378,7 +366,7 @@ class ServicePlane:
         self.local_dtns: List[int] = [
             i for i, dtn in enumerate(collab.dtns) if dtn.dc_id == home_dc
         ]
-        self.cache = AttrCache(cache_entries)
+        self.cache = AttrCache()
         #: crash-recoverable buffer of deferred write-back updates; with a
         #: journal_path each deferred update is on disk before the write is
         #: acknowledged, and leftover records from a crashed predecessor are
@@ -421,21 +409,11 @@ class ServicePlane:
             replica_set=lambda prefix: collab.replica_set(prefix),
             stand_ins=self._ring_rest,
             call=lambda idx, method, **kw: self.guarded_call("meta", idx, method, **kw),
-            ttl_s=lease_ttl_s,
             tracer=tracer,
         )
-        #: shard-pruning summary cache: dtn_idx -> (epoch, cached_at, summary).
-        #: The authoritative pruning source is :meth:`note_summaries_bulk` —
-        #: one query-time RPC to a local replica whose filters the
-        #: replication stream keeps current, gated per origin on the
-        #: replica's applied map meeting this client's session bar (the same
-        #: bar replica reads use).  The cache only *reuses* those results
-        #: across queries when ``summary_ttl_s > 0``: a cached filter cannot
-        #: see server-side indexing this client never witnessed (async
-        #: drains, other collaborators), so reuse trades a TTL-bounded
-        #: recall window for the warm RPC — off by default.
-        self.summary_ttl_s = summary_ttl_s
-        self._summaries: Dict[int, Tuple[int, float, ShardSummary]] = {}
+        #: shard-pruning accounting; the filters come from
+        #: :meth:`note_summaries_bulk`, one query-time RPC to a local replica
+        #: whose filters the replication stream keeps current
         self.shard_contacts = 0
         self.shards_pruned = 0
         self.pruned_empty_queries = 0
@@ -826,27 +804,6 @@ class ServicePlane:
         return self.local_dtns[hash_placement(path, len(self.local_dtns))]
 
     # -- shard summaries -------------------------------------------------------
-    def note_summary(self, dtn_idx: int, reply: Any) -> None:
-        """Harvest the piggybacked shard summary from a ``scatter_query`` reply.
-
-        Summaries ride every discovery reply for free (no extra RPC); newer
-        epochs replace older cached copies, and equal epochs refresh the TTL.
-        """
-        if not isinstance(reply, dict):
-            return
-        msg = reply.get("summary")
-        if not isinstance(msg, dict):
-            return
-        epoch = int(reply.get("summary_epoch", 0))
-        cached = self._summaries.get(dtn_idx)
-        if cached is not None and cached[0] > epoch:
-            return
-        try:
-            summary = ShardSummary.from_message(msg)
-        except (KeyError, TypeError, ValueError):
-            return
-        self._summaries[dtn_idx] = (epoch, time.monotonic(), summary)
-
     def note_summaries_bulk(self, reply: Any) -> Dict[int, ShardSummary]:
         """Ingest a ``summaries`` RPC reply (own + replicated peer filters).
 
@@ -856,13 +813,13 @@ class ServicePlane:
         folded in), so it may prune S only when that bound covers every
         epoch this client has witnessed from S — the session-consistency
         bar.  The serving DTN's own filter is judged the same way against
-        its own epoch.  Usable filters also land in the TTL cache.
+        its own epoch.  Nothing is kept across queries: a filter cannot see
+        server-side indexing this client never witnessed.
         """
         if not isinstance(reply, dict):
             return {}
         usable: Dict[int, ShardSummary] = {}
         applied = {int(k): int(v) for k, v in (reply.get("applied") or {}).items()}
-        now = time.monotonic()
         for origin_s, msg in (reply.get("summaries") or {}).items():
             try:
                 origin = int(origin_s)
@@ -876,26 +833,7 @@ class ServicePlane:
             if complete_through < self.seen_epoch(origin):
                 continue  # session bar not met: this filter may miss our writes
             usable[origin] = summary
-            cached = self._summaries.get(origin)
-            if cached is None or cached[0] <= complete_through:
-                self._summaries[origin] = (complete_through, now, summary)
         return usable
-
-    def fresh_summaries(self) -> Dict[int, ShardSummary]:
-        """TTL-cache reuse of previously ingested filters (see ``_summaries``).
-
-        Empty unless ``summary_ttl_s > 0`` — cached filters are blind to
-        server-side indexing this client never witnessed, so cross-query
-        reuse is an explicit opt-in with a TTL-bounded recall window.
-        """
-        if self.summary_ttl_s <= 0:
-            return {}
-        now = time.monotonic()
-        fresh: Dict[int, ShardSummary] = {}
-        for idx, (epoch, cached_at, summary) in self._summaries.items():
-            if epoch >= self.seen_epoch(idx) and now - cached_at <= self.summary_ttl_s:
-                fresh[idx] = summary
-        return fresh
 
     # -- cached metadata surface ----------------------------------------------
     def stat(self, path: str) -> Optional[Dict[str, Any]]:

@@ -75,6 +75,9 @@ __all__ = [
 #: from saturation.
 SUMMARY_BITS = 4096
 
+#: Fan-in of the central merge: shard results fold this many at a time.
+MERGE_GROUP = 8
+
 #: Hash functions per term (k).  With n/m ratios this testbed produces, k=3
 #: keeps the false-positive rate under a few percent.
 SUMMARY_HASHES = 3
@@ -352,33 +355,26 @@ class ScatterGatherPlan:
         """The per-shard batched call list (one ``query_predicate`` per predicate)."""
         return [("query_predicate", kw) for kw in self.predicate_messages()]
 
-    def merge(
-        self,
-        per_shard_results: Sequence[Sequence[Sequence[str]]],
-        *,
-        group_size: int = 8,
-    ) -> List[str]:
+    def merge(self, per_shard_results: Sequence[Sequence[Sequence[str]]]) -> List[str]:
         """Central merge: union over shards per predicate, intersect predicates.
 
         ``per_shard_results[s][p]`` is shard *s*'s path list for predicate *p*.
 
         The per-predicate union runs as a **tree-merge in fixed-size
-        groups**: shard results fold ``group_size`` at a time, level by
+        groups**: shard results fold ``MERGE_GROUP`` at a time, level by
         level, instead of one flat N-way union.  Union is associative so the
         answer is identical; what changes is the merge topology — no single
-        fold ever touches more than ``group_size`` partial sets, which is
+        fold ever touches more than ``MERGE_GROUP`` partial sets, which is
         what lets the planner's merge step distribute (and stay cache-sized)
-        past the testbed's 8 DTNs (benchmarked at 16/32 in fig9d).
+        past 8 DTNs.
         """
-        if group_size < 2:
-            raise QueryError("merge group_size must be >= 2")
         matched: set = set()
         for p_idx in range(len(self.query.predicates)):
             partials: List[set] = [set(sr[p_idx]) for sr in per_shard_results]
             while len(partials) > 1:
                 partials = [
-                    set().union(*partials[i : i + group_size])
-                    for i in range(0, len(partials), group_size)
+                    set().union(*partials[i : i + MERGE_GROUP])
+                    for i in range(0, len(partials), MERGE_GROUP)
                 ]
             union = partials[0] if partials else set()
             matched = union if p_idx == 0 else (matched & union)

@@ -73,8 +73,7 @@ __all__ = [
     "RECONCILE_TIMEOUT_S",
 ]
 
-#: write-back journal flush thresholds (mirroring AsyncIndexer's defaults;
-#: the testbed config re-exports these so benchmarks tune them in one place)
+#: write-back journal flush thresholds (mirroring AsyncIndexer's defaults)
 WB_MAX_PENDING = 64
 WB_MAX_AGE_S = 0.5
 #: replication pump drain thresholds (bounded replica lag)
@@ -82,8 +81,7 @@ PUMP_MAX_PENDING = 64
 PUMP_MAX_AGE_S = 0.05
 #: max raw records one drain coalesces per peer (the compaction window)
 COMPACT_WINDOW = 512
-#: anti-entropy defaults (configs/scispace_testbed.py re-exports these):
-#: namespace subtree a heal-time reconcile sweeps, and how long it may wait
+#: anti-entropy defaults: namespace subtree a heal-time reconcile sweeps, and how long it may wait
 #: for the pumps to quiesce before digest exchange
 RECONCILE_PREFIX = "/"
 RECONCILE_TIMEOUT_S = 10.0

@@ -24,8 +24,8 @@ prefixed containers) but the codec has two implementations:
   record dicts (str keys, scalar values) — the shape replication records and
   attribute rows take — skipping recursive dispatch entirely.
 * :func:`pack_recursive` — the original ``io.BytesIO`` recursive packer,
-  kept as the benchmark baseline (``benchmarks/fig11_wirepath.py``) and the
-  byte-for-byte reference the property tests pin the fast path against.
+  kept as the byte-for-byte reference encoder the tests pin the fast path
+  against.
 
 :func:`unpack` walks a :class:`memoryview` with integer offsets instead of a
 stream object; ``str`` payloads decode straight out of the view and ``bytes``
@@ -375,11 +375,10 @@ def _pack_into_recursive(buf: io.BytesIO, obj: Any) -> None:
 
 
 def pack_recursive(obj: Any) -> bytes:
-    """The original stream-based recursive packer (fig11 baseline).
+    """The original stream-based recursive packer.
 
-    Kept verbatim so benchmarks can measure the fast path against the exact
-    code the seed shipped, and so property tests can pin byte-for-byte
-    equality between the two implementations.
+    The reference encoder: the tests compare :func:`pack` and
+    :func:`pack_flat` against it byte for byte.
     """
     buf = io.BytesIO()
     _pack_into_recursive(buf, obj)
@@ -580,7 +579,7 @@ class RetryPolicy:
 
 @dataclass
 class RpcStats:
-    """Per-client running counters (used by benchmarks + EXPERIMENTS.md).
+    """Per-client running counters.
 
     ``calls`` counts channel round-trips; ``ops`` counts service operations.
     For a single :meth:`RpcClient.call` they advance together; a batched call

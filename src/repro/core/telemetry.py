@@ -27,32 +27,30 @@ cross-cutting layer the rest of the stack reports through:
   :func:`chrome_trace` exports Chrome-trace JSON (``chrome://tracing`` /
   Perfetto) for real tooling.
 
-Paper figures -> the metrics that explain them
-----------------------------------------------
+Layers -> the metrics that explain them
+---------------------------------------
 
-==========  ================================================================
-figure      telemetry that explains the result
-==========  ================================================================
-fig7        ``datapath.transfer_seconds`` histogram vs block size;
-            ``rpc.wire_seconds`` (per-op channel cost the LW amortizes)
-fig9d       ``rpc.calls`` vs ``rpc.ops`` (batching ratio the metadata plane
-            exists to improve); ``rpc.call_seconds`` p50/p99
-fig10       ``replication.records_shipped`` / ``plane.replica_hits`` (reads
-            served at the home DC instead of crossing the WAN)
-fig11       ``rpc.pack_seconds`` (codec fast path),
-            ``replication.records_compacted`` (path-compacted shipping),
-            ``plane.shards_pruned`` (summary-pruned scatter)
-fig12       ``datapath.cache.hit_bytes`` vs ``miss_bytes``;
-            ``datapath.prefetch_*``; read-ahead *overlap* is visible as
-            concurrent ``data.prefetch`` root spans in the trace buffer
-fig13       ``rpc.retries`` / ``rpc.deduped`` (exactly-once under chaos),
-            ``faults.*`` (injected drops/dups), ``plane.degraded_reads``
-fig14       ``lease.granted`` / ``lease.fenced`` (fence floor refusals),
-            ``plane.degraded_writes`` / ``plane.quorum_acks``; the full
-            story of one degraded write is its assembled trace tree
-fig15       the overhead of *this* layer: tracing-on vs tracing-off on the
-            fig9d pipelined-write burst, gated <= 5%
-==========  ================================================================
+===========  ===============================================================
+layer        telemetry that explains its cost
+===========  ===============================================================
+data path    ``datapath.transfer_seconds`` histogram vs block size;
+             ``rpc.wire_seconds`` (per-op channel cost the LW amortizes);
+             ``datapath.cache.hit_bytes`` vs ``miss_bytes``;
+             ``datapath.prefetch_*``; read-ahead *overlap* is visible as
+             concurrent ``data.prefetch`` root spans in the trace buffer
+metadata     ``rpc.calls`` vs ``rpc.ops`` (batching ratio the metadata plane
+             exists to improve); ``rpc.call_seconds`` p50/p99
+replication  ``replication.records_shipped`` / ``plane.replica_hits`` (reads
+             served at the home DC instead of crossing the WAN)
+wire path    ``rpc.pack_seconds`` (codec fast path),
+             ``replication.records_compacted`` (path-compacted shipping),
+             ``plane.shards_pruned`` (summary-pruned scatter)
+faults       ``rpc.retries`` / ``rpc.deduped`` (exactly-once under chaos),
+             ``faults.*`` (injected drops/dups), ``plane.degraded_reads``
+partitions   ``lease.granted`` / ``lease.fenced`` (fence floor refusals),
+             ``plane.degraded_writes`` / ``plane.quorum_acks``; the full
+             story of one degraded write is its assembled trace tree
+===========  ===============================================================
 
 Design notes: spans are ``__slots__`` objects appended to a ``deque``, and
 IDs are integers — ``(site_number << 40) | counter``, so they are unique
@@ -94,8 +92,8 @@ __all__ = [
     "chrome_trace",
 ]
 
-#: defaults for the ``trace_enabled`` / ``trace_buffer_spans`` /
-#: ``hist_buckets`` knobs (see configs/scispace_testbed.py)
+#: whether tracing is on when ``trace_enabled`` is not given; the depth of
+#: every node's span buffer; the log2 buckets of every histogram
 TRACE_ENABLED = True
 TRACE_BUFFER_SPANS = 4096
 HIST_BUCKETS = 48
@@ -170,10 +168,10 @@ class Histogram:
 
     __slots__ = ("name", "scale", "n", "counts", "count", "total", "vmin", "vmax", "_lock")
 
-    def __init__(self, name: str, *, scale: float = 1e-7, buckets: int = HIST_BUCKETS):
+    def __init__(self, name: str, *, scale: float = 1e-7):
         self.name = name
         self.scale = float(scale)
-        self.n = max(4, int(buckets))
+        self.n = HIST_BUCKETS
         self.counts = [0] * self.n
         self.count = 0
         self.total = 0.0
@@ -284,9 +282,8 @@ class MetricsRegistry:
     registry without double-counting.
     """
 
-    def __init__(self, site: str = "", *, hist_buckets: int = HIST_BUCKETS):
+    def __init__(self, site: str = ""):
         self.site = site
-        self.hist_buckets = hist_buckets
         self._metrics: Dict[str, Any] = {}
         self._collectors: List[Tuple[str, Callable[[], Dict[str, Any]]]] = []
         self._lock = threading.Lock()
@@ -308,7 +305,7 @@ class MetricsRegistry:
         return self._get(name, Gauge)
 
     def histogram(self, name: str, *, scale: float = 1e-7) -> Histogram:
-        return self._get(name, Histogram, scale=scale, buckets=self.hist_buckets)
+        return self._get(name, Histogram, scale=scale)
 
     def add_collector(self, prefix: str, fn: Callable[[], Dict[str, Any]]) -> None:
         with self._lock:
@@ -614,17 +611,11 @@ class Telemetry:
         site: str,
         *,
         trace_enabled: Optional[bool] = None,
-        trace_buffer_spans: Optional[int] = None,
-        hist_buckets: Optional[int] = None,
         region: Optional[Callable[[str], Any]] = None,
     ):
         self.site = site
-        self.registry = MetricsRegistry(
-            site, hist_buckets=HIST_BUCKETS if hist_buckets is None else hist_buckets
-        )
-        self.spans = SpanBuffer(
-            TRACE_BUFFER_SPANS if trace_buffer_spans is None else trace_buffer_spans
-        )
+        self.registry = MetricsRegistry(site)
+        self.spans = SpanBuffer()
         self.tracer = Tracer(
             site, self.spans, enabled=TRACE_ENABLED if trace_enabled is None else trace_enabled,
             region=region,

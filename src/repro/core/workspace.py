@@ -13,7 +13,7 @@ mkdir) over every data center in the collaboration:
   default (``pipeline=True``) the four metadata ops ride **one pipelined
   batch** to the owner DTN — one channel round-trip, four serializations —
   via the :class:`~repro.core.plane.ServicePlane`; ``pipeline=False`` keeps
-  the paper's serial per-op sequence for comparison (benchmarks/fig9d).
+  the paper's serial per-op sequence for comparison.
   With ``write_back=True`` the final flush op is buffered in the plane's
   write-back attribute cache and committed later as one batched ``update``
   per DTN (:meth:`flush`), trading metadata visibility lag for another
@@ -144,14 +144,11 @@ class Workspace:
         attr_filter: Optional[List[str]] = None,
         pipeline: bool = True,
         write_back: bool = False,
-        max_inflight: int = 8,
-        cache_entries: int = 4096,
         journal_path: Optional[str] = None,
         wb_max_pending: Optional[int] = None,
         wb_max_age_s: Optional[float] = None,
         prefer_replica: bool = False,
         prune_queries: bool = True,
-        summary_ttl_s: Optional[float] = None,
         stripe_bytes: int = STRIPE_BYTES,
         data_lanes: int = DATA_LANES,
         chunk_cache_bytes: int = CHUNK_CACHE_BYTES,
@@ -161,16 +158,12 @@ class Workspace:
         breaker_cooldown_s: Optional[float] = None,
         failover: bool = True,
         write_quorum: Optional[int] = None,
-        lease_ttl_s: Optional[float] = None,
         trace_enabled: Optional[bool] = None,
-        trace_buffer_spans: Optional[int] = None,
-        hist_buckets: Optional[int] = None,
     ):
         """``stripe_bytes`` / ``data_lanes`` shape the striped multi-lane
         transfer (0 / 1 restore the single-shot path); ``chunk_cache_bytes``
         sizes the consistent remote-read chunk cache (0 disables it);
-        ``readahead`` toggles asynchronous scidata payload prefetch.  All
-        four ride :class:`~repro.configs.scispace_testbed.TestbedConfig`.
+        ``readahead`` toggles asynchronous scidata payload prefetch.
 
         ``retry`` (a :class:`~repro.core.rpc.RetryPolicy`) makes every RPC
         and striped transfer retry unavailability with backoff + idempotency
@@ -194,8 +187,6 @@ class Workspace:
         # and (write_back) the crash-recoverable journal with count/age
         # flush thresholds.
         plane_kwargs: Dict[str, Any] = dict(
-            max_inflight=max_inflight,
-            cache_entries=cache_entries,
             write_back=write_back,
             journal_path=journal_path,
             prefer_replica=prefer_replica,
@@ -206,22 +197,14 @@ class Workspace:
             plane_kwargs["wb_max_pending"] = wb_max_pending
         if wb_max_age_s is not None:
             plane_kwargs["wb_max_age_s"] = wb_max_age_s
-        if summary_ttl_s is not None:
-            plane_kwargs["summary_ttl_s"] = summary_ttl_s
         if breaker_threshold is not None:
             plane_kwargs["breaker_threshold"] = breaker_threshold
         if breaker_cooldown_s is not None:
             plane_kwargs["breaker_cooldown_s"] = breaker_cooldown_s
         if write_quorum is not None:
             plane_kwargs["write_quorum"] = write_quorum
-        if lease_ttl_s is not None:
-            plane_kwargs["lease_ttl_s"] = lease_ttl_s
         if trace_enabled is not None:
             plane_kwargs["trace_enabled"] = trace_enabled
-        if trace_buffer_spans is not None:
-            plane_kwargs["trace_buffer_spans"] = trace_buffer_spans
-        if hist_buckets is not None:
-            plane_kwargs["hist_buckets"] = hist_buckets
         self.plane = ServicePlane(collab, home_dc, **plane_kwargs)
         # The data plane: every cross-DC byte moves through it (striped
         # lanes + chunk cache + read-ahead); home-DC bytes stay direct.
@@ -732,14 +715,13 @@ class Workspace:
         the result may miss those writes and the query falls back to the
         full fan-out.
 
-        The fan-out itself is **shard-pruned**: each discovery reply
-        piggybacks the shard's bloom summary (and the replication log ships
-        every shard's summary to every replica), so the plane accumulates a
-        filter per shard.  Before fanning out, the plan drops every
-        (shard, predicate) pair the summaries prove cannot match — bloom
-        bits are one-sided, so a skip is never wrong — and a predicate with
-        zero candidate shards short-circuits the whole query to ``[]`` with
-        zero RPCs.  Missing or stale summaries degrade to the plain full
+        The fan-out itself is **shard-pruned**: the replication log ships
+        every shard's bloom summary to every replica, and one intra-DC RPC
+        fetches them from a home-DC replica.  Before fanning out, the plan
+        drops every (shard, predicate) pair the summaries prove cannot
+        match — bloom bits are one-sided, so a skip is never wrong — and a
+        predicate with zero candidate shards short-circuits the whole query
+        to ``[]`` with zero RPCs.  Missing or stale summaries degrade to the plain full
         pushdown, never to a wrong answer.
         """
         plan = plan_query(query)
@@ -758,7 +740,6 @@ class Workspace:
                     for i, bar in self.plane.seen_epochs().items()
                     if bar > 0 and i != nearest
                 )
-                self.plane.note_summary(nearest, reply)
                 if fresh:
                     paths = set(plan.merge([reply["matches"]]))
                     return [
@@ -768,24 +749,15 @@ class Workspace:
                     ]
                 self.plane.replica_stale_fallbacks += 1
         n_shards = self.plane.n_dtns()
-        summaries = (
-            self.plane.fresh_summaries() if self.prune_queries else {}
-        )  # TTL-cache reuse (opt-in)
-        if (
-            self.prune_queries
-            and len(summaries) < n_shards
-            and self.collab.replication_enabled
-            and self.plane.local_dtns
-        ):
+        summaries: Dict[int, Any] = {}
+        if self.prune_queries and self.collab.replication_enabled and self.plane.local_dtns:
             # one intra-DC RPC fetches every shard's filter from a home-DC
             # replica (the replication log ships + maintains them there);
             # each filter is session-gated on the replica's applied map
             try:
-                warmed = self.plane.note_summaries_bulk(
+                summaries = self.plane.note_summaries_bulk(
                     self.plane.guarded_call("sds", self.plane.local_dtns[0], "summaries")
                 )
-                warmed.update(summaries)
-                summaries = warmed
             except RpcUnavailable:
                 pass  # no pruning help available; full pushdown still works
         decision = plan.prune(summaries, n_shards)
@@ -814,7 +786,6 @@ class Workspace:
         for i, reply in enumerate(per_dtn):
             if reply is None:
                 continue
-            self.plane.note_summary(i, reply)
             full = [[] for _ in all_preds]
             for k, j in enumerate(decision.send[i]):
                 full[j] = reply["matches"][k]

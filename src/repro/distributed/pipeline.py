@@ -15,7 +15,7 @@ Mechanics (pure ``shard_map`` + ``lax.ppermute``):
 
 This module is deliberately self-contained (own dry-run test) rather than
 threaded through every model: the assigned meshes keep it disabled, and the
-cost model in EXPERIMENTS.md §Roofline covers the non-PP configuration.
+roofline cost model covers the non-PP configuration.
 """
 
 from __future__ import annotations

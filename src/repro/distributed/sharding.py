@@ -1,6 +1,6 @@
 """Sharding rules: parameter/batch/cache PartitionSpecs for every arch × mesh.
 
-Strategy (DESIGN.md §4):
+Strategy:
 
 - **DP** — the batch dimension is sharded over all data-like axes
   (``('pod', 'data')`` on the multi-pod mesh, ``('data',)`` single-pod).
@@ -141,7 +141,7 @@ def param_spec_for_path(
     dimension over ``data`` (ZeRO/FSDP-style fully-sharded state): GSPMD
     all-gathers weights per layer in the forward, and optimizer state stays
     1/|data| per chip — what lets the 52B/773B archs fit the 16 GB/chip
-    budget (EXPERIMENTS.md §Dry-run records per-cell bytes).
+    budget (``repro.launch.dryrun`` reports per-cell bytes).
     """
     for pattern, prefs in _RULES:
         if re.search(pattern, path):
@@ -177,9 +177,8 @@ def _apply_prefs(
             break  # first satisfiable preference wins; do not over-shard
     if fsdp and "data" in mesh.axis_names and "data" not in used_axes:
         # largest unsharded non-stack dim that divides; scan axis excluded.
-        # (Preferring output dims instead was tried and REFUTED in §Perf
-        # cell B: it trades the input-dim psums for output-activation
-        # gathers at +5% wire.  FSDP's in-dim psums are why it is enabled
+        # (Preferring output dims instead was tried and refuted: it trades
+        # the input-dim psums for output-activation gathers at +5% wire.  FSDP's in-dim psums are why it is enabled
         # only where capacity requires it — see launch.dryrun.FSDP_ARCHS.)
         dp = mesh.shape["data"]
         cands = [

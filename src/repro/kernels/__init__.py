@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the framework's compute hot-spots.
 
 The paper (SCISPACE) has no kernel-level contribution — these kernels exist
-because the *framework's* model substrate needs them on TPU (DESIGN.md §6):
+because the *framework's* model substrate needs them on TPU:
 
 - :mod:`.flash_attention` — fused online-softmax attention (all attn archs)
 - :mod:`.rwkv6_scan`      — chunked WKV recurrence (RWKV-6 "Finch")

@@ -1,6 +1,6 @@
 """Chunked Mamba-1 selective scan for TPU (``pl.pallas_call`` + BlockSpecs).
 
-TPU adaptation (DESIGN.md §6): the CUDA kernel assigns one thread per channel
+TPU adaptation: the CUDA kernel assigns one thread per channel
 and scans time sequentially in registers.  On TPU the equivalent is a grid
 over ``(batch, d_inner blocks, time chunks)`` with the per-channel state
 h ∈ ℝ^{ds×bd} held in VMEM scratch (channels on the 128-wide lane axis);

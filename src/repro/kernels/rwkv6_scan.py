@@ -1,6 +1,6 @@
 """Chunked RWKV-6 WKV recurrence for TPU (``pl.pallas_call`` + BlockSpecs).
 
-TPU adaptation of the CUDA wkv6 kernel (DESIGN.md §6): the GPU kernel runs
+TPU adaptation of the CUDA wkv6 kernel: the GPU kernel runs
 one thread per channel and serializes over time; a mechanical port would
 leave the MXU idle.  Instead the sequence is processed in **chunks**: the
 O(C×C) state crosses chunk boundaries (the only sequential dependence) while

@@ -11,12 +11,12 @@ Per cell this script:
   3. ``jit(...).lower(...).compile()`` — sharding mismatches, unsupported
      collectives or compile-time OOM are failures,
   4. prints ``memory_analysis()`` (does it fit 16 GB/chip?) and
-     ``cost_analysis()`` (FLOPs / bytes for §Roofline),
+     ``cost_analysis()`` (FLOPs / bytes for the roofline),
   5. emits the 3-term roofline row (single-pod mesh only, per the spec).
 
 Usage:
     python -m repro.launch.dryrun --arch gemma2-2b --shape train_4k
-    python -m repro.launch.dryrun --all --multi-pod --out results/dryrun.json
+    python -m repro.launch.dryrun --all --multi-pod --out dryrun.json
 """
 
 import os
@@ -57,8 +57,8 @@ TRAIN_MICROBATCHES = 4
 #: Archs whose optimizer state needs FSDP sharding to fit 16 GB/chip.
 #: fp32 AdamW state = 12 bytes/param over the 16-way model axis: 7B ⇒ 5.3 GB
 #: (fits), 15B ⇒ 11.3 GB (fits, tight), 52B/773B ⇒ 39/580 GB (need FSDP).
-#: FSDP costs data-axis collectives on the contracted weight dims (§Perf
-#: cell-A evidence), so it is enabled only where capacity forces it.
+#: FSDP costs data-axis collectives on the contracted weight dims, so it is
+#: enabled only where capacity forces it.
 FSDP_ARCHS = {
     "jamba-v0.1-52b",
     "llama4-maverick-400b-a17b",
@@ -114,7 +114,7 @@ def run_cell(
     overrides: Optional[Dict[str, Any]] = None,
     verbose: bool = True,
 ) -> Dict[str, Any]:
-    """Lower+compile one cell; returns the record for EXPERIMENTS.md."""
+    """Lower+compile one cell; returns its record."""
     cfg = get_config(arch)
     if overrides:
         cfg = cfg.replace(**overrides)
@@ -122,7 +122,7 @@ def run_cell(
     if shape.name == "long_500k" and not cfg.sub_quadratic:
         return {
             "arch": arch, "shape": shape_name, "mesh": "multi" if multi_pod else "single",
-            "status": "SKIP", "reason": "pure full-attention arch (DESIGN.md §5)",
+            "status": "SKIP", "reason": "pure full-attention arch",
         }
     if fsdp is None:
         fsdp = arch in FSDP_ARCHS
@@ -193,7 +193,7 @@ def run_cell(
     mf = model_flops(n_params, n_active, tokens, kind=("train" if kind == "train" else "serve"))
     from repro.roofline.analytic import cell_bytes, cell_flops
 
-    af = cell_flops(cfg, shape, moe_block=getattr(cfg, "moe_block", 0))
+    af = cell_flops(cfg, shape)
     ab = cell_bytes(
         cfg, shape, n_params=n_params, n_devices=n_devices,
         fsdp=fsdp, tp=mesh.shape["model"],

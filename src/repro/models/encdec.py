@@ -61,7 +61,7 @@ def enc_len_for(cfg, seq_len: int) -> int:
     """Encoder (frame) length for a given decoder length.
 
     The audio frontend downsamples aggressively; we model the backbone's
-    encoder length as seq_len // 4 (recorded in DESIGN.md assumptions),
+    encoder length as seq_len // 4 (an assumption),
     clamped to at least one attention chunk.
     """
     return max(seq_len // 4, min(seq_len, 16))

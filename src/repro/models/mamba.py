@@ -1,6 +1,6 @@
 """Mamba-1 selective SSM block (Jamba's sequence mixer).
 
-TPU adaptation (DESIGN.md §6): the CUDA selective-scan kernel is a sequential
+TPU adaptation: the CUDA selective-scan kernel is a sequential
 scan parallelized across channels.  Here the train/prefill path uses a
 *chunked associative scan*: ``lax.scan`` over sequence chunks (bounding live
 memory) with a numerically-stable ``lax.associative_scan`` inside each chunk

@@ -17,8 +17,7 @@ A layer may hold only some of the experts (``MoESpec.n_held`` from
 ``first_held``): the expert-parallel share of one chip.
 
 The dispatch einsum costs 2·B·S·(E·C)·D FLOPs (E·C ≈ S·top_k·cf), which the
-roofline's MODEL_FLOPS/HLO_FLOPs ratio makes visible as routing overhead —
-a primary hillclimbing surface (§Perf).
+roofline's MODEL_FLOPS/HLO_FLOPs ratio makes visible as routing overhead.
 """
 
 from __future__ import annotations
@@ -104,31 +103,16 @@ def moe_layer(
     cfg,
     *,
     dtype,
-    capacity_factor: float = 1.25,
 ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
     """Returns (output [B,S,D], load-balance aux loss scalar, stats).
 
     ``stats`` holds ``load``, the choices at each of the router's experts
     [E], and three counters: ``routed``, the choices bound for the experts
     held here; ``kept``, those within capacity; ``slots``, the expert slots
-    computed.
-
-    ``cfg.moe_block`` > 0 dispatches in sequence blocks: the one-hot
-    dispatch/combine einsums cost 2·(E·C)·D per token with E·C ≈ S_blk·K·cf,
-    so blocking cuts dispatch FLOPs and the [B,S,E,C] tensor by S/S_blk —
-    the §Perf optimization for MoE archs.  Routing stays per-token
-    identical; only capacity accounting becomes per-block (tighter, which
-    matches production Switch/GShard implementations).
+    computed.  Capacity is counted over each row's whole sequence, at
+    ``cfg.moe.capacity_factor``.
     """
-    blk = getattr(cfg, "moe_block", 0)
-    cf = getattr(cfg.moe, "capacity_factor", capacity_factor)
-    B, S, D = x.shape
-    if blk and blk < S and S % blk == 0:
-        nb = S // blk
-        xb = x.reshape(B * nb, blk, D)
-        out, aux, stats = _moe_dispatch(p, xb, cfg, dtype=dtype, capacity_factor=cf)
-        return out.reshape(B, S, D), aux, stats
-    return _moe_dispatch(p, x, cfg, dtype=dtype, capacity_factor=cf)
+    return _moe_dispatch(p, x, cfg, dtype=dtype, capacity_factor=cfg.moe.capacity_factor)
 
 
 def _moe_dispatch(

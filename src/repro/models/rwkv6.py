@@ -12,7 +12,7 @@ the chunk math is stable at any length; chunk size bounds the [T,T,C] score
 tensor.  The Pallas kernel (:mod:`repro.kernels.rwkv6_scan`) mirrors this
 chunking with the state carried in VMEM.
 
-Simplification vs the released model (recorded in DESIGN.md): token-shift
+Simplification vs the released model: token-shift
 lerps use learned per-channel μ rather than the data-dependent ddlerp LoRA;
 decay keeps its LoRA.  This preserves the paper's architectural signature
 (data-dependent decay, outer-product state) at the assigned dimensions.

@@ -189,21 +189,6 @@ def _ffn_apply(lp, spec, h, cfg, dt):
     raise ValueError(spec.ffn)
 
 
-def _sp_constrain(x, cfg):
-    """Sequence-parallel residual stream (§Perf: collective term).
-
-    Constraining the residual's sequence dim onto ``model`` turns each
-    block's output all-reduce into reduce-scatter (+ a deferred all-gather
-    at the next projection) — half the wire bytes, and the norms between
-    blocks compute on 1/TP of the tokens.  No-op unless enabled.
-    """
-    if not getattr(cfg, "seq_shard_activations", False):
-        return x
-    from jax.sharding import PartitionSpec as P
-
-    return jax.lax.with_sharding_constraint(x, P(None, "model", None))
-
-
 #: the MoE counters summed over layers: choices bound for held experts,
 #: choices kept within capacity, expert slots computed
 _MOE_COUNTERS = ("routed", "kept", "slots")
@@ -221,12 +206,12 @@ def _layer_train(lp, spec, x, positions, cfg, dt):
     mix = _mixer_apply(lp, spec, h, positions, cfg, dt)
     if cfg.post_block_norm:
         mix = norm(lp["norm1_post"], mix, kind=cfg.norm)
-    x = _sp_constrain(x + mix, cfg)
+    x = x + mix
     h = norm(lp["norm2"], x, kind=cfg.norm)
     f, aux, stats = _ffn_apply(lp, spec, h, cfg, dt)
     if cfg.post_block_norm:
         f = norm(lp["norm2_post"], f, kind=cfg.norm)
-    return _sp_constrain(x + f, cfg), aux, stats
+    return x + f, aux, stats
 
 
 def _add_counters(counters, stats):
@@ -344,21 +329,18 @@ def train_loss(
         xx, tt = inp  # [B, c, D], [B, c]
         logits = _logits(params, xx, cfg).astype(jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
-        if getattr(cfg, "gather_ce", False):  # legacy baseline formulation
-            picked = jnp.take_along_axis(logits, tt[..., None], axis=-1)[..., 0]
-        else:
-            # one-hot contraction instead of take_along_axis: the gather over
-            # a vocab-sharded logits tensor forces an all-gather of the full
-            # [B, c, V] block per chunk; the contraction stays vocab-local
-            # and reduces with a tiny [B, c] psum (§Perf: sharded-vocab CE).
-            onehot = jax.nn.one_hot(tt, logits.shape[-1], dtype=logits.dtype)
-            picked = jnp.sum(logits * onehot, axis=-1)
+        # one-hot contraction instead of take_along_axis: the gather over
+        # a vocab-sharded logits tensor forces an all-gather of the full
+        # [B, c, V] block per chunk; the contraction stays vocab-local
+        # and reduces with a tiny [B, c] psum.
+        onehot = jax.nn.one_hot(tt, logits.shape[-1], dtype=logits.dtype)
+        picked = jnp.sum(logits * onehot, axis=-1)
         nll = lse - picked
         return carry + nll.sum(), None
 
     if getattr(cfg, "remat_loss_chunk", False):
         # recompute the [B, c, V] logits in the backward pass instead of
-        # saving one residual per chunk (§Perf: memory term)
+        # saving one residual per chunk
         chunk_loss = jax.checkpoint(chunk_loss, prevent_cse=False)
 
     from repro.distributed.vma import vary

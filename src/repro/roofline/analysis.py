@@ -24,7 +24,7 @@ this is what the hierarchical/compressed cross-pod modes move.
 
 MODEL_FLOPS (6·N·D dense / 6·N_active·D MoE) anchors the useful-compute
 ratio; HLO_FLOPs below cost_analysis's own numbers signals remat recompute
-or dispatch overhead — the §Perf hillclimbing signal.
+or dispatch overhead.
 """
 
 from __future__ import annotations

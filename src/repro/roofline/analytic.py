@@ -45,17 +45,16 @@ def _mlp_flops(cfg, d_ff: int) -> float:
     return n_mat * 2 * cfg.d_model * d_ff
 
 
-def _moe_layer_flops(cfg, S_block: int, *, capacity_factor: float = 1.25) -> float:
+def _moe_layer_flops(cfg, S: int, *, capacity_factor: float = 1.25) -> float:
     """MoE FFN per token: router + dense one-hot dispatch/combine + experts.
 
-    The dispatch einsums cost 2·(E·C)·D per token with E·C ≈ S_block·K·cf —
-    linear in the dispatch block size.  S_block = full S for the baseline
-    implementation; the blocked-dispatch optimization (§Perf) shrinks it.
+    The dispatch einsums cost 2·(E·C)·D per token with E·C ≈ S·K·cf —
+    linear in the sequence length.
     """
     spec = cfg.moe
     D = cfg.d_model
     E, K, F = spec.n_experts, spec.top_k, spec.d_ff
-    EC = S_block * K * capacity_factor
+    EC = S * K * capacity_factor
     router = 2 * D * E
     dispatch = 2 * EC * D * 2  # dispatch + combine
     n_mat = 3 if cfg.activation == "swiglu" else 2
@@ -91,7 +90,7 @@ def _rwkv_cmix_flops(cfg) -> float:
     return 2 * cfg.d_model * cfg.d_ff * 2 + 2 * cfg.d_model * cfg.d_model
 
 
-def flops_breakdown(cfg, shape, *, moe_block: int = 0) -> Dict[str, float]:
+def flops_breakdown(cfg, shape) -> Dict[str, float]:
     """Per-token forward FLOPs by component (whole stack)."""
     S = shape.seq_len
     decode = shape.kind == "decode"
@@ -117,7 +116,7 @@ def flops_breakdown(cfg, shape, *, moe_block: int = 0) -> Dict[str, float]:
         if spec.ffn == "dense":
             out["ffn"] += n * _mlp_flops(cfg, cfg.d_ff)
         elif spec.ffn == "moe":
-            out["ffn"] += n * _moe_layer_flops(cfg, moe_block or S)
+            out["ffn"] += n * _moe_layer_flops(cfg, S)
         elif spec.ffn == "rwkv_cmix":
             out["ffn"] += n * _rwkv_cmix_flops(cfg)
     if cfg.is_encdec:
@@ -136,9 +135,9 @@ def flops_breakdown(cfg, shape, *, moe_block: int = 0) -> Dict[str, float]:
     return out
 
 
-def cell_flops(cfg, shape, *, moe_block: int = 0) -> float:
+def cell_flops(cfg, shape) -> float:
     """Total fleet FLOPs for one step of this cell."""
-    per_tok = sum(flops_breakdown(cfg, shape, moe_block=moe_block).values())
+    per_tok = sum(flops_breakdown(cfg, shape).values())
     if shape.kind == "train":
         tokens = shape.global_batch * shape.seq_len
         return 3.0 * per_tok * tokens  # fwd + bwd(2×)

@@ -1,6 +1,6 @@
 """Train-step builder: jit + GSPMD baseline, manual-pod hierarchical variants.
 
-Three cross-pod modes (DESIGN.md §4):
+Three cross-pod modes:
 
 - ``auto`` (baseline) — one ``jax.jit`` over the whole mesh; GSPMD inserts
   the gradient reduction (fused f32 all-reduce over pod×data).  This is the

@@ -1,6 +1,6 @@
 """Trainer: the fault-tolerant training loop.
 
-Responsibilities (large-scale runnability, DESIGN.md §4):
+Responsibilities (large-scale runnability):
 
 - drive ``build_train_step`` over the sharded data pipeline;
 - **checkpoint/restart** through SCISPACE (local-write + MEU by default):

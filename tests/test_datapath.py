@@ -14,10 +14,15 @@ import numpy as np
 import pytest
 
 from repro.core import ChunkCache, Collaboration, DataPath, NativeSession, Workspace
-from repro.core.datapath import merge_ranges, subtract_ranges
+from repro.core.datapath import (
+    CHUNK_CACHE_BYTES,
+    DATA_LANES,
+    STRIPE_BYTES,
+    merge_ranges,
+    subtract_ranges,
+)
 from repro.core.metadata import path_hash
 from repro.core.rpc import Channel, RpcError
-from repro.configs.scispace_testbed import TESTBED
 
 
 def _remote_path(collab, home_dc: str, tag: str) -> str:
@@ -447,19 +452,22 @@ def test_local_reads_bypass_datapath(collab):
 
 # -- knob plumbing ------------------------------------------------------------
 def test_knobs_ride_config_to_workspace(collab):
-    assert TESTBED.stripe_bytes > 0
-    assert TESTBED.data_lanes >= 1
-    assert TESTBED.chunk_cache_bytes > 0
-    assert TESTBED.readahead is True
-    ws = Workspace(
-        collab, "alice", "dc0",
-        stripe_bytes=TESTBED.stripe_bytes,
-        data_lanes=TESTBED.data_lanes,
-        chunk_cache_bytes=TESTBED.chunk_cache_bytes,
-        readahead=TESTBED.readahead,
-    )
-    assert ws.datapath.stripe_bytes == TESTBED.stripe_bytes
-    assert ws.datapath.data_lanes == TESTBED.data_lanes
-    assert ws.datapath.cache.max_bytes == TESTBED.chunk_cache_bytes
+    assert STRIPE_BYTES > 0 and DATA_LANES >= 1 and CHUNK_CACHE_BYTES > 0
+    ws = Workspace(collab, "alice", "dc0")
+    assert ws.datapath.stripe_bytes == STRIPE_BYTES
+    assert ws.datapath.data_lanes == DATA_LANES
+    assert ws.datapath.cache.max_bytes == CHUNK_CACHE_BYTES
     assert ws.datapath.readahead is True
+    ws.close()
+    ws = Workspace(
+        collab, "bob", "dc0",
+        stripe_bytes=STRIPE_BYTES // 2,
+        data_lanes=DATA_LANES + 1,
+        chunk_cache_bytes=CHUNK_CACHE_BYTES // 2,
+        readahead=False,
+    )
+    assert ws.datapath.stripe_bytes == STRIPE_BYTES // 2
+    assert ws.datapath.data_lanes == DATA_LANES + 1
+    assert ws.datapath.cache.max_bytes == CHUNK_CACHE_BYTES // 2
+    assert ws.datapath.readahead is False
     ws.close()

@@ -489,8 +489,8 @@ def test_heal_cancels_timed_restarts_and_resets_cadence():
     from one that never had the plan: pending crash_dtn_at_call timed
     restarts cancelled (the victim restarted NOW, not 30 s later), partitions
     lifted, and all cadence state (rule matched/fired, crash triggers) reset
-    — while the lifetime observability totals survive (fig13/fault_matrix
-    read plan.stats() after the heal)."""
+    — while the lifetime observability totals survive (scripts/fault_matrix.py
+    reads plan.stats() after the heal)."""
     c = _replicated()
     try:
         victim = next(d.dtn_id for d in c.dtns if d.dc_id == "dc1")

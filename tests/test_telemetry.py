@@ -6,8 +6,8 @@ The contracts under test:
   registries: counters sum, histograms merge, percentiles stay monotone;
 - ``Workspace.telemetry()`` is ONE scrape covering every documented counter
   family — rpc / datapath / replication / lease / plane / faults — and the
-  legacy ``*_stats()`` shims read the same numbers (the fig13/fig14 stats
-  drift hazard: two hand-merged views of the same counters disagreeing);
+  legacy ``*_stats()`` shims read the same numbers (the stats drift
+  hazard: two hand-merged views of the same counters disagreeing);
 - every Workspace entry point roots a trace; the RPC envelope propagates it
   so client spans, server apply spans, and striped-lane spans assemble into
   one parent-linked cross-DC tree (``Collaboration.collect_trace``);
@@ -167,7 +167,7 @@ def test_workspace_telemetry_covers_documented_counters_under_faults():
 
 
 def test_stats_shims_read_the_registry_not_a_second_ledger():
-    """fig13/fig14's fault-matrix keys come out of the same fold the scrape
+    """The fault-matrix keys come out of the same fold the scrape
     uses — the drift hazard the registry removes (satellite a)."""
     c = _replicated()
     try:

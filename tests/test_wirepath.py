@@ -352,21 +352,23 @@ def test_pump_accepts_wire_path_knobs():
         collab.close()
 
 
-def test_testbed_config_carries_wire_path_knobs():
-    from repro.configs.scispace_testbed import TestbedConfig
-
-    cfg = TestbedConfig()
-    assert cfg.compact_window == COMPACT_WINDOW
-    assert cfg.summary_bits == SUMMARY_BITS
-    assert cfg.adaptive_batch is False
-    # and the knobs actually reach the cluster layer
+def test_wire_path_knobs_reach_the_cluster():
     c = Collaboration()
-    c.add_datacenter("dc0", n_dtns=1, summary_bits=cfg.summary_bits // 2)
+    c.add_datacenter("dc0", n_dtns=1)
+    c.add_datacenter("dc1", n_dtns=1, summary_bits=SUMMARY_BITS // 2)
     try:
-        assert c.dtns[0].discovery.summary.nbits == cfg.summary_bits // 2
-        c.start_replication(batch_limit=cfg.compact_window,
-                            adaptive_batch=cfg.adaptive_batch)
-        assert c.dtns[0].replica_pump.batch_limit == cfg.compact_window
+        assert c.dtns[0].discovery.summary.nbits == SUMMARY_BITS
+        assert c.dtns[1].discovery.summary.nbits == SUMMARY_BITS // 2
+        c.start_replication()
+        assert c.dtns[0].replica_pump.batch_limit == COMPACT_WINDOW
+        assert c.dtns[0].replica_pump.batcher is None
+    finally:
+        c.close()
+    c = Collaboration()
+    c.add_datacenter("dc0", n_dtns=1)
+    try:
+        c.start_replication(batch_limit=COMPACT_WINDOW // 2)
+        assert c.dtns[0].replica_pump.batch_limit == COMPACT_WINDOW // 2
     finally:
         c.close()
 
